@@ -22,17 +22,28 @@ PREWARMED: dict[str, str] = {
     "ceph_tpu.ops.rs_kernels:gf_bitmatmul":
         "decode/scrub batcher prewarm() + encode_service prewarm() "
         "compile every (signature, batch, bucket) shape at EC map-"
-        "install warmup (osd/daemon.py _ec_warmup)",
+        "install warmup (osd/daemon.py _warm_ec_profiles)",
     "ceph_tpu.ops.rs_kernels:gf_encode_compare":
         "scrub_batcher.prewarm() compiles the full bucket ladder at EC "
         "warmup; the scrub I/O path only ever launches warmed shapes",
     "ceph_tpu.ops.rs_kernels:gf_bitmatmul_pallas_grouped":
-        "bench/experimental Pallas path; not dispatched by the I/O "
-        "path (ec_benchmark + perf labs call it directly)",
+        "on a TPU backend BitmatrixCodec._apply selects it for 2-D data "
+        "whose (k, m) leave room for >1 column group — the client EC "
+        "write / degraded-read path (EncodeService._run_group_single) "
+        "and the per-op sync path (MatrixErasureCode._apply_device); "
+        "encode_service.prewarm() compiles it at EC map-install warmup "
+        "for widths up to 64 x the stripe-unit chunk, wider payloads "
+        "(a 4 MiB object is S = 512 KiB per op) launch cold and are "
+        "counted in cold_launches (ROADMAP S2); checked byte-exact on "
+        "the chip by chip_smoke.py's kernels phase",
     "ceph_tpu.ops.rs_kernels:gf_bitmatmul_pallas":
-        "bench/experimental Pallas path; not dispatched by the I/O path",
+        "same dispatch as the grouped kernel (BitmatrixCodec._apply on "
+        "a TPU backend), taken when the code fills the MXU rows alone "
+        "or S has a single tile; same warmup and the same cold-launch "
+        "gap above the ladder",
     "ceph_tpu.ops.rs_kernels:gf_bitmatmul_pallas_acc":
-        "bench/experimental Pallas path; not dispatched by the I/O path",
+        "loop body of bench.py's one-launch harness only; not "
+        "dispatched by the I/O path",
     "ceph_tpu.ops.hashing:_crc_kernel_jit.kern":
         "scrub_batcher.prewarm() compiles every (crc_lanes, bucket) "
         "shape at EC warmup; lru_cache(1) keeps one program per process",

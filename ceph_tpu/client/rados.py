@@ -62,8 +62,8 @@ log = logging.getLogger("ceph_tpu.client")
 
 OP_TIMEOUT = 30.0
 # the reference Objecter resends indefinitely as maps advance; bounded
-# here but generous — under heavy co-tenant CPU contention a recovering
-# cluster can legitimately answer EAGAIN for a while
+# here but generous — under heavy CPU contention a recovering cluster
+# can legitimately answer EAGAIN for a while
 MAX_RETRIES = 25
 # resend backoff: exponential with full jitter, bounded (the
 # objecter_retry/backoff discipline — fixed sleeps synchronize every

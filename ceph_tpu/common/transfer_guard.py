@@ -9,19 +9,15 @@ batchers dispatch — recovery decode, deep-scrub crc / re-encode
 compare, encode-farm groups, the mgr analytics digest — runs inside
 :func:`no_implicit_transfers`, and:
 
-- where jax exposes ``jax.transfer_guard``, the window runs under
-  ``transfer_guard("disallow")``: any *implicit* host<->device
-  transfer (a raw numpy arg sliding into a jitted call, a device
-  scalar forced through ``bool()``) raises, the batcher's existing
-  dispatch fallback answers from the host path (correctness
-  unaffected), and the violation lands in the ``host_transfers``
-  counter;
+- the window runs under ``jax.transfer_guard("disallow")``: any
+  *implicit* host<->device transfer (a raw numpy arg sliding into a
+  jitted call, a device scalar forced through ``bool()``) raises, the
+  batcher's existing dispatch fallback answers from the host path
+  (correctness unaffected), and the violation lands in the
+  ``host_transfers`` counter;
 - explicit transfers — ``jax.device_put`` in, ``jax.device_get`` out
   — stay allowed: they are the sanctioned, declared boundary ops the
-  static ``device-host-sink`` baseline documents one by one;
-- on a jax without ``transfer_guard`` the shim still tracks guard
-  windows/depth and counts whatever violations surface as transfer
-  errors, so counters keep their shape everywhere.
+  static ``device-host-sink`` baseline documents one by one.
 
 Counters live in ``BucketCounters("transfer_guard")``
 (``guard_windows``, ``host_transfers``, ``host_exits``) and are
@@ -99,15 +95,6 @@ def in_guard() -> bool:
     return getattr(_state, "depth", 0) > 0
 
 
-def _jax_guard_cm(level: str):
-    try:
-        import jax
-
-        return jax.transfer_guard(level)
-    except (ImportError, AttributeError):
-        return None
-
-
 def _is_transfer_error(exc: BaseException) -> bool:
     msg = str(exc)
     return "transfer" in msg and (
@@ -126,13 +113,11 @@ def no_implicit_transfers(kind: str):
     c = guard_counters()
     c.inc("guard_windows", k=kind)
     _state.depth = getattr(_state, "depth", 0) + 1
-    cm = _jax_guard_cm("disallow")
+    import jax
+
     try:
-        if cm is None:
+        with jax.transfer_guard("disallow"):
             yield
-        else:
-            with cm:
-                yield
     except Exception as exc:
         if _is_transfer_error(exc):
             c.inc("host_transfers", k=kind)
@@ -152,12 +137,10 @@ def host_exit(kind: str):
         yield
         return
     guard_counters().inc("host_exits", k=kind)
-    cm = _jax_guard_cm("allow")
-    if cm is None:
+    import jax
+
+    with jax.transfer_guard("allow"):
         yield
-    else:
-        with cm:
-            yield
 
 
 def snapshot() -> dict[str, int]:

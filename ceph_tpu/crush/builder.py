@@ -150,12 +150,16 @@ def add_simple_rule(
     """CrushWrapper::add_simple_rule: take root; chooseleaf <mode> <num>
     <failure-domain>; emit.  ``num=0`` selects pool-size items;
     ``mode='indep'`` with rule_type=3 is the shape EC profiles create
-    (ErasureCode.cc:76-100)."""
+    (ErasureCode.cc:76-100).  Indep rules get the reference's
+    ``set_choose_tries 100``: a slot whose holder went out is only
+    retried, never shifted, and with k+m+1 failure domains the 50
+    tries of the tunables leave some PGs a hole for good."""
     if rule_id is None:
         rule_id = max(map_.rules.keys(), default=-1) + 1
     steps = []
     if mode == "indep":
         steps.append(RuleStep(RuleOp.SET_CHOOSELEAF_TRIES, 5, 0))
+        steps.append(RuleStep(RuleOp.SET_CHOOSE_TRIES, 100, 0))
     steps.append(RuleStep(RuleOp.TAKE, root_id, 0))
     op = RuleOp.CHOOSELEAF_FIRSTN if mode == "firstn" else RuleOp.CHOOSELEAF_INDEP
     if failure_domain_type == 0:

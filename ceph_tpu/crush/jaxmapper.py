@@ -16,7 +16,7 @@ Design notes (SURVEY.md §7 hard-part 4):
   machines with the same bounded trip counts the C code has
   (choose_total_tries); ``vmap`` batches the machines over seeds.
 - straw2 draws (mapper.c:315-365) need 64-bit fixed-point: the module
-  runs its jitted programs under ``jax.experimental.enable_x64`` and is
+  runs its jitted programs under ``jax.enable_x64`` and is
   explicit about dtypes so the rest of the framework stays in default
   32-bit mode.
 - The map compiles to dense padded arrays (items/weights/child tables);
@@ -1030,11 +1030,7 @@ class BatchedRuleMapper:
             rew = np.zeros(max(cc.max_devices, 1), np.int32)
             rw = np.asarray(reweights, np.int64)
             rew[: len(rw)] = rw[: len(rew)]
-        try:  # renamed from jax.experimental across jax releases
-            _enable_x64 = jax.enable_x64
-        except AttributeError:
-            from jax.experimental import enable_x64 as _enable_x64
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             if self._jitted is None:
                 self._jitted = self._build()
             # explicit transfer discipline (ctlint device-host-sink):

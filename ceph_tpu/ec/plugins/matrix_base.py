@@ -27,6 +27,7 @@ packet-row XORs just like jerasure_schedule_decode_lazy.
 from __future__ import annotations
 
 import collections
+import logging
 import os
 from typing import Iterable, Mapping
 
@@ -34,6 +35,8 @@ import numpy as np
 
 from ceph_tpu.ec.interface import ECError, ErasureCode
 from ceph_tpu.ops.gf256 import gf_matmul, gf_matrix_to_bitmatrix
+
+log = logging.getLogger("ceph_tpu.ec")
 
 #: Below this many payload bytes per encode/decode call, host numpy XOR
 #: beats device dispatch latency (SURVEY.md §7 hard part 3: the per-op
@@ -123,6 +126,12 @@ class MatrixErasureCode(ErasureCode):
 
     _device_unavailable = False  # latched after the first failed import
 
+    #: process-wide counts of the per-op sync device path (every plugin
+    #: instance shares them, like the batchers' ``shared().stats``):
+    #: ``device_applies`` launches, ``fallbacks`` launches that raised
+    #: and were answered from host numpy instead
+    device_stats: collections.Counter = collections.Counter()
+
     def _apply_matrix(self, M: np.ndarray, rows: np.ndarray) -> np.ndarray:
         """out = M @ rows over GF(2^8); device for big payloads."""
         if (
@@ -130,17 +139,31 @@ class MatrixErasureCode(ErasureCode):
             and not MatrixErasureCode._device_unavailable
         ):
             try:
-                return self._apply_device(M, rows)
+                out = self._apply_device(M, rows)
+                MatrixErasureCode.device_stats["device_applies"] += 1
+                return out
             except ImportError:
                 # no jax on this host: latch (on the shared base class)
                 # so large ops don't re-pay the module-finder miss
                 MatrixErasureCode._device_unavailable = True
+                self._note_fallback()
             except Exception:
                 # device runtime failure (backend init, OOM, ...):
                 # fall through — the host path is always correct —
                 # but don't latch; the condition may be transient
-                pass
+                self._note_fallback()
         return gf_matmul(M, rows)
+
+    @staticmethod
+    def _note_fallback() -> None:
+        """Count a device→host fallback (call from the except block);
+        the first one per process logs its traceback."""
+        MatrixErasureCode.device_stats["fallbacks"] += 1
+        if MatrixErasureCode.device_stats["fallbacks"] == 1:
+            log.exception(
+                "EC device path failed; answering from host numpy "
+                "(further failures are counted in "
+                "MatrixErasureCode.device_stats, not logged)")
 
     def _apply_device(self, M: np.ndarray, rows: np.ndarray) -> np.ndarray:
         import jax

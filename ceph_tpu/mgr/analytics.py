@@ -216,14 +216,10 @@ class AnalyticsEngine:
                     count_cold: bool = True) -> dict[str, np.ndarray]:
         import jax
 
-        try:
-            _x64 = jax.enable_x64
-        except AttributeError:  # jax-0.4.x
-            from jax.experimental import enable_x64 as _x64
         from ceph_tpu.ops.compile_cache import ensure_persistent_cache
 
         ensure_persistent_cache()
-        with _x64(True):
+        with jax.enable_x64(True):
             if self._jit is None:
                 self._jit = self._build_jit()
             shape_key = ("analytics", self.shape)

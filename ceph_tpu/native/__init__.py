@@ -16,14 +16,19 @@ Public API:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 
 import numpy as np
 
+log = logging.getLogger("ceph_tpu.native")
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "_libceph_tpu_native.so")
+_SO_STEM = "_libceph_tpu_native"
 _SRCS = ["crc32c.cc", "crush_hash.cc"]
 
 _lib = None
@@ -40,19 +45,32 @@ def _load():
             return _lib
         srcs = [os.path.join(_HERE, s) for s in _SRCS]
         try:
-            if not os.path.exists(_SO) or any(
-                os.path.getmtime(s) > os.path.getmtime(_SO) for s in srcs
-            ):
-                tmp = _SO + f".tmp.{os.getpid()}"
+            # the binary's name carries its sources' content hash: a
+            # copied or checked-out tree can never load a stale .so
+            # (mtimes do not survive a copy; the sources' bytes do)
+            digest = hashlib.sha256()
+            for src in srcs:
+                with open(src, "rb") as f:
+                    digest.update(f.read())
+            so = os.path.join(
+                _HERE, f"{_SO_STEM}.{digest.hexdigest()[:16]}.so")
+            if not os.path.exists(so):
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp]
                     + srcs,
                     check=True,
                     capture_output=True,
                 )
-                os.replace(tmp, _SO)
-            lib = ctypes.CDLL(_SO)
-        except (OSError, subprocess.CalledProcessError):
+                os.replace(tmp, so)
+                for old in glob.glob(os.path.join(_HERE, f"{_SO_STEM}*.so")):
+                    if old != so:
+                        os.unlink(old)
+            lib = ctypes.CDLL(so)
+        except (OSError, subprocess.CalledProcessError) as exc:
+            log.warning(
+                "native runtime unavailable (%s): crc32c/xor/straw2 run "
+                "on the pure-Python fallbacks", exc)
             _build_failed = True
             return None
         lib.ceph_tpu_crc32c.restype = ctypes.c_uint32

@@ -897,7 +897,6 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         'auto' = farm when >1 local jax device, 'on' = always attach the
         shared service, 'off' = never.  Resolved once, lazily."""
         if not self._encode_service_resolved:
-            self._encode_service_resolved = True
             mode = self.conf["osd_ec_encode_farm"]
             if mode != "off":
                 from ceph_tpu.parallel import encode_service as es
@@ -906,6 +905,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                 if svc.active() or mode == "on":
                     svc.min_bytes = self.conf["osd_ec_farm_min_bytes"]
                     self._encode_service = svc
+            # only once shared() has answered: a backend that failed to
+            # start raises again on the next use instead of leaving the
+            # daemon resolved-to-nothing, serving from numpy
+            self._encode_service_resolved = True
         return self._encode_service
 
     @property
@@ -1004,7 +1007,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             # a cold compile stalls the I/O path for ~30 s); on the CPU
             # backend (tests, dev) compiles are milliseconds and the
             # eager virtual-mesh warmup would cost more than it saves
-            farm_warm = jax.default_backend() not in ("cpu",)
+            farm_warm = jax.default_backend() == "tpu"
             for name, prof in fresh:
                 try:
                     ec = ec_registry.factory(
@@ -1020,6 +1023,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                             and hasattr(ec, "coding_matrix")):
                         svc.prewarm(ec.coding_matrix, widths)
                 except Exception:
+                    self.perf.inc("ec_warmup_failures")
                     log.exception(
                         "osd.%d: EC warmup for profile %r failed",
                         self.id, name)
@@ -1034,9 +1038,17 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
 
                 configure(mode, self.conf["osd_transfer_guard_window"])
 
+        def _warm_done(task) -> None:
+            self._warm_tasks.discard(task)
+            if not task.cancelled() and task.exception() is not None:
+                # raised outside the per-profile net above
+                self.perf.inc("ec_warmup_failures")
+                log.error("osd.%d: EC warmup failed", self.id,
+                          exc_info=task.exception())
+
         task = asyncio.ensure_future(asyncio.to_thread(_warm))
         self._warm_tasks.add(task)
-        task.add_done_callback(self._warm_tasks.discard)
+        task.add_done_callback(_warm_done)
 
     def _extent_cache_get(self, pool_id, oid, version, lo, hi):
         ent = self._extent_cache.get((pool_id, oid))

@@ -19,6 +19,8 @@ scalar pipeline.
 
 from __future__ import annotations
 
+import collections
+import logging
 from typing import NamedTuple
 
 import numpy as np
@@ -38,6 +40,8 @@ from ceph_tpu.osd.types import (
     PgPool,
     pg_t,
 )
+
+log = logging.getLogger("ceph_tpu.remap")
 
 _NONE = np.int32(CRUSH_ITEM_NONE)
 
@@ -112,6 +116,10 @@ class BatchedClusterMapper:
 
     def __init__(self, osdmap: OSDMap):
         self.osdmap = osdmap
+        #: pools answered by the batched program (``batched_pools``)
+        #: vs by the scalar pipeline (``scalar_pools``: unsupported
+        #: map/rule, or a batched launch that raised)
+        self.stats = collections.Counter()
         try:
             fp = _crush_fingerprint(osdmap.crush, osdmap.choose_args)
         except Exception:
@@ -180,19 +188,19 @@ class BatchedClusterMapper:
                 # jax backend unavailable/broken (e.g. a misconfigured
                 # JAX_PLATFORMS in a daemon environment): the placement
                 # answer must not depend on the accelerator being there
-                import logging
-
-                logging.getLogger("ceph_tpu.remap").warning(
+                log.warning(
                     "batched remap unavailable; using scalar pipeline",
                     exc_info=True,
                 )
                 mapper = None
         if mapper is not None:
+            self.stats["batched_pools"] += 1
             cnt = cnt.astype(np.int32).copy()
             raw = np.full((b, width), _NONE, np.int32)
             raw[:, : raw0.shape[1]] = raw0
         elif pool.crush_rule in om.crush.rules:
             # scalar fallback (unsupported map features)
+            self.stats["scalar_pools"] += 1
             raw = np.full((b, width), _NONE, np.int32)
             cnt = np.zeros(b, np.int32)
             from ceph_tpu.crush.mapper import crush_do_rule

@@ -26,28 +26,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5: top-level shard_map, replication check kw is check_vma
-    from jax import shard_map as _shard_map_fn
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, kw is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ceph_tpu.ops.rs_kernels import pack_bits, unpack_bits
-
-
-def shard_map(f=None, **kw):
-    """Version-compat facade over jax's shard_map (the replication-check
-    keyword was renamed across releases)."""
-    if "check_vma" in kw and _CHECK_KW != "check_vma":
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    if f is None:
-        return functools.partial(_shard_map_fn, **kw)
-    return _shard_map_fn(f, **kw)
 
 
 # -- input shardings --------------------------------------------------------

@@ -126,7 +126,7 @@ class TestSingleDeviceCoalescing:
     """Single-chip microbatching (round-3 VERDICT item 6): with ONE
     device and no mesh, the service still coalesces concurrent per-PG
     encodes into one dispatch per window — requests concatenate along
-    S, so the PERF_LAB relay-amortization carries into production I/O.
+    S, so one launch serves the whole window.
     The mode is device-agnostic; CI drives it with a CPU device."""
 
     def test_unit_coalesce_one_dispatch(self):

@@ -257,7 +257,10 @@ async def _tier_promote_vs_write():
         ):
             code, rs, _ = await client.command(cmd)
             assert code == 0, rs
-        await client._wait_new_map(client.osdmap.epoch, timeout=10)
+        # the overlay's epoch, not "any newer map": once the client has
+        # it, waiting for another one only sleeps out the timeout
+        while client.osdmap.epoch < mon.osdmap.epoch:
+            await client._wait_new_map(client.osdmap.epoch, timeout=10)
         io = client.ioctx("base")
 
         # cold object in the base pool (written pre-tier via direct

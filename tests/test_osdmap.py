@@ -59,6 +59,25 @@ class TestBasicMapping:
             assert len(up) == 5
             assert CRUSH_ITEM_NONE not in up
 
+    def test_ec_one_spare_host_refills_any_lost_osd(self):
+        """k+m+1 hosts, any one OSD out: every PG finds the one free
+        host for the vacated slot.  That takes the EC rule's
+        ``set_choose_tries 100`` (CrushWrapper::add_simple_rule); the
+        tunables' 50 tries left 1-4 of these 128 PGs a hole for 10 of
+        the 12 OSDs, and such a PG never recovers."""
+        m = make_osdmap(n_hosts=12, osds_per_host=1, ec=True, size=11,
+                        pg_num=128)
+        for lost in range(12):
+            weight = m.osd_weight[lost]
+            m.mark_down(lost)
+            m.mark_out(lost)
+            for ps in range(128):
+                up, *_ = m.pg_to_up_acting_osds(pg_t(1, ps))
+                assert len(up) == 11 and lost not in up, (lost, ps, up)
+                assert CRUSH_ITEM_NONE not in up, (lost, ps, up)
+            m.mark_up(lost)
+            m.osd_weight[lost] = weight
+
     def test_out_of_range_ps_folded_empty(self):
         m = make_osdmap(pg_num=64)
         assert m.pg_to_up_acting_osds(pg_t(1, 64), folded=True) == ([], -1, [], -1)

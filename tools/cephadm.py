@@ -71,12 +71,18 @@ def _daemon_pid(data: str, name: str) -> int | None:
     return pid if _alive(pid) else None
 
 
-def _spawn(data: str, name: str, argv: list[str]) -> int:
+def _spawn(data: str, name: str, argv: list[str], *,
+           owns_chip: bool = False) -> int:
+    """Start one daemon process; only the one that ``owns_chip``
+    inherits the JAX platform environment (common/cpumesh.py)."""
     log_path = os.path.join(data, f"{name}.log")
+    env = dict(os.environ)
+    if not owns_chip:
+        env["JAX_PLATFORMS"] = "cpu"
     with open(log_path, "ab") as logf:
         proc = subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), "_daemon"] + argv,
-            stdout=logf, stderr=logf,
+            stdout=logf, stderr=logf, env=env,
             start_new_session=True,  # survives the cephadm process
         )
     with open(_pidfile(data, name), "w") as f:
@@ -95,6 +101,13 @@ async def _run_daemon(args) -> None:
         force=True,
     )
     from ceph_tpu.common import ConfigProxy
+
+    from ceph_tpu.common.cpumesh import claim_jax_backend
+
+    logging.info(
+        "%s: %s",
+        f"mon.{args.rank}" if args.kind == "mon" else f"osd.{args.osd_id}",
+        claim_jax_backend("the first osd"))
 
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
@@ -238,7 +251,7 @@ def _spawn_osd(data: str, spec: dict, osd_id: int) -> None:
         "osd", "--data", data, "--store", spec["store"],
         "--osd-id", str(osd_id),
         "--mon-ports", ",".join(map(str, spec["mon_ports"])),
-    ])
+    ], owns_chip=osd_id == min(spec["osds"]))
 
 
 def cmd_ls(args) -> int:

@@ -25,6 +25,14 @@ async def amain(args) -> int:
     from ceph_tpu.crush.types import CrushMap
     from ceph_tpu.mon import Monitor
     from ceph_tpu.osd.daemon import OSDDaemon
+    from ceph_tpu.parallel import encode_service
+
+    # Start the JAX backend before any daemon's clock runs.  Left to
+    # the daemons it starts at their first EC map, on the event loop
+    # all of them share, and a TPU runtime takes 9-16 s to come up
+    # against a beacon grace of 4 beacons.  A chip that cannot be had
+    # raises here.
+    encode_service.shared()
 
     crush = CrushMap()
     B.build_hierarchy(
