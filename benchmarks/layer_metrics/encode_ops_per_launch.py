@@ -1,0 +1,13 @@
+"""Encode requests served per device launch of the encode service.
+"""
+
+LAYER = "launch batching"
+UNIT = "ops/launch"
+MOVES = "throughput_MiB_s"
+SOURCE = "program_counter"
+
+
+def compute(spans, counters, trace, run):
+    launches = sum(counters.get(f"encode.{k}_dispatches", 0)
+                   for k in ("single", "dp", "tp"))
+    return counters.get("encode.coalesced", 0) / launches if launches else None
