@@ -1,0 +1,15 @@
+"""Mean exclusive time on one ``client_op``'s blocking path in stage
+``other`` (no stage: the client's and the primary's own time), by the
+program's ``TraceCollector``; the five stages add up to the op's duration.
+"""
+
+from harness import spantree
+
+LAYER = "client"
+UNIT = "ms"
+MOVES = "throughput_MiB_s"
+SOURCE = "program_span"
+
+
+def compute(spans, counters, trace, run):
+    return spantree.critical_path_ms(spans, run, "other")

@@ -1,0 +1,14 @@
+"""Time in ``recovery_decode`` spans (the awaited batched decode) per
+``recover_object``.
+"""
+
+from harness import spantree
+
+LAYER = "recovery"
+UNIT = "ms"
+MOVES = "recovery_MiB_s"
+SOURCE = "program_span"
+
+
+def compute(spans, counters, trace, run):
+    return spantree.mean_ms(spans, "recovery_decode", per="recover_object")

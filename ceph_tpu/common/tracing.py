@@ -27,6 +27,12 @@ This module provides the same capability TPU-side, now **cluster-wide**:
   MMgrReport — the mgr's TraceCollector (mgr/tracer.py) assembles the
   cluster-wide trees.
 
+- an interval that is only known once it has ended (a wait, a phase a
+  worker thread ran) is filed afterwards with :meth:`Tracer.record`;
+- with head sampling at 0 and tail capture off the hot path builds no
+  :class:`Span` at all: every constructor hands out the one shared
+  :data:`INERT` span, whose children and wire context are inert too.
+
 Usage::
 
     tracer = get_tracer("osd.3")
@@ -35,11 +41,13 @@ Usage::
         with tracer.span("ec_sub_write", parent=sp, shard=2) as child:
             sub_msg.trace = tracer.ctx_for(child)
             ...
+        tracer.record("admit_wait", parent=sp, start_mono=t0,
+                      end_mono=time.monotonic(), stage="queue")
 """
 
 from __future__ import annotations
 
-import contextlib
+import contextvars
 import itertools
 import random
 import threading
@@ -92,7 +100,7 @@ class TraceContext:
         return cls(dec.u64(), dec.u64(), dec.bool_(), dec.str_())
 
 
-@dataclass
+@dataclass(slots=True)
 class Span:
     name: str
     span_id: int
@@ -105,6 +113,10 @@ class Span:
     end_mono: float | None = None
     tags: dict = field(default_factory=dict)
     duration: float | None = None
+    #: the tracer that files this span, so code that only holds the
+    #: span (a batching service serving many daemons) can file a child
+    #: where the parent lives; None on the inert span
+    tracer: "Tracer | None" = field(default=None, repr=False, compare=False)
 
     def tag(self, **kv) -> None:
         self.tags.update(kv)
@@ -126,6 +138,78 @@ class Span:
             ),
             "tags": dict(self.tags),
         }
+
+
+class _InertSpan(Span):
+    """What every constructor returns when nothing would keep the span
+    (trace unsampled, tail capture off): one shared object that takes
+    no tags, is its own no-op context manager, and makes its children
+    and its wire context inert too."""
+
+    __slots__ = ()
+
+    def tag(self, **kv) -> None:
+        pass
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+INERT: Span = _InertSpan(
+    name="", span_id=0, parent_id=None, start=0.0, sampled=False)
+
+#: the span that work started by the running task is filed under: the
+#: op's ``do_op`` while an OSD executes it, narrowed to ``ec_encode``,
+#: ``recover_object`` or ``recovery_decode`` around the calls whose
+#: callees (the batching services, sub-reads, pushes) parent their own
+#: spans there.  Tasks inherit it at creation, so a gather of sub-ops
+#: sees its op's span and nothing crosses between concurrent ops.
+CURRENT_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "ceph_tpu_current_span", default=None)
+
+
+class scope:
+    """``with scope(span):`` makes ``span`` the running task's
+    :data:`CURRENT_SPAN` for the block."""
+
+    __slots__ = ("span", "token")
+
+    def __init__(self, span: Span | None):
+        self.span = span
+
+    def __enter__(self) -> Span | None:
+        self.token = CURRENT_SPAN.set(self.span)
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            CURRENT_SPAN.reset(self.token)
+        except ValueError:
+            # a task garbage-collected at loop teardown runs this exit
+            # in a foreign Context; the var dies with the task
+            pass
+        return False
+
+
+class _OpenSpan:
+    """``with tracer.span(...) as sp``: finishes the span on exit."""
+
+    __slots__ = ("tracer", "span")
+
+    def __init__(self, tracer: "Tracer", span: Span):
+        self.tracer, self.span = tracer, span
+
+    def __enter__(self) -> Span:
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is not None:
+            self.span.tags["error"] = exc_type.__name__
+        self.tracer.finish_span(self.span)
+        return False
 
 
 class Tracer:
@@ -169,9 +253,17 @@ class Tracer:
         self.counters["sampler_accept" if ok else "sampler_reject"] += 1
         return ok
 
+    def wants(self, sampled: bool) -> bool:
+        """Whether a span of a trace with this head verdict is built at
+        all: an unsampled one only for tail capture to look at."""
+        return sampled or self.tail_slow_s is not None
+
     def _make_span(self, name: str, parent: Span | None,
-                   ctx: TraceContext | None, tags: dict) -> Span:
+                   ctx: TraceContext | None, tags: dict,
+                   start_mono: float | None = None) -> Span:
         if parent is not None:
+            if parent is INERT:
+                return INERT
             trace_id, parent_id, sampled = (
                 parent.trace_id, parent.span_id, parent.sampled)
         elif ctx is not None:
@@ -182,40 +274,57 @@ class Tracer:
         else:
             trace_id, parent_id = _next_id(), None
             sampled = self._head_sample()
+        if not self.wants(sampled):
+            return INERT
+        now = time.monotonic()
+        if start_mono is None:
+            start_mono = now
         return Span(
             name=name, span_id=_next_id(), parent_id=parent_id,
             trace_id=trace_id, sampled=sampled, daemon=self.name,
-            start=time.time(), start_mono=time.monotonic(), tags=tags,
+            start=time.time() - (now - start_mono), start_mono=start_mono,
+            tags=tags, tracer=self,
         )
 
-    @contextlib.contextmanager
     def span(self, name: str, parent: Span | None = None,
              ctx: TraceContext | None = None, **tags):
-        sp = self._make_span(name, parent, ctx, dict(tags))
-        t0 = time.perf_counter()
-        try:
-            yield sp
-        except BaseException as e:
-            sp.tags["error"] = type(e).__name__
-            raise
-        finally:
-            sp.duration = time.perf_counter() - t0
-            sp.end_mono = time.monotonic()
-            self.finish(sp)
+        """Context manager around the spanned work; yields the span
+        (the inert one when nothing would keep it)."""
+        sp = self._make_span(name, parent, ctx, tags)
+        return sp if sp is INERT else _OpenSpan(self, sp)
 
     def start_span(self, name: str, parent: Span | None = None,
                    ctx: TraceContext | None = None, **tags) -> Span:
         """Non-contextmanager form (spans closed by :meth:`finish_span`
         — callers whose open/close straddle callbacks)."""
-        return self._make_span(name, parent, ctx, dict(tags))
+        return self._make_span(name, parent, ctx, tags)
 
     def finish_span(self, sp: Span) -> None:
+        if sp is INERT:
+            return
         sp.end_mono = time.monotonic()
         sp.duration = max(sp.end_mono - sp.start_mono, 0.0)
         self.finish(sp)
 
-    def ctx_for(self, sp: Span) -> TraceContext:
-        """The wire context making ``sp`` the remote side's parent."""
+    def record(self, name: str, *, parent: Span | None = None,
+               ctx: TraceContext | None = None, start_mono: float,
+               end_mono: float, **tags) -> Span:
+        """File a finished span whose interval is already known: a wait
+        (only known once it has ended) or a phase a worker thread ran
+        (it cannot hold a context manager across the loop)."""
+        sp = self._make_span(name, parent, ctx, tags, start_mono)
+        if sp is not INERT:
+            sp.end_mono = end_mono
+            sp.duration = max(end_mono - start_mono, 0.0)
+            self.finish(sp)
+        return sp
+
+    def ctx_for(self, sp: Span | None) -> TraceContext | None:
+        """The wire context making ``sp`` the remote side's parent;
+        None (the message rides untraced) for no span or the inert
+        one."""
+        if sp is None or sp is INERT:
+            return None
         return TraceContext(
             trace_id=sp.trace_id, span_id=sp.span_id,
             sampled=sp.sampled, reqid=str(sp.tags.get("reqid", "")),
@@ -286,3 +395,23 @@ def device_tracer() -> Tracer:
     block-until-ready duration — batch padding and host<->device copy
     waste become directly visible (the BENCH_ALL gap diagnosis plane)."""
     return get_tracer("device")
+
+
+def launch_span(wait_name: str, waiters, **tags):
+    """The device tracer's ``xla_launch`` span around one launch of a
+    batching service.  ``waiters`` are ``(parent span or None, arrival
+    on the monotonic clock)`` of the requests the launch serves: each
+    traced one gets a ``wait_name`` child (``stage="queue"``: coalescing
+    window + executor queue + host packing) from its arrival to now,
+    filed where its parent lives, and the launch is tagged with their
+    ids as ``parents``."""
+    now = time.monotonic()
+    parents = []
+    for parent, arrived in waiters:
+        if parent is not None and parent is not INERT:
+            parent.tracer.record(wait_name, parent=parent, stage="queue",
+                                 start_mono=arrived, end_mono=now)
+            parents.append(parent.span_id)
+    if parents:
+        tags["parents"] = parents
+    return device_tracer().span("xla_launch", stage="device", **tags)

@@ -186,32 +186,54 @@ class TraceCollector:
 
     @staticmethod
     def _critical_path(tree: dict) -> tuple[list[dict], dict]:
-        """Walk the dominant child chain: at each node follow the child
-        that ends LATEST (the op cannot have completed before it); the
-        node's exclusive time — its duration minus the on-path child's
-        — lands in the node's stage bucket.  Returns (path, stage_ms).
+        """Walk the root's interval backwards from its end: at each
+        instant the op waits for the child that ends latest before it,
+        so that child is on the path down to its own start, where the
+        walk goes on with the children that ended before that.  The
+        time a node is on the path with no child of its own there is
+        its exclusive time, and lands in its stage bucket — a span
+        with no ``stage`` tag subdivides its parent and takes the
+        parent's.  The exclusive times add up to the root's duration.
+        Intervals are wall-clock start + duration, the one clock
+        daemons in different processes share.  Returns (path: every
+        node on it, a parent before its children, those in time
+        order; stage_ms).
         """
         stages = {s: 0.0 for s in STAGES}
-        path: list[dict] = []
-        node = tree
-        while node is not None:
-            dur = node.get("duration_ms") or 0.0
-            kids = [
-                c for c in node.get("children", ())
-                if c.get("end_mono") is not None
-            ]
-            nxt = max(
-                kids, key=lambda c: c["end_mono"], default=None)
-            child_dur = (nxt.get("duration_ms") or 0.0) if nxt else 0.0
-            exclusive = max(dur - child_dur, 0.0)
-            stages[_stage_of(node)] += exclusive
-            path.append({
+
+        def interval(node: dict) -> tuple[float, float]:
+            t0 = node.get("start") or 0.0
+            return t0, t0 + (node.get("duration_ms") or 0.0) / 1e3
+
+        def walk(node: dict, end: float, inherited: str) -> list[dict]:
+            own = node.get("tags", {}).get("stage")
+            stage = _stage_of(node) if own else inherited
+            start, node_end = interval(node)
+            cursor = min(end, node_end)
+            exclusive, below = 0.0, []
+            kids = sorted(
+                (c for c in node.get("children", ())
+                 if c.get("duration_ms") is not None),
+                key=lambda c: interval(c)[1], reverse=True)
+            for c in kids:
+                c_start, c_end = interval(c)
+                c_end = min(c_end, cursor)
+                if c_end <= max(c_start, start):
+                    continue    # wholly behind a sibling already walked
+                exclusive += cursor - c_end
+                below.append(walk(c, c_end, stage))
+                cursor = max(c_start, start)
+            exclusive += max(cursor - start, 0.0)
+            stages[stage] += exclusive * 1e3
+            here = {
                 "name": node["name"], "daemon": node.get("daemon", ""),
-                "stage": _stage_of(node),
-                "duration_ms": dur,
-                "exclusive_ms": round(exclusive, 3),
-            })
-            node = nxt
+                "stage": stage,
+                "duration_ms": node.get("duration_ms") or 0.0,
+                "exclusive_ms": round(exclusive * 1e3, 3),
+            }
+            return [here] + [p for sub in reversed(below) for p in sub]
+
+        path = walk(tree, float("inf"), "other")
         return path, {k: round(v, 3) for k, v in stages.items()}
 
     # -- query surface -------------------------------------------------

@@ -17,8 +17,9 @@ reference's msgr2 message frames (header: type, source entity, seq).
 from __future__ import annotations
 
 import asyncio
-import contextlib
 import logging
+import sys
+import time
 from typing import Awaitable, Callable
 
 from ceph_tpu.msg import frames
@@ -150,32 +151,52 @@ class Connection:
         delay = self.messenger.inject_delay
         if delay > 0:
             await asyncio.sleep(delay)
-        trace = getattr(msg, "trace", None)
-        tracer = self.messenger.tracer
-        span_cm = (
-            tracer.span(
-                "msg_send", ctx=trace, stage="net",
-                msg=type(msg).__name__,
-                peer=f"{self.peer[0]}.{self.peer[1]}" if self.peer else "?",
+        t_asked = time.monotonic()
+        async with self._send_lock:
+            await self._write_message(msg, t_asked, time.monotonic())
+
+    async def _write_message(self, msg: Message, t_asked: float,
+                             t_locked: float) -> float:
+        """Encode and write one frame, the send lock held since
+        ``t_locked``; returns the monotonic time it was done.  A traced
+        message files its ``msg_send`` span over ``[t_asked, done]``,
+        the three legs as tags: ``lock_wait_ms`` (behind other frames
+        to this peer), ``encode_ms``, ``write_ms`` (``write_frame``
+        incl. the drain), and the frame's ``bytes``."""
+        self._seq += 1
+        segs = encode_message(msg, self.messenger.entity, self._seq)
+        tag = frames.Tag.MESSAGE
+        if (
+            self.compressor is not None
+            and sum(len(s) for s in segs)
+            >= self.messenger.compress_min_size
+        ):
+            segs = [self.compressor.compress(s) for s in segs]
+            tag = frames.Tag.MESSAGE_COMPRESSED
+        t_encoded = time.monotonic()
+        try:
+            await frames.write_frame(
+                self.writer, tag, segs, crypto=self.crypto
             )
-            if tracer is not None and trace is not None and trace.sampled
-            else contextlib.nullcontext()
-        )
-        with span_cm:
-            async with self._send_lock:
-                self._seq += 1
-                segs = encode_message(msg, self.messenger.entity, self._seq)
-                tag = frames.Tag.MESSAGE
-                if (
-                    self.compressor is not None
-                    and sum(len(s) for s in segs)
-                    >= self.messenger.compress_min_size
-                ):
-                    segs = [self.compressor.compress(s) for s in segs]
-                    tag = frames.Tag.MESSAGE_COMPRESSED
-                await frames.write_frame(
-                    self.writer, tag, segs, crypto=self.crypto
-                )
+        finally:
+            t_done = time.monotonic()
+            trace = getattr(msg, "trace", None)
+            tracer = self.messenger.tracer
+            if (tracer is not None and trace is not None
+                    and tracer.wants(trace.sampled)):
+                failed = sys.exc_info()[0]
+                tracer.record(
+                    "msg_send", ctx=trace, stage="net",
+                    start_mono=t_asked, end_mono=t_done,
+                    msg=type(msg).__name__,
+                    peer=f"{self.peer[0]}.{self.peer[1]}"
+                    if self.peer else "?",
+                    lock_wait_ms=1e3 * (t_locked - t_asked),
+                    encode_ms=1e3 * (t_encoded - t_locked),
+                    write_ms=1e3 * (t_done - t_encoded),
+                    bytes=sum(len(s) for s in segs),
+                    **({"error": failed.__name__} if failed else {}))
+        return t_done
 
     async def send_messages(self, msgs: list[Message]) -> None:
         """Send a burst of messages back-to-back under ONE send-lock
@@ -204,38 +225,14 @@ class Connection:
         delay = self.messenger.inject_delay
         if delay > 0:
             await asyncio.sleep(delay)
-        tracer = self.messenger.tracer
+        t_asked = time.monotonic()
         async with self._send_lock:
+            t_locked = time.monotonic()
             for msg in msgs:
-                trace = getattr(msg, "trace", None)
-                span_cm = (
-                    tracer.span(
-                        "msg_send", ctx=trace, stage="net",
-                        msg=type(msg).__name__,
-                        peer=(f"{self.peer[0]}.{self.peer[1]}"
-                              if self.peer else "?"),
-                    )
-                    if tracer is not None and trace is not None
-                    and trace.sampled
-                    else contextlib.nullcontext()
-                )
-                with span_cm:
-                    self._seq += 1
-                    segs = encode_message(
-                        msg, self.messenger.entity, self._seq)
-                    tag = frames.Tag.MESSAGE
-                    if (
-                        self.compressor is not None
-                        and sum(len(s) for s in segs)
-                        >= self.messenger.compress_min_size
-                    ):
-                        segs = [
-                            self.compressor.compress(s) for s in segs
-                        ]
-                        tag = frames.Tag.MESSAGE_COMPRESSED
-                    await frames.write_frame(
-                        self.writer, tag, segs, crypto=self.crypto
-                    )
+                # the burst's lock wait goes to its first message; each
+                # later one starts where the one before it ended
+                t_asked = t_locked = await self._write_message(
+                    msg, t_asked, t_locked)
 
     async def _run(self) -> None:
         try:
@@ -278,16 +275,6 @@ class Connection:
                 ]
             msg = decode_message(segs)
             msg.conn = self
-            tracer = self.messenger.tracer
-            if (tracer is not None and msg.trace is not None
-                    and msg.trace.sampled):
-                # a zero-length arrival marker: the collector pairs it
-                # with the sender's msg_send span to bound wire time
-                with tracer.span(
-                    "msg_recv", ctx=msg.trace, stage="net",
-                    msg=type(msg).__name__,
-                ):
-                    pass
             await self.messenger._dispatch(msg)
         elif tag == frames.Tag.COMPRESSION_REQUEST:
             # inbound negotiation (compression_onwire.cc server
@@ -387,10 +374,11 @@ class Messenger:
         # deterministic chaos shim (ceph_tpu/chaos/netem.py Netem);
         # None = transparent
         self.netem = None
-        # the owning daemon's Tracer: messages carrying a SAMPLED
-        # trace context get msg_send/msg_recv spans (stage=net), the
-        # wire legs of the cluster-wide span tree; None = no messenger
-        # spans (clients of the raw messenger)
+        # the owning daemon's Tracer: a message carrying a trace
+        # context the tracer wants (sampled, or tail capture on) gets a
+        # msg_send span (stage=net) on the sending side, the wire leg
+        # of the cluster-wide span tree; None = no messenger spans
+        # (clients of the raw messenger)
         self.tracer = None
 
     async def _dispatch(self, msg: Message) -> None:

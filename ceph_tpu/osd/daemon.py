@@ -41,6 +41,7 @@ import time
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.ec import registry as ec_registry
 from ceph_tpu.msg.messages import (
@@ -132,7 +133,7 @@ from ceph_tpu.osd.snaps import (
     encode_snaps,
 )
 from ceph_tpu.osd.types import PgPool, pg_t
-from ceph_tpu.store import MemStore, Transaction, coll_t, ghobject_t
+from ceph_tpu.store import MemStore, Transaction, TxOp, coll_t, ghobject_t
 
 log = logging.getLogger("ceph_tpu.osd")
 
@@ -295,12 +296,6 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         self._watchers: dict[tuple[int, str], dict[tuple, object]] = {}
         self._notify_waiters: dict[tuple, asyncio.Future] = {}
         self._trim_tasks: set = set()
-        import contextvars
-
-        # root span of the client op executing in THIS task (ops run as
-        # concurrent tasks, so a plain attribute would cross-parent)
-        self._op_span = contextvars.ContextVar(
-            f"osd{osd_id}_op_span", default=None)
         self._recovering_pgs: set[tuple[int, int]] = set()
         # (pool, ps) -> newest epoch whose recovery pass completed for
         # that pg: a pg is only reported clean once the pass has
@@ -1084,15 +1079,17 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         get a device-stage span so the critical-path breakdown can
         attribute encode time separately from net/queue/store."""
         with self._maybe_span(
-            "ec_encode", parent=self._op_span.get(), stage="device",
+            "ec_encode", parent=tracing.CURRENT_SPAN.get(), stage="device",
             nbytes=len(logical),
-        ):
+        ) as sp, tracing.scope(sp):
+            # the encode service files this op's wait for its launch
+            # under the span in scope (encode_batch_wait)
             return await ecutil.encode_async(
                 sinfo, ec, logical, service=self.encode_service)
 
     async def _ecu_decode_concat(self, sinfo, ec, chunks):
         with self._maybe_span(
-            "ec_decode", parent=self._op_span.get(), stage="device",
+            "ec_decode", parent=tracing.CURRENT_SPAN.get(), stage="device",
             shards=len(chunks),
         ):
             return await ecutil.decode_concat_async(
@@ -1173,11 +1170,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                             lg.append(t, e)
                     self._pg_log_trim(t, lg)
                     if not t.empty():
-                        if getattr(self.store, "blocking_commit", False):
-                            await asyncio.to_thread(
-                                self.store.queue_transaction, t)
-                        else:
-                            self.store.queue_transaction(t)
+                        await self._commit(t)
             if self.epoch == epoch0:
                 self._primed_intervals[key] = (epoch0, act)
             return self.epoch == epoch0
@@ -1227,6 +1220,57 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         if parent is None and ctx is None:
             return _ctx.nullcontext(None)
         return self.tracer.span(name, parent=parent, ctx=ctx, **tags)
+
+    async def _commit(self, t: Transaction, parent_span=None) -> None:
+        """Queue ``t`` on the store.  Journaling stores fsync: their
+        commit runs on a worker thread so one OSD's disk flush never
+        stalls the whole event loop (the reference's journaling happens
+        on dedicated finisher threads for the same reason).
+
+        Under a traced ``store_commit`` (``parent_span``) the two legs
+        the coroutine cannot see are filed as its children:
+        ``store_exec_wait`` (submit to the executor -> first instruction
+        in the worker thread) and ``store_txn`` (the thread's call of
+        ``queue_transaction``, tagged with the phases the store stamped
+        into ``t.marks``).  What remains of the parent is transaction
+        build plus the wait for the loop to resume this coroutine.
+        The children carry no ``stage`` of their own: they subdivide
+        the parent's, and a sum of the store stage's spans stays a sum
+        of commits."""
+        if not getattr(self.store, "blocking_commit", False):
+            self.store.queue_transaction(t)
+            return
+        if parent_span is None or parent_span is tracing.INERT:
+            await asyncio.to_thread(self.store.queue_transaction, t)
+            return
+        submitted = time.monotonic()
+
+        def run() -> None:
+            started = time.monotonic()
+            try:
+                self.store.queue_transaction(t)
+            finally:
+                # filed here, on the worker thread: the loop thread is
+                # the bottleneck and pays for neither span
+                ended = time.monotonic()
+                self.tracer.record(
+                    "store_exec_wait", parent=parent_span,
+                    start_mono=submitted, end_mono=started)
+                m, phases = t.marks, {}
+                if "kv" in m:       # the commit ran through every phase
+                    phases = {
+                        "lock_wait_ms": 1e3 * (m["locked"] - m["enter"]),
+                        "data_ms": 1e3 * (m["data"] - m["locked"]),
+                        "fsync_ms": 1e3 * (m["fsync"] - m["data"]),
+                        "kv_ms": 1e3 * (m["kv"] - m["fsync"]),
+                    }
+                self.tracer.record(
+                    "store_txn", parent=parent_span,
+                    start_mono=started, end_mono=ended,
+                    bytes=sum(len(op[4]) for op in t.ops
+                              if op[0] == TxOp.WRITE), **phases)
+
+        await asyncio.to_thread(run)
 
     async def _store_latency_gate(self) -> None:
         """Async injected-store-latency point (chaos degraded-disk
@@ -1367,10 +1411,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     return
                 t = Transaction()
                 t.remove(c, o)
-                if getattr(self.store, "blocking_commit", False):
-                    await asyncio.to_thread(self.store.queue_transaction, t)
-                else:
-                    self.store.queue_transaction(t)
+                await self._commit(t)
                 disk_fault_counters().inc("quarantined")
         except OSError:
             # a dying disk can refuse the removal too; escalation is
@@ -2070,11 +2111,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                             else:
                                 t.setattrs(c, head, {SS_ATTR: ss.to_bytes()})
                     try:
-                        if getattr(self.store, "blocking_commit", False):
-                            await asyncio.to_thread(
-                                self.store.queue_transaction, t)
-                        else:
-                            self.store.queue_transaction(t)
+                        await self._commit(t)
                     except (FileNotFoundError, FileExistsError):
                         pass  # raced a concurrent op; next trim rescans
                 await asyncio.sleep(0)
@@ -2133,17 +2170,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     reqid=msg.reqid, oid=msg.oid, pool=msg.pool,
                     ops=len(msg.ops),
                 ) as _sp:
-                    token = self._op_span.set(_sp)
-                    try:
+                    with tracing.scope(_sp):
                         reply = await self._execute_op(msg)
-                    finally:
-                        try:
-                            self._op_span.reset(token)
-                        except ValueError:
-                            # a task garbage-collected at loop teardown
-                            # runs this finally in a foreign Context;
-                            # the var dies with the task either way
-                            pass
                     _sp.tag(result=reply.result)
             tracked.mark_event("replying")
             if reply.result == 0 and reply.data:
@@ -2156,6 +2184,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         except Exception:
             log.exception("osd.%d: op tid %d crashed", self.id, msg.tid)
             reply = MOSDOpReply(tid=msg.tid, result=-errno.EIO, epoch=self.epoch)
+        reply.trace = msg.trace     # the reply leg's msg_send joins the op
         try:
             await msg.conn.send_message(reply)
         except ConnectionError:
@@ -2780,15 +2809,12 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             pool, pg, msg.oid, effects, attrs, version, delete,
             reqid=msg.reqid,
         )
-        parent_sp = self._op_span.get()
+        parent_sp = tracing.CURRENT_SPAN.get()
         await self._store_latency_gate()
         with self._maybe_span(
             "store_commit", parent=parent_sp, stage="store", oid=msg.oid,
-        ):
-            if getattr(self.store, "blocking_commit", False):
-                await asyncio.to_thread(self.store.queue_transaction, t)
-            else:
-                self.store.queue_transaction(t)
+        ) as commit_sp:
+            await self._commit(t, commit_sp)
         waits = []
         for osd in acting:
             if osd in (self.id, CRUSH_ITEM_NONE):
@@ -2866,12 +2892,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                 with self._maybe_span(
                     "store_commit", ctx=msg.trace, stage="store",
                     oid=msg.oid,
-                ):
-                    if getattr(self.store, "blocking_commit", False):
-                        await asyncio.to_thread(
-                            self.store.queue_transaction, t)
-                    else:
-                        self.store.queue_transaction(t)
+                ) as commit_sp:
+                    await self._commit(t, commit_sp)
             else:
                 # legacy full-object payload (recovery pushes reuse this)
                 await self._apply_full_object(
@@ -2887,8 +2909,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             lg = self._pg_log(self._shard_coll(pool, msg.pg, NO_SHARD))
             floored = (lg.contig_floor is not None
                        and lg.info.last_update == msg.version)
-        await msg.conn.send_message(MOSDRepOpReply(
+        rep = MOSDRepOpReply(
             tid=msg.tid, pg=msg.pg, from_osd=self.id, result=result,
             epoch=self.epoch, floored=floored,
-        ))
+        )
+        rep.trace = msg.trace   # the reply leg's msg_send joins the op
+        await msg.conn.send_message(rep)
 

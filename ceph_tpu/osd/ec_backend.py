@@ -11,6 +11,7 @@ import logging
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.pglog import (
@@ -122,7 +123,7 @@ class ECBackendMixin:
 
         await FAULTS.check("osd.ec_fan_out")
         guarded = prev_version is not None
-        parent_sp = self._op_span.get()
+        parent_sp = tracing.CURRENT_SPAN.get()
         waits = []
         local: list[tuple[int, bytes]] = []
         estale = False
@@ -206,12 +207,13 @@ class ECBackendMixin:
                 with self._maybe_span(
                     "store_commit", parent=parent_sp, stage="store",
                     shard=shard, oid=oid,
-                ):
+                ) as commit_sp:
                     await self._apply_shard_write_async(
                         pool, pg, shard, oid, payload, attrs,
                         version=version, off=off, truncate=truncate,
                         rmattrs=rmattrs, reqid=reqid,
                         clone_snap=clone_snap, clone_snaps=clone_snaps,
+                        commit_span=commit_sp,
                     )
         if estale:
             if _retried:
@@ -311,11 +313,7 @@ class ECBackendMixin:
                 self._ensure_coll(t0, self._shard_coll(pool, pg, my_shard))
                 lg.rollback_divergent(t0, msg.oid, served or ZERO)
                 if t0.ops:
-                    if getattr(self.store, "blocking_commit", False):
-                        await asyncio.to_thread(
-                            self.store.queue_transaction, t0)
-                    else:
-                        self.store.queue_transaction(t0)
+                    await self._commit(t0)
             # fall through: apply the vector afresh
         for o in ops:
             if o.op in (OP_OMAP_SETKEYS, OP_OMAP_RMKEYS, OP_OMAP_CLEAR):
@@ -582,20 +580,18 @@ class ECBackendMixin:
         off: int = 0, truncate: int | None = None,
         rmattrs: list[str] | None = None, reqid: str = "",
         clone_snap: int = 0, clone_snaps: bytes = b"",
+        commit_span=None,
     ) -> None:
-        """Same, but journaling stores fsync: run their commit on a
-        worker thread so one OSD's disk flush never stalls the whole
-        event loop (the reference's journaling happens on dedicated
-        finisher threads for the same reason)."""
+        """Same, but through :meth:`_commit` (journaling stores commit
+        on a worker thread); ``commit_span`` is the caller's open
+        ``store_commit`` span, which the commit's legs are filed
+        under."""
         t = self._shard_write_txn(
             pool, pg, shard, oid, payload, attrs, delete, version,
             off, truncate, rmattrs, reqid, clone_snap, clone_snaps,
         )
         try:
-            if getattr(self.store, "blocking_commit", False):
-                await asyncio.to_thread(self.store.queue_transaction, t)
-            else:
-                self.store.queue_transaction(t)
+            await self._commit(t, commit_span)
         except OSError as e:
             # a failed/torn commit is a medium error too: it feeds the
             # same ledger so a disk that can no longer write escalates
@@ -1070,7 +1066,7 @@ class ECBackendMixin:
                 return None, None, eno
         tid = next(self._tids)
         rep = await self._traced_sub_op(
-            "ec_sub_read", self._op_span.get(), shard, osd,
+            "ec_sub_read", tracing.CURRENT_SPAN.get(), shard, osd,
             "", MOSDECSubOpRead(
                 tid=tid, pg=pg, shard=shard, from_osd=self.id, oid=oid,
                 off=off, length=length, want_attrs=True, epoch=self.epoch,
@@ -1213,7 +1209,7 @@ class ECBackendMixin:
                 with self._maybe_span(
                     "store_commit", ctx=msg.trace, stage="store",
                     shard=msg.shard, oid=msg.oid,
-                ):
+                ) as commit_sp:
                     await self._apply_shard_write_async(
                         pool, msg.pg, msg.shard, msg.oid, msg.data,
                         msg.attrs, delete=msg.delete, version=msg.version,
@@ -1221,6 +1217,7 @@ class ECBackendMixin:
                         rmattrs=msg.rmattrs, reqid=msg.reqid,
                         clone_snap=msg.clone_snap,
                         clone_snaps=msg.clone_snaps,
+                        commit_span=commit_sp,
                     )
         except OSError as e:
             result = -(e.errno or errno.EIO)
@@ -1234,10 +1231,12 @@ class ECBackendMixin:
             lg = self._pg_log(self._shard_coll(pool, msg.pg, msg.shard))
             floored = (lg.contig_floor is not None
                        and lg.info.last_update == msg.version)
-        await msg.conn.send_message(MOSDECSubOpWriteReply(
+        rep = MOSDECSubOpWriteReply(
             tid=msg.tid, pg=msg.pg, shard=msg.shard, from_osd=self.id,
             result=result, epoch=self.epoch, floored=floored,
-        ))
+        )
+        rep.trace = msg.trace   # the reply leg's msg_send joins the op
+        await msg.conn.send_message(rep)
 
     async def _handle_sub_read(self, msg: MOSDECSubOpRead) -> None:
         pool = self.osdmap.get_pg_pool(msg.pg.pool)
@@ -1279,4 +1278,5 @@ class ECBackendMixin:
                     from_osd=self.id, result=-(e.errno or 5),
                     epoch=self.epoch,
                 )
+        rep.trace = msg.trace
         await msg.conn.send_message(rep)

@@ -11,6 +11,7 @@ import time
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.pglog import (
@@ -175,52 +176,72 @@ class RecoveryMixin:
             if o != CRUSH_ITEM_NONE and o != self.id
         })
         retry = self.conf["osd_backfill_retry_interval"]
-        async with self.local_reserver.request(key, priority=1):
-            self.recovery_stats["peak_local"] = max(
-                self.recovery_stats["peak_local"],
-                self.local_reserver.in_use)
-            granted: list[int] = []
-            try:
-                while not self.stopping and self.epoch == pass_epoch:
-                    if await self._reserve_remotes(pg, peers, granted):
-                        break
-                    # partial holds across the retry sleep invite
-                    # cluster-wide deadlock (two primaries each camped
-                    # on one of the other's replicas): drop everything
-                    self.recovery_stats["reservation_rejects"] += 1
-                    await self._release_remotes(pg, granted)
-                    granted.clear()
-                    # a TOOFULL rejecter may be full of exactly the
-                    # logged deletes this pass would replay onto it:
-                    # run the delete-replay OUTSIDE the reservation
-                    # gate so the peer can dig itself out and GRANT
-                    # the next round (fullness-chaos-found deadlock;
-                    # reference recovery deletes are never
-                    # reservation- or fullness-gated)
-                    await self._recover_pg_deletes(pool, pg, acting)
-                    await asyncio.sleep(retry)
-                else:
-                    return
-                self._recovering_pgs.add(key)
+        # one trace per PG pass: recover_pg -> pg_reserve (queueing for
+        # the local slot and a slot on every peer, retry sleeps
+        # included), pg_scan, then each object's admit wait and
+        # recover_object — in scope, so the object legs parent there
+        with self.tracer.span("recover_pg", pg=str(pg)) as pg_sp, \
+                tracing.scope(pg_sp):
+            reserve_sp = self.tracer.start_span(
+                "pg_reserve", parent=pg_sp, stage="queue")
+            rounds = rejects = 0
+            async with self.local_reserver.request(key, priority=1):
+                self.recovery_stats["peak_local"] = max(
+                    self.recovery_stats["peak_local"],
+                    self.local_reserver.in_use)
+                granted: list[int] = []
                 try:
-                    ok = await self._recover_pg(pool, pg, acting)
-                    if ok:
-                        # MONOTONE: a pass verified under an older map
-                        # must never rewind a newer verdict.  A queued
-                        # background pass (_queue_pg_pass) can run for
-                        # tens of seconds (sub-op timeouts) while the
-                        # map-driven task completes a newer pass and
-                        # EXITS believing everything clean; the stale
-                        # completion landing afterwards knocked the pg
-                        # back to active+peering with nothing left to
-                        # re-run recovery — the silent soak-sweep wedge
-                        self._clean_epoch[key] = max(
-                            pass_epoch, self._clean_epoch.get(key, -1))
-                        self.recovery_stats["pgs_recovered"] += 1
+                    try:
+                        while not self.stopping \
+                                and self.epoch == pass_epoch:
+                            rounds += 1
+                            if await self._reserve_remotes(
+                                    pg, peers, granted):
+                                break
+                            # partial holds across the retry sleep invite
+                            # cluster-wide deadlock (two primaries each
+                            # camped on one of the other's replicas): drop
+                            # everything
+                            rejects += 1
+                            self.recovery_stats["reservation_rejects"] += 1
+                            await self._release_remotes(pg, granted)
+                            granted.clear()
+                            # a TOOFULL rejecter may be full of exactly the
+                            # logged deletes this pass would replay onto
+                            # it: run the delete-replay OUTSIDE the
+                            # reservation gate so the peer can dig itself
+                            # out and GRANT the next round (fullness-chaos-
+                            # found deadlock; reference recovery deletes
+                            # are never reservation- or fullness-gated)
+                            await self._recover_pg_deletes(pool, pg, acting)
+                            await asyncio.sleep(retry)
+                        else:
+                            pg_sp.tag(result="superseded")
+                            return
+                    finally:
+                        reserve_sp.tag(rounds=rounds, rejects=rejects)
+                        self.tracer.finish_span(reserve_sp)
+                    self._recovering_pgs.add(key)
+                    try:
+                        ok = await self._recover_pg(pool, pg, acting)
+                        if ok:
+                            # MONOTONE: a pass verified under an older map
+                            # must never rewind a newer verdict.  A queued
+                            # background pass (_queue_pg_pass) can run for
+                            # tens of seconds (sub-op timeouts) while the
+                            # map-driven task completes a newer pass and
+                            # EXITS believing everything clean; the stale
+                            # completion landing afterwards knocked the pg
+                            # back to active+peering with nothing left to
+                            # re-run recovery — the silent soak-sweep wedge
+                            self._clean_epoch[key] = max(
+                                pass_epoch, self._clean_epoch.get(key, -1))
+                            self.recovery_stats["pgs_recovered"] += 1
+                        pg_sp.tag(result="ok" if ok else "incomplete")
+                    finally:
+                        self._recovering_pgs.discard(key)
                 finally:
-                    self._recovering_pgs.discard(key)
-            finally:
-                await self._release_remotes(pg, granted)
+                    await self._release_remotes(pg, granted)
 
     async def _reserve_remotes(
         self, pg: pg_t, peers: list[int], granted: list[int],
@@ -485,6 +506,10 @@ class RecoveryMixin:
         pairs = self._pg_members(pool, acting)
         if self.id not in [o for _, o in pairs]:
             return True
+        # query/log/scope phase, until the object set is known (a scan
+        # that raises leaves no span; the pass's own span says why)
+        pg_sp = tracing.CURRENT_SPAN.get() or tracing.INERT
+        scan_sp = self.tracer.start_span("pg_scan", parent=pg_sp)
         # prior-set (PastIntervals role): still-up members of previous
         # acting sets serve as extra data SOURCES — a fully-remapped PG
         # pulls from its old home
@@ -755,6 +780,9 @@ class RecoveryMixin:
             objs = scope
         all_ok = True
         rsleep = self.conf["osd_recovery_sleep"]
+        ordered = sorted(objs - skip_done)
+        self.tracer.finish_span(scan_sp)
+        pg_sp.tag(objects=len(ordered))
 
         async def _one(oid: str) -> bool:
             # osd_recovery_max_active: in-flight reconciliations per
@@ -763,8 +791,13 @@ class RecoveryMixin:
             # so saturated client I/O overtakes it (admission strictly
             # BEFORE the object lock — a lock holder must never wait
             # on admission, or slots+locks could cycle)
+            t_asked = time.monotonic()
             async with self._recovery_budget:
                 async with self.op_gate.admit("recovery"):
+                    self.tracer.record(
+                        "recovery_admit_wait", parent=pg_sp, stage="queue",
+                        oid=oid, start_mono=t_asked,
+                        end_mono=time.monotonic())
                     ok = await self._reconcile_object(
                         pool, pg, pairs, oid, stray=oid in strays,
                         prior_pairs=prior,
@@ -773,7 +806,6 @@ class RecoveryMixin:
                     await asyncio.sleep(rsleep)
                 return bool(ok)
 
-        ordered = sorted(objs - skip_done)
         results = await asyncio.gather(
             *[_one(oid) for oid in ordered], return_exceptions=True,
         )
@@ -914,15 +946,23 @@ class RecoveryMixin:
         mid-write would see a partial fan-out and wrongly roll it back
         (``have_lock`` for callers inside the write path that already
         hold it)."""
+        # a child of the PG pass (or of the client op whose write path
+        # reconciles); in scope, so the helpers' ec_sub_read and the
+        # push — and through its context the target's store_commit —
+        # join the object's tree
         with self.tracer.span(
-            "recover_object", pg=str(pg), oid=oid,
-        ):
+            "recover_object", parent=tracing.CURRENT_SPAN.get(),
+            pg=str(pg), oid=oid,
+        ) as sp, tracing.scope(sp):
             if not have_lock:
                 async with self._obj_lock(pool.id, oid):
-                    return await self._reconcile_object_locked(
+                    ok = await self._reconcile_object_locked(
                         pool, pg, pairs, oid, stray, prior_pairs)
-            return await self._reconcile_object_locked(
-                pool, pg, pairs, oid, stray, prior_pairs)
+            else:
+                ok = await self._reconcile_object_locked(
+                    pool, pg, pairs, oid, stray, prior_pairs)
+            sp.tag(result="ok" if ok else "failed")
+            return ok
 
     async def _reconcile_object_locked(
         self, pool: PgPool, pg: pg_t, pairs: list[tuple[int, int]], oid: str,
@@ -1048,17 +1088,23 @@ class RecoveryMixin:
         src_attrs = next(
             a for (s, o), (p, v, a) in all_state.items() if p and v == vmax
         )
+        # the object's legs as children of recover_object (probe and
+        # locking above stay its self time): the gather of source
+        # reads, the decode, the gather of pushes
+        obj_sp = tracing.CURRENT_SPAN.get() or tracing.INERT
         if not is_ec:
             s0, o0 = next(iter(sources.items()))
-            payload, _a, _e = await self._read_shard_quiet(
-                pool, pg, s0, o0, oid
-            )
+            with self.tracer.span("recovery_read", parent=obj_sp):
+                payload, _a, _e = await self._read_shard_quiet(
+                    pool, pg, s0, o0, oid
+                )
             if payload is None:
                 return False
-            results = await asyncio.gather(*(
-                self._push(pool, pg, s, o, oid, payload, src_attrs)
-                for s, o in targets
-            ), return_exceptions=True)  # a dead target must not abort
+            with self.tracer.span("recovery_push", parent=obj_sp):
+                results = await asyncio.gather(*(
+                    self._push(pool, pg, s, o, oid, payload, src_attrs)
+                    for s, o in targets
+                ), return_exceptions=True)  # a dead target must not abort
             return clone_ok and not unprobed and not any(
                 isinstance(r, BaseException) for r in results)
         ec = self._ec_for(pool)
@@ -1234,11 +1280,7 @@ class RecoveryMixin:
                 self._ensure_coll(t, self._shard_coll(pool, pg, my_shard))
                 lg.rollback_divergent(t, oid, ZERO)
                 if t.ops:
-                    if getattr(self.store, "blocking_commit", False):
-                        await asyncio.to_thread(
-                            self.store.queue_transaction, t)
-                    else:
-                        self.store.queue_transaction(t)
+                    await self._commit(t)
                 return True
             v_star = max(candidates)
             log.warning(
@@ -1275,10 +1317,7 @@ class RecoveryMixin:
             t = Transaction()
             self._ensure_coll(t, self._shard_coll(pool, pg, my_shard))
             lg.rollback_divergent(t, oid, v_star)
-            if getattr(self.store, "blocking_commit", False):
-                await asyncio.to_thread(self.store.queue_transaction, t)
-            else:
-                self.store.queue_transaction(t)
+            await self._commit(t)
         need = {s for s, _ in targets}
         # single-shard repair of a regenerating code: thread
         # minimum_to_decode's (sub-chunk offset, count) runs down to
@@ -1314,6 +1353,7 @@ class RecoveryMixin:
         # (the reference's ECSubRead/MOSDPGPush are fire-and-gather)
         chunks: dict[int, np.ndarray] = {}
         used_packed = False
+        read_sp = self.tracer.start_span("recovery_read", parent=obj_sp)
         if repair_extents is not None and set(repair_extents) <= set(sources):
             src_items = [(s, sources[s]) for s in sorted(repair_extents)]
             payloads = await asyncio.gather(*(
@@ -1341,29 +1381,37 @@ class RecoveryMixin:
                 if payload is not None:
                     chunks[s] = np.frombuffer(payload, np.uint8)
             if len(chunks) < k:
+                self.tracer.finish_span(read_sp)
                 log.error(
                     "osd.%d: %s/%s recovery aborted: %d/%d source reads "
                     "succeeded", self.id, pg, oid, len(chunks), k,
                 )
                 return False
+        self.tracer.finish_span(read_sp)
         # the timed decode stage (BASELINE.md #5; reference
         # ECBackend.cc:365-431 handle_recovery_read_complete): measured
-        # IN the running daemon, not inferred from microbenches
+        # IN the running daemon, not inferred from microbenches.  The
+        # span is in scope so the aggregator files this object's wait
+        # for its launch under it (decode_batch_wait)
         _t0 = time.perf_counter()
-        rebuilt = await ecutil.decode_shards_async(
-            sinfo, ec, chunks, need, packed_repair=used_packed,
-            service=self.encode_service,
-            aggregator=self.decode_aggregator,
-        )
+        with self.tracer.span(
+            "recovery_decode", parent=obj_sp, stage="device",
+        ) as dec_sp, tracing.scope(dec_sp):
+            rebuilt = await ecutil.decode_shards_async(
+                sinfo, ec, chunks, need, packed_repair=used_packed,
+                service=self.encode_service,
+                aggregator=self.decode_aggregator,
+            )
         self.perf.inc("recovery_decode_seconds",
                       time.perf_counter() - _t0)
         self.perf.inc("recovery_decode_bytes",
                       sum(v.nbytes for v in rebuilt.values()))
-        results = await asyncio.gather(*(
-            self._push(pool, pg, s, o, oid, rebuilt[s].tobytes(), src_attrs,
-                       force=force_push)
-            for s, o in targets
-        ), return_exceptions=True)  # dead targets retry on the next pass
+        with self.tracer.span("recovery_push", parent=obj_sp):
+            results = await asyncio.gather(*(
+                self._push(pool, pg, s, o, oid, rebuilt[s].tobytes(),
+                           src_attrs, force=force_push)
+                for s, o in targets
+            ), return_exceptions=True)  # dead targets retry next pass
         return not unprobed and not any(
             isinstance(r, BaseException) for r in results)
 
@@ -1963,11 +2011,14 @@ class RecoveryMixin:
         self._push_waiters[tid] = fut
         try:
             conn = await self._osd_conn(osd)
-            await conn.send_message(MOSDPGPush(
+            push = MOSDPGPush(
                 pg=pg, shard=shard, from_osd=self.id,
                 pushes=[(oid, payload, attrs)], epoch=self.epoch,
                 force=force, tid=tid,
-            ))
+            )
+            # the object's context: the target's store_commit joins
+            push.trace = self.tracer.ctx_for(tracing.CURRENT_SPAN.get())
+            await conn.send_message(push)
             await asyncio.wait_for(fut, SUBOP_TIMEOUT)
         finally:
             self._push_waiters.pop(tid, None)
@@ -1990,11 +2041,7 @@ class RecoveryMixin:
                         t.write(c, co, 0, payload)
                     if attrs:
                         t.setattrs(c, co, attrs)
-                    if getattr(self.store, "blocking_commit", False):
-                        await asyncio.to_thread(
-                            self.store.queue_transaction, t)
-                    else:
-                        self.store.queue_transaction(t)
+                    await self._commit(t)
                 continue
             # never regress: a write may have landed here between the
             # primary's probe and this push (the reference serializes
@@ -2012,11 +2059,7 @@ class RecoveryMixin:
                 t0 = Transaction()
                 self._pg_log(c).rollback_divergent(t0, oid, pushed_v)
                 if t0.ops:
-                    if getattr(self.store, "blocking_commit", False):
-                        await asyncio.to_thread(
-                            self.store.queue_transaction, t0)
-                    else:
-                        self.store.queue_transaction(t0)
+                    await self._commit(t0)
             # a push REPLACES the object: stale local attrs the source
             # doesn't carry (e.g. a hinfo dropped by an RMW this member
             # missed) must go, or deep scrub sees a phantom crc chain
@@ -2025,11 +2068,17 @@ class RecoveryMixin:
                 stale_attrs = [
                     n for n in self.store.getattrs(c, o) if n not in attrs
                 ]
-            await self._apply_shard_write_async(
-                pool, msg.pg, msg.shard, oid, payload, attrs,
-                rmattrs=stale_attrs,
-            )
-        await msg.conn.send_message(MOSDPGPushReply(
+            with self._maybe_span(
+                "store_commit", ctx=msg.trace, stage="store",
+                shard=msg.shard, oid=oid,
+            ) as commit_sp:
+                await self._apply_shard_write_async(
+                    pool, msg.pg, msg.shard, oid, payload, attrs,
+                    rmattrs=stale_attrs, commit_span=commit_sp,
+                )
+        rep = MOSDPGPushReply(
             pg=msg.pg, shard=msg.shard, from_osd=self.id, epoch=self.epoch,
             tid=msg.tid,
-        ))
+        )
+        rep.trace = msg.trace
+        await msg.conn.send_message(rep)

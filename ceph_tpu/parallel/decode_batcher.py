@@ -38,9 +38,11 @@ from __future__ import annotations
 import asyncio
 import collections
 import threading
+import time
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.metrics import BucketCounters
 
 #: padded widths below this stay in one bucket — tiny decodes all share
@@ -120,7 +122,11 @@ class DecodeAggregator:
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         key = D.shape[0].to_bytes(2, "little") + D.tobytes()
-        self._pending.setdefault(key, []).append((D, rows, fut))
+        # the caller's span in scope (recovery_decode) and the arrival
+        # ride along: the first launch that serves this request files
+        # its wait under it
+        self._pending.setdefault(key, []).append(
+            (D, rows, fut, tracing.CURRENT_SPAN.get(), time.monotonic()))
         self.stats["requests"] += 1
         if self._flush_handle is None:
             self._flush_handle = loop.call_later(self.window_s, self._flush)
@@ -165,8 +171,8 @@ class DecodeAggregator:
 
             self.stats["fallbacks"] += 1
             outs = await asyncio.to_thread(
-                lambda: [gf_matmul(D, rows) for D, rows, _ in group])
-        for (_, _, fut), out in zip(group, outs):
+                lambda: [gf_matmul(D, rows) for D, rows, *_ in group])
+        for (_, _, fut, *_), out in zip(group, outs):
             if not fut.done():
                 fut.set_result(out)
 
@@ -181,7 +187,7 @@ class DecodeAggregator:
         bucket.  Every lane therefore lands in the CLOSED ladder
         [min_bucket .. tile_cap] that prewarm compiles in full."""
         plan: dict[int, list[tuple[int, int, int]]] = {}
-        for i, (_, rows, _) in enumerate(group):
+        for i, (_, rows, *_) in enumerate(group):
             s = rows.shape[1]
             if s <= self.tile_cap:
                 w = pow2_bucket(s, self.min_bucket)
@@ -206,8 +212,9 @@ class DecodeAggregator:
         out_rows = bits.shape[0] // 8
         outs = [
             np.empty((out_rows, rows.shape[1]), np.uint8)
-            for _, rows, _ in group
+            for _, rows, *_ in group
         ]
+        waiting = set(range(len(group)))   # not yet served by a launch
         for w, lanes in self._bucket_plan(group).items():
             for at in range(0, len(lanes), self.max_batch):
                 chunk = lanes[at:at + self.max_batch]
@@ -228,22 +235,24 @@ class DecodeAggregator:
                 # device-launch profiling span: bucket shape, lane
                 # occupancy and block-until-ready time, per launch —
                 # padding waste becomes visible in `ceph trace`/mgr
-                from ceph_tpu.common.tracing import device_tracer
                 from ceph_tpu.common.transfer_guard import (
                     no_implicit_transfers,
                 )
 
+                served = {gi for gi, _, _ in chunk} & waiting
+                waiting -= served
                 # transfers are EXPLICIT by construction: device_put
                 # uploads the padded batch, device_get gathers the
                 # whole launch result once (the by-design host exit —
                 # rebuilt shards persist to the store); the guard
                 # turns any implicit transfer sneaking in between
                 # into a counted violation + host fallback
-                with device_tracer().span(
-                    "xla_launch", stage="device", kind="decode_batch",
-                    w=w, b=b, b_real=b_real,
+                with tracing.launch_span(
+                    "decode_batch_wait",
+                    [group[gi][3:] for gi in sorted(served)],
+                    kind="decode_batch", w=w, b=b, b_real=b_real,
                     occupancy=round(b_real / b, 3), cold=cold,
-                ) as _dsp, no_implicit_transfers("decode_batch"):
+                ), no_implicit_transfers("decode_batch"):
                     out = jax.device_get(jax.block_until_ready(
                         gf_bitmatmul(bits, jax.device_put(batch))))
                 self.stats["launches"] += 1

@@ -24,9 +24,11 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import time
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.metrics import BucketCounters
 from ceph_tpu.parallel.decode_batcher import pow2_bucket
 
@@ -88,7 +90,10 @@ class EncodeService:
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         key = M.shape[0].to_bytes(2, "little") + M.tobytes()
-        self._pending.setdefault(key, []).append((M, rows, fut))
+        # the caller's span in scope (the op's ec_encode) and the arrival
+        # ride along: the launch files this request's wait under it
+        self._pending.setdefault(key, []).append(
+            (M, rows, fut, tracing.CURRENT_SPAN.get(), time.monotonic()))
         if self._flush_handle is None:
             self._flush_handle = loop.call_later(self.window_s, self._flush)
         return await fut
@@ -142,8 +147,8 @@ class EncodeService:
 
             self.stats["fallbacks"] += 1
             outs = await asyncio.to_thread(
-                lambda: [gf_matmul(M, rows) for M, rows, _ in group])
-        for (_, _, fut), out in zip(group, outs):
+                lambda: [gf_matmul(M, rows) for M, rows, *_ in group])
+        for (_, _, fut, *_), out in zip(group, outs):
             if not fut.done():
                 fut.set_result(out)
 
@@ -176,7 +181,7 @@ class EncodeService:
             return self._run_group_single(group, bits, k)
 
         if len(group) == 1 and "shard" in self.mesh.shape:
-            _, rows, _fut = group[0]
+            rows = group[0][1]
             nsh = self.mesh.shape["shard"]
             if nsh > 1 and k % nsh == 0:
                 # same fixed-bucket discipline as the dp path: pad S to
@@ -191,7 +196,8 @@ class EncodeService:
                     tp_data_sharding,
                 )
 
-                with self._note_shape(("tp", bits.shape, k, S), w=S):
+                with self._note_shape(("tp", bits.shape, k, S), group,
+                                      w=S):
                     res = sharded_encode_tp(
                         self.mesh, bits, jax.device_put(
                             padded, tp_data_sharding(self.mesh)))
@@ -209,17 +215,17 @@ class EncodeService:
         ndev = 1
         for ax in self.mesh.shape.values():
             ndev *= ax
-        widths = [rows.shape[1] for _, rows, _ in group]
+        widths = [rows.shape[1] for _, rows, *_ in group]
         S = pow2_bucket(max(widths), 1)
         B = ndev * pow2_bucket(-(-len(group) // ndev), 1)
         batch = np.zeros((B, k, S), np.uint8)
-        for i, (_, rows, _) in enumerate(group):
+        for i, (_, rows, *_) in enumerate(group):
             batch[i, :, : rows.shape[1]] = rows
         axes = tuple(a for a in ("pg", "shard") if a in self.mesh.shape)
         from ceph_tpu.parallel.encode_farm import dp_batch_sharding
 
-        with self._note_shape(("dp", bits.shape, B, k, S), w=S, b=B,
-                              b_real=len(group)):
+        with self._note_shape(("dp", bits.shape, B, k, S), group, w=S,
+                              b=B):
             res = batch_encode_dp(
                 self.mesh, bits, jax.device_put(
                     batch, dp_batch_sharding(self.mesh, axes)),
@@ -244,24 +250,24 @@ class EncodeService:
         self.stats["mesh_devices_used"] = max(
             self.stats["mesh_devices_used"], len(res.sharding.device_set))
 
-    def _note_shape(self, shape_key: tuple, *, w: int, b: int = 1,
-                    b_real: int = 1):
+    def _note_shape(self, shape_key: tuple, group: list[tuple], *,
+                    w: int, b: int = 1):
         """Track whether a launch shape was already compiled (a miss is
         a cold in-path compile the warmup should have covered) and
-        return the device-launch profiling span wrapping the launch."""
+        return the device-launch profiling span wrapping the launch;
+        every traced request of ``group`` gets its ``encode_batch_wait``
+        filed (arrival -> here) and the launch names their spans."""
         cold = shape_key not in self._warm
         if cold:
             self._warm.add(shape_key)
             self.stats["cold_launches"] += 1
             self.metrics.inc("cold_launches", w=w, b=b)
-        from ceph_tpu.common.tracing import device_tracer
-
-        return device_tracer().span(
-            "xla_launch", stage="device",
+        b_real = len(group)
+        return tracing.launch_span(
+            "encode_batch_wait", [req[3:] for req in group],
             kind=f"encode_{shape_key[0]}", w=w, b=b, b_real=b_real,
             occupancy=round(b_real / max(b, 1), 3), cold=cold,
         )
-
 
     def _run_group_single(self, group: list[tuple], bits, k) -> list[np.ndarray]:
         """Single-device dispatch: concatenate every request's rows
@@ -273,16 +279,16 @@ class EncodeService:
         from ceph_tpu.common.transfer_guard import no_implicit_transfers
         from ceph_tpu.ops.rs_kernels import BitmatrixCodec
 
-        widths = [rows.shape[1] for _, rows, _ in group]
+        widths = [rows.shape[1] for _, rows, *_ in group]
         total = sum(widths)
         S = pow2_bucket(total, 1)  # fixed pow2 width bucket
         big = np.zeros((k, S), np.uint8)
         off = 0
-        for (_, rows, _), w in zip(group, widths):
+        for (_, rows, *_), w in zip(group, widths):
             big[:, off:off + w] = rows
             off += w
-        with self._note_shape(("single", bits.shape, k, S), w=S,
-                              b_real=len(group)), \
+        with self._note_shape(("single", bits.shape, k, S), group,
+                              w=S), \
                 no_implicit_transfers("encode_single"):
             out = jax.device_get(BitmatrixCodec._apply(
                 bits, jax.device_put(big), None))

@@ -38,6 +38,7 @@ import json
 import os
 import struct
 import threading
+import time
 
 from ceph_tpu.common.fault_injector import (
     InjectedError,
@@ -475,7 +476,10 @@ class BlockStore(ObjectStore):
 
     def queue_transaction(self, txn: Transaction) -> None:
         store_fault_check("write", self.fault_domain)
+        marks = txn.marks   # phase boundaries, for the submitter to read
+        marks["enter"] = time.monotonic()
         with self._txn_lock:
+            marks["locked"] = time.monotonic()
             self._validate(txn)
             batch = WriteBatch()
             view = _TxnView(self.db, batch)
@@ -483,10 +487,12 @@ class BlockStore(ObjectStore):
             wrote_block = False
             for op in txn.ops:
                 wrote_block |= self._translate(op, view, freed)
+            marks["data"] = time.monotonic()    # pwrite + crc done
             if wrote_block:
                 # ordering invariant: blob data durable BEFORE the kv
                 # commit that references it
                 os.fsync(self._fd)
+            marks["fsync"] = time.monotonic()
             tear = store_data_fault("write", self.fault_domain)
             if tear is not None and tear.get("torn"):
                 # torn write: blob data hit the platter but the kv
@@ -498,6 +504,7 @@ class BlockStore(ObjectStore):
                     5, "injected torn write (kv commit dropped)")
             store_fault_check("commit", self.fault_domain)
             self.db.submit(batch)
+            marks["kv"] = time.monotonic()
             for blob in freed:
                 self._deref_blob(blob)
         for cb in txn.on_applied:

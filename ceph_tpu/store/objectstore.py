@@ -74,11 +74,18 @@ class Transaction:
 
     Callbacks mirror the reference's contexts: ``on_applied`` fires when
     the transaction is readable, ``on_commit`` when durable (in MemStore
-    both fire at apply, as the reference MemStore does)."""
+    both fire at apply, as the reference MemStore does).
+
+    ``marks`` is the store's to fill while it applies the transaction:
+    ``time.monotonic()`` at the boundaries of its commit phases
+    (BlockStore: ``enter``, ``locked``, ``data``, ``fsync``, ``kv``), for
+    whoever submitted it to read afterwards.  A store with no phases
+    worth telling apart leaves it empty."""
 
     ops: list[tuple] = field(default_factory=list)
     on_applied: list[Callable[[], None]] = field(default_factory=list)
     on_commit: list[Callable[[], None]] = field(default_factory=list)
+    marks: dict[str, float] = field(default_factory=dict)
 
     def touch(self, c: coll_t, o: ghobject_t) -> "Transaction":
         self.ops.append((TxOp.TOUCH, c, o))

@@ -231,8 +231,10 @@ class TestAdminSocket:
         """trace_ring_max replaces the hardcoded 2048-span ring."""
         from ceph_tpu.common.tracing import Tracer
 
+        # unsampled, kept in the ring for tail capture to look at (with
+        # tail capture off too, nothing would be built at all)
         t = Tracer("ring-test", ring_max=4, sample_rate=0.0,
-                   tail_slow_s=None)
+                   tail_slow_s=60.0)
         for i in range(10):
             with t.span(f"s{i}"):
                 pass
