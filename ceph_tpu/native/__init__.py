@@ -8,6 +8,7 @@ fallbacks so the package works before/without a toolchain.
 
 Public API:
   crc32c(data, seed=-1)          -- reference ceph_crc32c semantics
+  crc_backend()                  -- which code computes it on this host
   crc32c_zeros(length, seed=-1)  -- crc of `length` zero bytes
   xor_region(dst, src)           -- dst ^= src in place (uint8 arrays)
   available()                    -- True when the .so is loaded
@@ -75,8 +76,10 @@ def _load():
             return None
         lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
         lib.ceph_tpu_crc32c.argtypes = [
-            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
         ]
+        lib.ceph_tpu_crc_backend.restype = ctypes.c_char_p
+        lib.ceph_tpu_crc_backend.argtypes = []
         lib.ceph_tpu_xor_region.restype = None
         lib.ceph_tpu_xor_region.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
@@ -140,27 +143,49 @@ def _py_crc32c(data: bytes, seed: int) -> int:
 
 # -- public API -------------------------------------------------------------
 
-def crc32c(data, seed: int = 0xFFFFFFFF) -> int:
-    """Reference ceph_crc32c(seed, data, len): reflected CRC32C table
-    update, no init/final inversion (sctp_crc32.c:update_crc32)."""
+def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
+    """Reference ceph_crc32c(seed, data, len): reflected CRC32C update,
+    no init/final inversion (sctp_crc32.c:update_crc32).  ``table``
+    forces the slice-by-8 path, the oracle of the hardware one."""
+    lib = _load()
+    if lib is None:
+        return _py_crc32c(
+            data if isinstance(data, (bytes, bytearray, memoryview))
+            else np.asarray(data, dtype=np.uint8).tobytes(), seed)
+    seed &= 0xFFFFFFFF
+    # the buffer's address, with no numpy array on the way: bytes go to
+    # ctypes as they are, a writable buffer (bytearray, a received
+    # segment's memoryview) lends its memory for the call
+    if isinstance(data, bytes):
+        return lib.ceph_tpu_crc32c(seed, data, len(data), table)
+    if isinstance(data, bytearray) or (
+            isinstance(data, memoryview) and not data.readonly
+            and data.c_contiguous):
+        n = data.nbytes if isinstance(data, memoryview) else len(data)
+        if n == 0:
+            return seed
+        return lib.ceph_tpu_crc32c(
+            seed, (ctypes.c_char * n).from_buffer(data), n, table)
     arr = np.ascontiguousarray(
         np.frombuffer(data, dtype=np.uint8)
-        if isinstance(data, (bytes, bytearray, memoryview))
+        if isinstance(data, memoryview)
         else np.asarray(data, dtype=np.uint8).reshape(-1)
     )
+    return lib.ceph_tpu_crc32c(seed, arr.ctypes.data, arr.nbytes, table)
+
+
+def crc_backend() -> str:
+    """"sse4.2" / "armv8" (the CPU's CRC32C instruction), "table"
+    (slice-by-8 in the native library) or "python" (no library)."""
     lib = _load()
-    if lib is not None:
-        return lib.ceph_tpu_crc32c(
-            seed & 0xFFFFFFFF, arr.ctypes.data, arr.nbytes
-        )
-    return _py_crc32c(arr.tobytes(), seed)
+    return "python" if lib is None else lib.ceph_tpu_crc_backend().decode()
 
 
 def crc32c_zeros(length: int, seed: int = 0xFFFFFFFF) -> int:
     """crc32c of `length` zero bytes (reference crc32c.cc:216)."""
     lib = _load()
     if lib is not None:
-        return lib.ceph_tpu_crc32c(seed & 0xFFFFFFFF, None, length)
+        return lib.ceph_tpu_crc32c(seed & 0xFFFFFFFF, None, length, 0)
     t = _py_table()
     crc = seed & 0xFFFFFFFF
     for _ in range(length):

@@ -4,12 +4,30 @@
 // (reference src/common/sctp_crc32.c:update_crc32 — plain reflected
 // table update, caller passes the seed, no init/final inversion;
 // reference src/common/crc32c.cc:216 ceph_crc32c_zeros for the
-// null-buffer "crc of zeros" path).  Slice-by-8 for throughput; the
-// build wires SSE4.2/ARMv8 hardware CRC when -march allows, matching
-// the reference's runtime-dispatch intent without the asm files.
+// null-buffer "crc of zeros" path).  Two code paths, one value: the
+// CPU's CRC32C instruction where it has one (x86-64 SSE4.2, found at
+// run time with __builtin_cpu_supports, so the build needs no -m flag;
+// aarch64 where the compiler defines __ARM_FEATURE_CRC32), three
+// streams interleaved to hide the instruction's latency, and the
+// slice-by-8 tables everywhere else.  The tables stay the oracle:
+// `table_only` forces them, for the tests.
 #include <cstddef>
 #include <cstdint>
 #include <cstring>
+
+#if defined(__x86_64__)
+#include <nmmintrin.h>
+#define CRC_HW_NAME "sse4.2"
+#define CRC_HW_TARGET __attribute__((target("sse4.2")))
+#define CRC_HW_U8(c, v) _mm_crc32_u8(static_cast<uint32_t>(c), v)
+#define CRC_HW_U64(c, v) _mm_crc32_u64(c, v)
+#elif defined(__aarch64__) && defined(__ARM_FEATURE_CRC32)
+#include <arm_acle.h>
+#define CRC_HW_NAME "armv8"
+#define CRC_HW_TARGET
+#define CRC_HW_U8(c, v) __crc32cb(static_cast<uint32_t>(c), v)
+#define CRC_HW_U64(c, v) __crc32cd(static_cast<uint32_t>(c), v)
+#endif
 
 namespace {
 
@@ -32,21 +50,17 @@ struct Tables {
 };
 const Tables kT;
 
-}  // namespace
-
-extern "C" {
-
-// Matches ceph_crc32c(seed, data, len); data may be null (= zeros).
-uint32_t ceph_tpu_crc32c(uint32_t crc, const uint8_t* data, size_t len) {
-  if (data == nullptr) {
-    // crc of `len` zero bytes: the byte step degenerates to
-    // crc = T[crc & 0xff] ^ (crc >> 8); once crc hits 0 it stays 0.
-    while (len >= 1 && crc != 0) {
-      crc = kT.t[0][crc & 0xff] ^ (crc >> 8);
-      len--;
-    }
-    return crc;
+// crc of `len` zero bytes: the byte step degenerates to
+// crc = T[crc & 0xff] ^ (crc >> 8); once crc hits 0 it stays 0.
+uint32_t crc_zeros(uint32_t crc, size_t len) {
+  while (len >= 1 && crc != 0) {
+    crc = kT.t[0][crc & 0xff] ^ (crc >> 8);
+    len--;
   }
+  return crc;
+}
+
+uint32_t crc_table(uint32_t crc, const uint8_t* data, size_t len) {
   while (len && (reinterpret_cast<uintptr_t>(data) & 7)) {
     crc = kT.t[0][(crc ^ *data++) & 0xff] ^ (crc >> 8);
     len--;
@@ -65,6 +79,85 @@ uint32_t ceph_tpu_crc32c(uint32_t crc, const uint8_t* data, size_t len) {
   while (len--) crc = kT.t[0][(crc ^ *data++) & 0xff] ^ (crc >> 8);
   return crc;
 }
+
+#ifdef CRC_HW_NAME
+// Three streams of kLane bytes run side by side (the instruction has a
+// latency of three cycles and a throughput of one); the update is
+// linear, so crc(A||B) = advance(crc(A), |B| zero bytes) ^ crc_0(B),
+// and kAdvance is that advance over kLane zero bytes, by byte of crc.
+constexpr size_t kLane = 2048;
+
+struct Advance {
+  uint32_t t[4][256];
+  Advance() {
+    for (int k = 0; k < 4; k++)
+      for (uint32_t b = 0; b < 256; b++) t[k][b] = crc_zeros(b << (8 * k), kLane);
+  }
+  uint32_t operator()(uint64_t c) const {
+    return t[0][c & 0xff] ^ t[1][(c >> 8) & 0xff] ^ t[2][(c >> 16) & 0xff] ^
+           t[3][(c >> 24) & 0xff];
+  }
+};
+const Advance kAdvance;
+
+inline uint64_t load64(const uint8_t* p) {
+  uint64_t v;
+  std::memcpy(&v, p, 8);
+  return v;
+}
+
+CRC_HW_TARGET uint32_t crc_hw(uint32_t crc, const uint8_t* data, size_t len) {
+  uint64_t c0 = crc;
+  while (len && (reinterpret_cast<uintptr_t>(data) & 7)) {
+    c0 = CRC_HW_U8(c0, *data++);
+    len--;
+  }
+  while (len >= 3 * kLane) {
+    uint64_t c1 = 0, c2 = 0;
+    for (size_t i = 0; i < kLane; i += 8) {
+      c0 = CRC_HW_U64(c0, load64(data + i));
+      c1 = CRC_HW_U64(c1, load64(data + kLane + i));
+      c2 = CRC_HW_U64(c2, load64(data + 2 * kLane + i));
+    }
+    c0 = kAdvance(c0) ^ c1;
+    c0 = kAdvance(c0) ^ c2;
+    data += 3 * kLane;
+    len -= 3 * kLane;
+  }
+  while (len >= 8) {
+    c0 = CRC_HW_U64(c0, load64(data));
+    data += 8;
+    len -= 8;
+  }
+  while (len--) c0 = CRC_HW_U8(c0, *data++);
+  return static_cast<uint32_t>(c0);
+}
+
+#if defined(__x86_64__)
+const bool kHaveHw = __builtin_cpu_supports("sse4.2");
+#else
+const bool kHaveHw = true;
+#endif
+#else
+#define CRC_HW_NAME "table"
+const bool kHaveHw = false;
+constexpr auto crc_hw = crc_table;
+#endif
+
+}  // namespace
+
+extern "C" {
+
+// Matches ceph_crc32c(seed, data, len); data may be null (= zeros).
+uint32_t ceph_tpu_crc32c(uint32_t crc, const uint8_t* data, size_t len,
+                         int table_only) {
+  if (data == nullptr) return crc_zeros(crc, len);
+  return kHaveHw && !table_only ? crc_hw(crc, data, len)
+                                : crc_table(crc, data, len);
+}
+
+// Which path ceph_tpu_crc32c takes on this CPU.
+const char* ceph_tpu_crc_backend() { return kHaveHw ? CRC_HW_NAME : "table"; }
 
 // XOR-accumulate src into dst (region parity; reference
 // src/erasure-code/isa/xor_op.cc semantics, compiler-vectorized).

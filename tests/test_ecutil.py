@@ -58,6 +58,115 @@ def test_crc32c_chaining_splits():
         assert native.crc32c(buf[cut:], native.crc32c(buf[:cut])) == whole
 
 
+# the CPU's CRC32C instruction against its two oracles: the slice-by-8
+# tables (`table=True`, an argument of the C entry point) and the
+# pure-Python byte loop.  Lengths straddle the three-stream block
+# (3 x 2048 bytes) and its multiples.
+CRC_LENGTHS = [0, 1, 7, 8, 9, 63, 2047, 2048, 6143, 6144, 6145, 12288,
+               18433, 65536, 70000]
+
+
+def _crc_bytes(n: int, salt: int = 0) -> bytes:
+    return np.random.default_rng(1000 + n + salt).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def test_crc_backend_names_a_real_backend():
+    assert native.available()
+    assert native.crc_backend() in ("sse4.2", "armv8", "table")
+    with open("/proc/cpuinfo") as f:
+        flags = f.read()
+    if "sse4_2" in flags:
+        assert native.crc_backend() == "sse4.2"
+
+
+@pytest.mark.parametrize("n", CRC_LENGTHS)
+def test_crc32c_hardware_equals_table_equals_python(n):
+    buf = _crc_bytes(n)
+    rng = np.random.default_rng(n)
+    for seed in (0, 0xFFFFFFFF, -1, int(rng.integers(0, 2**32))):
+        want = native._py_crc32c(buf, seed)
+        assert native.crc32c(buf, seed, table=True) == want, (n, seed)
+        assert native.crc32c(buf, seed) == want, (n, seed)
+
+
+def test_crc32c_random_lengths_hardware_equals_table():
+    rng = np.random.default_rng(7)
+    for n in rng.integers(0, 70001, 200):
+        buf = _crc_bytes(int(n), salt=1)
+        seed = int(rng.integers(0, 2**32))
+        assert native.crc32c(buf, seed) == native.crc32c(
+            buf, seed, table=True), (n, seed)
+
+
+def test_crc32c_4MiB_all_paths_agree():
+    buf = _crc_bytes(4 << 20)
+    want = native._py_crc32c(buf, 0xFFFFFFFF)
+    assert native.crc32c(buf, table=True) == want
+    assert native.crc32c(buf) == want
+    assert native.crc32c(buf, 0) == native.crc32c(buf, 0, table=True)
+
+
+@pytest.mark.parametrize("align", range(16))
+def test_crc32c_every_alignment_of_an_offset_memoryview(align):
+    n = 3 * 6144 + 77
+    raw = bytearray(_crc_bytes(n + 16))
+    for view in (memoryview(raw)[align:align + n],
+                 memoryview(bytes(raw))[align:align + n]):
+        want = native.crc32c(bytes(view), table=True)
+        assert native.crc32c(view) == want
+        assert native.crc32c(view, table=True) == want
+    assert native._py_crc32c(raw[align:align + 500], 0xFFFFFFFF) == \
+        native.crc32c(memoryview(raw)[align:align + 500])
+
+
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "memoryview",
+                                  "readonly_memoryview", "numpy",
+                                  "numpy_strided", "numpy_2d"])
+def test_crc32c_input_kinds(kind):
+    buf = _crc_bytes(20000)
+    want = native.crc32c(buf, 99, table=True)
+    arr = np.frombuffer(buf, dtype=np.uint8)
+    data = {
+        "bytes": buf,
+        "bytearray": bytearray(buf),
+        "memoryview": memoryview(bytearray(buf)),
+        "readonly_memoryview": memoryview(buf),
+        "numpy": arr,
+        "numpy_strided": np.repeat(arr, 2)[::2],
+        "numpy_2d": arr.reshape(100, 200),
+    }[kind]
+    assert native.crc32c(data, 99) == want
+    assert native.crc32c(data, 99, table=True) == want
+    empty = {"bytes": b"", "bytearray": bytearray(),
+             "memoryview": memoryview(bytearray()),
+             "readonly_memoryview": memoryview(b"")}.get(kind)
+    if empty is not None:
+        assert native.crc32c(empty, 99) == 99
+
+
+@pytest.mark.parametrize("n", [1000, 6144, 3 * 6144 + 5, 70000])
+def test_crc32c_chaining_across_paths(n):
+    # crc(b, crc(a)) == crc(a + b), whichever path computed either part
+    buf = _crc_bytes(n, salt=2)
+    whole = native.crc32c(buf, table=True)
+    for cut in (0, 1, 8, n // 3, n // 2, n - 1, n):
+        a, b = buf[:cut], buf[cut:]
+        assert native.crc32c(b, native.crc32c(a)) == whole
+        assert native.crc32c(b, native.crc32c(a, table=True)) == whole
+        assert native.crc32c(b, native.crc32c(a), table=True) == whole
+
+
+@pytest.mark.parametrize("seed", [0, 1, 1234, 0xFFFFFFFF])
+def test_crc32c_zeros_unchanged(seed):
+    # the nullptr path never reaches the instruction: same loop as
+    # before, and equal to both paths over an explicit zero buffer
+    for n in (0, 1, 15, 16, 17, 4096, 6144, 100000):
+        z = native.crc32c_zeros(n, seed)
+        assert z == native.crc32c(b"\0" * n, seed)
+        assert z == native.crc32c(b"\0" * n, seed, table=True)
+
+
 def test_xor_region():
     rng = np.random.default_rng(3)
     a = rng.integers(0, 256, 4097, dtype=np.uint8)
