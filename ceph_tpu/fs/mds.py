@@ -163,7 +163,8 @@ class MDSDaemon:
             )
             self._admin.register(
                 "perf dump", "dump perf counters",
-                lambda cmd: self.perf.dump(),
+                lambda cmd: {**self.perf.dump(),
+                             **self.messenger.perf_dump()},
             )
             self._admin.register(
                 "status", "daemon status",

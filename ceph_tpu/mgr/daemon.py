@@ -314,7 +314,8 @@ class MgrDaemon:
         )
         sock.register(
             "perf dump", "dump perf counters",
-            lambda cmd: self.perf.dump(),
+            lambda cmd: {**self.perf.dump(),
+                         **self.messenger.perf_dump()},
         )
         sock.register(
             "dump_traces", "recent spans of this mgr's tracer "

@@ -255,7 +255,8 @@ class Monitor(OSDMonitorMixin, StatsServiceMixin, MgrServiceMixin,
             )
             self._admin.register(
                 "perf dump", "dump perf counters",
-                lambda cmd: self.perf.dump(),
+                lambda cmd: {**self.perf.dump(),
+                             **self.messenger.perf_dump()},
             )
             await self._admin.start()
         await self._replay()

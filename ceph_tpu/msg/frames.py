@@ -52,12 +52,224 @@ class FrameError(ConnectionError):
     pass
 
 
-async def send_banner(writer: asyncio.StreamWriter, features: int = 1) -> None:
+# what a Messenger counts about its wire (``Messenger.stats``, ``perf
+# dump``'s ``msgr_*``): recv_calls / frames_in and reader_wakeups /
+# frames_in say how many socket reads and how many resumptions of the
+# reader coroutine one frame costs
+STATS = ("frames_in", "bytes_in", "recv_calls", "reader_wakeups",
+         "frames_out", "bytes_out")
+
+
+class FrameStream(asyncio.BufferedProtocol):
+    """One connection's socket, both directions, under the method names
+    the rest of msg/ uses on an asyncio stream pair (``readexactly``,
+    ``write``/``writelines``/``drain``/``close``), so it is passed as
+    reader and writer alike.
+
+    What it adds is ``readinto``: the socket fills the caller's buffer
+    itself (``recv_into`` on the buffer's unfilled tail) and the caller
+    is resumed once, when the buffer is full — a frame's segments are
+    received where they stay.  ``readexactly`` serves the short control
+    reads (banner, preamble) from a small staging buffer, which also
+    takes whatever arrives while no read is posted: a burst of small
+    frames lands there in one recv and is split without suspending.
+    """
+
+    STAGE = 64 * 1024
+
+    def __init__(self, stats: dict | None = None, on_connect=None):
+        self.stats = dict.fromkeys(STATS, 0) if stats is None else stats
+        self._on_connect = on_connect   # acceptor side: coroutine(stream)
+        self._task: asyncio.Task | None = None
+        self._loop = asyncio.get_running_loop()
+        self._transport: asyncio.Transport | None = None
+        self._stage = memoryview(bytearray(self.STAGE))
+        self._r = self._w = 0           # staged, unread: _stage[_r:_w]
+        self._dest: memoryview | None = None   # readinto's buffer ...
+        self._dest_at = 0                      # ... filled this far
+        self._need = 0      # staged bytes a parked readexactly wants
+        self._waiter: asyncio.Future | None = None
+        self._eof = False
+        self._exc: BaseException | None = None
+        self._lost = False
+        self._read_paused = False
+        self._write_paused = False
+        self._drainers: list[asyncio.Future] = []
+
+    # -- transport callbacks -------------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self._transport = transport
+        if self._on_connect is not None:
+            self._task = self._loop.create_task(self._on_connect(self))
+            self._task.add_done_callback(self._on_connect_done)
+
+    def _on_connect_done(self, task: asyncio.Task) -> None:
+        # what asyncio's own stream server does with a handler's crash
+        if not task.cancelled() and task.exception() is not None:
+            self._loop.call_exception_handler({
+                "message": "unhandled exception in a connection handler",
+                "exception": task.exception(),
+                "transport": self._transport,
+            })
+            self._transport.close()
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._dest is not None:
+            return self._dest[self._dest_at:]
+        return self._stage[self._w:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        self.stats["recv_calls"] += 1
+        self.stats["bytes_in"] += nbytes
+        if self._dest is not None:
+            self._dest_at += nbytes
+            if self._dest_at == len(self._dest):
+                self._dest = None
+                self._wake()
+            return
+        self._w += nbytes
+        if self._w == len(self._stage):
+            self._compact()
+            if self._w == len(self._stage):
+                # full and nobody reading: the kernel holds the rest
+                self._read_paused = True
+                self._transport.pause_reading()
+        if self._w - self._r >= self._need:
+            self._wake()
+
+    def eof_received(self) -> bool:
+        self._eof = True
+        self._wake()
+        return False        # the transport closes itself
+
+    def connection_lost(self, exc) -> None:
+        self._lost = True
+        self._eof = True
+        self._exc = exc
+        self._wake()
+        self._wake_drainers()
+
+    def pause_writing(self) -> None:
+        self._write_paused = True
+
+    def resume_writing(self) -> None:
+        self._write_paused = False
+        self._wake_drainers()
+
+    def _wake_drainers(self) -> None:
+        for fut in self._drainers:
+            if not fut.done():
+                fut.set_result(None)
+
+    # -- reading -------------------------------------------------------
+
+    def _wake(self) -> None:
+        if self._waiter is not None and not self._waiter.done():
+            self._waiter.set_result(None)
+
+    def _compact(self) -> None:
+        if self._r:
+            n = self._w - self._r
+            self._stage[:n] = bytes(self._stage[self._r:self._w])
+            self._r, self._w = 0, n
+
+    def _consumed(self) -> None:
+        """Staged bytes were taken: rewind an empty stage, and let the
+        socket deliver again if the full stage had stopped it."""
+        if self._r == self._w:
+            self._r = self._w = 0
+        if self._read_paused and (self._dest is not None
+                                  or self._w < len(self._stage)):
+            self._read_paused = False
+            self._transport.resume_reading()
+
+    async def _park(self, partial, expected: int) -> None:
+        """Suspend the one reader until the transport callbacks wake
+        it; the stream's end or loss raises what a StreamReader raises
+        (``partial`` is called for the bytes got so far)."""
+        if self._exc is not None:
+            raise self._exc
+        if self._eof:
+            raise asyncio.IncompleteReadError(partial(), expected)
+        assert self._waiter is None, "one reader per stream"
+        self._waiter = self._loop.create_future()
+        try:
+            await self._waiter
+        finally:
+            self._waiter = None
+        self.stats["reader_wakeups"] += 1
+
+    async def readexactly(self, n: int) -> bytes:
+        if n > len(self._stage):
+            buf = bytearray(n)
+            await self.readinto(memoryview(buf))
+            return bytes(buf)
+        while self._w - self._r < n:
+            if self._r + n > len(self._stage):
+                self._compact()
+                self._consumed()
+            self._need = n
+            await self._park(
+                lambda: bytes(self._stage[self._r:self._w]), n)
+        data = bytes(self._stage[self._r:self._r + n])
+        self._r += n
+        self._consumed()
+        return data
+
+    async def readinto(self, dest: memoryview) -> None:
+        """Fill ``dest``: first from what is staged, the rest straight
+        from the socket."""
+        have = min(self._w - self._r, len(dest))
+        if have:
+            dest[:have] = self._stage[self._r:self._r + have]
+            self._r += have
+        if have < len(dest):
+            self._dest, self._dest_at = dest, have
+        self._consumed()
+        try:
+            while self._dest is not None:
+                await self._park(
+                    lambda: bytes(dest[:self._dest_at]), len(dest))
+        finally:
+            self._dest = None
+
+    # -- writing -------------------------------------------------------
+
+    def write(self, data) -> None:
+        self.stats["bytes_out"] += len(data)
+        self._transport.write(data)
+
+    def writelines(self, bufs: list) -> None:
+        self.stats["bytes_out"] += sum(len(b) for b in bufs)
+        self._transport.writelines(bufs)
+
+    async def drain(self) -> None:
+        if self._lost:
+            raise self._exc or ConnectionResetError("Connection lost")
+        if self._transport.is_closing():
+            # let connection_lost() run before the next write
+            await asyncio.sleep(0)
+        while self._write_paused and not self._lost:
+            fut = self._loop.create_future()
+            self._drainers.append(fut)
+            try:
+                await fut
+            finally:
+                self._drainers.remove(fut)
+        if self._lost:
+            raise self._exc or ConnectionResetError("Connection lost")
+
+    def close(self) -> None:
+        self._transport.close()
+
+
+async def send_banner(writer, features: int = 1) -> None:
     writer.write(BANNER + struct.pack("<Q", features))
     await writer.drain()
 
 
-async def recv_banner(reader: asyncio.StreamReader) -> int:
+async def recv_banner(reader) -> int:
     got = await reader.readexactly(len(BANNER))
     if got != BANNER:
         raise FrameError(f"bad banner {got!r}")
@@ -65,46 +277,68 @@ async def recv_banner(reader: asyncio.StreamReader) -> int:
     return features
 
 
-def _preamble(tag: int, seg_lens: list[int]) -> bytes:
-    head = struct.pack(
+def _head(tag: int, seg_lens: list[int]) -> bytes:
+    return struct.pack(
         "<BB4I", tag, len(seg_lens),
-        *(seg_lens + [0] * (MAX_SEGMENTS - len(seg_lens))),
+        *seg_lens, *([0] * (MAX_SEGMENTS - len(seg_lens))),
     )
+
+
+def _preamble(tag: int, seg_lens: list[int]) -> bytes:
+    head = _head(tag, seg_lens)
     return head + struct.pack("<I", crc32c(head))
 
 
-async def write_frame(
-    writer: asyncio.StreamWriter, tag: int, segments: list[bytes],
-    crypto=None,
-) -> None:
+def _count(stream, key: str) -> None:
+    stats = getattr(stream, "stats", None)
+    if stats is not None:
+        stats[key] += 1
+
+
+async def write_frame(writer, tag: int, segments: list, crypto=None) -> None:
+    """One frame, handed to the transport in one ``writelines``: the
+    segments go as they are (no copy of what is already bytes-like),
+    each crc is computed once."""
     assert 0 < len(segments) <= MAX_SEGMENTS
-    segs = [bytes(s) for s in segments]
+    segs = [s if isinstance(s, (bytes, bytearray, memoryview)) else bytes(s)
+            for s in segments]
+    seg_lens = [len(s) for s in segs]
     if crypto is not None:
-        plain = struct.pack(
-            "<BB4I", tag, len(segs),
-            *([len(s) for s in segs] + [0] * (MAX_SEGMENTS - len(segs))),
-        ) + b"".join(segs)
-        ct = crypto.encrypt(plain)
-        writer.write(struct.pack("<I", len(ct)) + ct)
-        await writer.drain()
-        return
-    writer.write(_preamble(tag, [len(s) for s in segs]))
-    for s in segs:
-        writer.write(s)
-    # epilogue: one crc32c per present segment (frames_v2.h:124-143)
-    writer.write(struct.pack(f"<{len(segs)}I", *(crc32c(s) for s in segs)))
+        ct = crypto.encrypt(b"".join([_head(tag, seg_lens), *segs]))
+        writer.writelines([struct.pack("<I", len(ct)), ct])
+    else:
+        writer.writelines([
+            _preamble(tag, seg_lens),
+            *segs,
+            # epilogue: one crc32c per present segment
+            # (frames_v2.h:124-143)
+            struct.pack(f"<{len(segs)}I", *(crc32c(s) for s in segs)),
+        ])
+    _count(writer, "frames_out")
     await writer.drain()
 
 
-async def read_frame(
-    reader: asyncio.StreamReader, crypto=None,
-) -> tuple[int, list[bytes]]:
+async def _read_body(reader, n: int) -> memoryview:
+    """The ``n`` bytes that follow a frame's lengths, in a buffer of
+    their own: a FrameStream's socket fills it in place; a plain
+    ``asyncio.StreamReader`` hands over its copy."""
+    if isinstance(reader, FrameStream):
+        body = memoryview(bytearray(n))
+        await reader.readinto(body)
+        return body
+    return memoryview(await reader.readexactly(n))
+
+
+async def read_frame(reader, crypto=None) -> tuple[int, list[memoryview]]:
+    """-> (tag, segments).  The segments are views of the one buffer
+    the frame was received into."""
     if crypto is not None:
         (ln,) = struct.unpack("<I", await reader.readexactly(4))
         if ln > MAX_FRAME_LEN:
             raise FrameError("secure frame too large")
+        ct = await _read_body(reader, ln)
         try:
-            plain = crypto.decrypt(await reader.readexactly(ln))
+            plain = memoryview(crypto.decrypt(ct))
         except Exception as e:  # InvalidTag and friends
             raise FrameError(f"secure frame authentication failed: {e}")
         tag, nseg = plain[0], plain[1]
@@ -118,20 +352,29 @@ async def read_frame(
             off += n
         if off != len(plain):
             raise FrameError("secure frame length mismatch")
+        _count(reader, "frames_in")
         return tag, segs
-    head = await reader.readexactly(18)
-    (want_crc,) = struct.unpack("<I", await reader.readexactly(4))
-    if crc32c(head) != want_crc:
+    pre = await reader.readexactly(22)
+    head = pre[:18]
+    if crc32c(head) != struct.unpack_from("<I", pre, 18)[0]:
         raise FrameError("preamble crc mismatch")
     tag, nseg = head[0], head[1]
     if not 0 < nseg <= MAX_SEGMENTS:
         raise FrameError(f"bad segment count {nseg}")
-    seg_lens = struct.unpack("<4I", head[2:])[:nseg]
-    if sum(seg_lens) > MAX_FRAME_LEN:
+    seg_lens = struct.unpack_from("<4I", head, 2)[:nseg]
+    total = sum(seg_lens)
+    if total > MAX_FRAME_LEN:
         raise FrameError("frame too large")
-    segs = [await reader.readexactly(n) for n in seg_lens]
-    crcs = struct.unpack(f"<{nseg}I", await reader.readexactly(4 * nseg))
-    for s, c in zip(segs, crcs):
-        if crc32c(s) != c:
+    # segments and epilogue (one crc per segment) arrive as one read
+    body = await _read_body(reader, total + 4 * nseg)
+    crcs = struct.unpack_from(f"<{nseg}I", body, total)
+    segs = []
+    off = 0
+    for n, c in zip(seg_lens, crcs):
+        seg = body[off : off + n]
+        off += n
+        if crc32c(seg) != c:
             raise FrameError("segment crc mismatch")
-    return tag, list(segs)
+        segs.append(seg)
+    _count(reader, "frames_in")
+    return tag, segs

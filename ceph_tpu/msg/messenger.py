@@ -24,6 +24,7 @@ from typing import Awaitable, Callable
 
 from ceph_tpu.msg import frames
 from ceph_tpu.msg.denc import Decoder, Encoder
+from ceph_tpu.native import crc_backend
 
 log = logging.getLogger("ceph_tpu.msg")
 
@@ -109,8 +110,8 @@ class Connection:
     def __init__(
         self,
         messenger: "Messenger",
-        reader: asyncio.StreamReader,
-        writer: asyncio.StreamWriter,
+        reader: frames.FrameStream,
+        writer: frames.FrameStream,
         peer: tuple[str, int] | None = None,
     ):
         self.messenger = messenger
@@ -282,7 +283,7 @@ class Connection:
             # have; empty reply = stay uncompressed
             from ceph_tpu import compressor as _comp
 
-            offered = segs[0].decode().split(",") if segs[0] else []
+            offered = str(segs[0], "utf-8").split(",") if segs[0] else []
             if self.messenger.compress_mode == "none":
                 offered = []  # 'none = never': refuse politely
             picked = next(
@@ -355,11 +356,11 @@ class Messenger:
         self.compress_min_size = compress_min_size
         self._server: asyncio.base_events.Server | None = None
         self._conns: dict[tuple[str, int], Connection] = {}  # by entity
-        # every live connection needs a strong root: asyncio's
-        # StreamReaderProtocol only holds the reader WEAKLY (py3.8+), so
-        # an un-referenced Connection/reader-task cycle would be
-        # garbage-collected mid-session, silently closing the socket —
-        # which the peer misreads as a daemon failure
+        # every live connection needs a strong root: the loop holds
+        # tasks weakly, so an un-referenced Connection/reader-task
+        # cycle would be garbage-collected mid-session, silently
+        # closing the socket — which the peer misreads as a daemon
+        # failure
         self._live: set[Connection] = set()
         self._connect_locks: dict[tuple[str, int], asyncio.Lock] = {}
         self.addr: tuple[str, int] | None = None
@@ -380,6 +381,13 @@ class Messenger:
         # of the cluster-wide span tree; None = no messenger spans
         # (clients of the raw messenger)
         self.tracer = None
+        # wire counters, shared with every connection's FrameStream
+        self.stats = dict.fromkeys(frames.STATS, 0)
+
+    def perf_dump(self) -> dict:
+        """The messenger's part of a daemon's ``perf dump``."""
+        return {**{f"msgr_{k}": v for k, v in self.stats.items()},
+                "crc_backend": crc_backend()}
 
     async def _dispatch(self, msg: Message) -> None:
         if self.dispatcher is not None:
@@ -397,14 +405,14 @@ class Messenger:
     # -- server side ---------------------------------------------------
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self._server = await asyncio.start_server(self._accept, host, port)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: frames.FrameStream(self.stats, self._accept), host, port)
         sock = self._server.sockets[0]
         self.addr = sock.getsockname()[:2]
         return self.addr
 
-    async def _accept(
-        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
-    ) -> None:
+    async def _accept(self, stream: frames.FrameStream) -> None:
+        reader = writer = stream
         conn = Connection(self, reader, writer)
 
         async def _handshake() -> None:
@@ -489,7 +497,9 @@ class Messenger:
         mid-flight leaves a stale selector registration that a reused
         fd number then trips over (the CPython _sock_write_done /
         _ensure_fd_no_transport race — also fuzzer-found)."""
-        reader, writer = await asyncio.open_connection(host, port)
+        _, reader = await asyncio.get_running_loop().create_connection(
+            lambda: frames.FrameStream(self.stats), host, port)
+        writer = reader
         try:
             return await asyncio.wait_for(
                 self._handshake_out(reader, writer, host, port),
@@ -549,7 +559,7 @@ class Messenger:
                     raise frames.FrameError(
                         "no COMPRESSION_DONE in 256 frames")
             conn._preread = preread
-            picked = segs[0].decode()
+            picked = str(segs[0], "utf-8")
             if picked:
                 conn.compressor = _comp.create(picked)
         await self._register(conn)

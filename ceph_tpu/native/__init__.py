@@ -164,8 +164,9 @@ def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
         n = data.nbytes if isinstance(data, memoryview) else len(data)
         if n == 0:
             return seed
-        return lib.ceph_tpu_crc32c(
-            seed, (ctypes.c_char * n).from_buffer(data), n, table)
+        # held for the call: it pins the buffer against a resize
+        first = ctypes.c_char.from_buffer(data)
+        return lib.ceph_tpu_crc32c(seed, ctypes.byref(first), n, table)
     arr = np.ascontiguousarray(
         np.frombuffer(data, dtype=np.uint8)
         if isinstance(data, memoryview)

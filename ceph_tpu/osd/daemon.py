@@ -457,7 +457,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         (src/osd/OSD.cc::asok_command slice)."""
         sock.register(
             "perf dump", "dump perf counters",
-            lambda cmd: self.perf.dump(),
+            lambda cmd: {**self.perf.dump(),
+                         **self.messenger.perf_dump()},
         )
         sock.register(
             "dump_ops_in_flight", "in-flight client ops",

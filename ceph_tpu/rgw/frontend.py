@@ -130,7 +130,8 @@ class S3Frontend:
             )
             self._admin.register(
                 "perf dump", "dump perf counters",
-                lambda cmd: self.perf.dump(),
+                lambda cmd: {**self.perf.dump(),
+                             **self._rados.messenger.perf_dump()},
             )
             self._admin.register(
                 "status", "daemon status",
