@@ -29,7 +29,7 @@ PREWARMED: dict[str, str] = {
     "ceph_tpu.ops.rs_kernels:gf_bitmatmul_pallas_grouped":
         "on a TPU backend BitmatrixCodec._apply selects it for 2-D data "
         "whose (k, m) leave room for >1 column group — the client EC "
-        "write / degraded-read path (EncodeService._run_group_single) "
+        "write / degraded-read path (EncodeService._run_group) "
         "and the per-op sync path (MatrixErasureCode._apply_device); "
         "encode_service.prewarm() compiles it at EC map-install warmup "
         "for widths up to 64 x the stripe-unit chunk, wider payloads "
@@ -58,12 +58,12 @@ PREWARMED: dict[str, str] = {
         "CLAY repair programs are staged per (profile, lost-node) at "
         "recovery planning time via stage(), outside the shard-read "
         "critical path; executables persist in the XLA disk cache",
-    "ceph_tpu.parallel.encode_farm:batch_encode_dp._encode":
-        "encode_service.prewarm() drives the farm over every warmed "
-        "(bucket, batch) shape at EC map-install warmup",
-    "ceph_tpu.parallel.encode_farm:sharded_encode_tp._encode":
-        "encode_service.prewarm() covers the tensor-parallel path for "
-        "the shapes the farm selects it for",
+    "ceph_tpu.parallel.encode_farm:_program.encode_mesh_cols":
+        "the one mesh program (built and jitted once per mesh, one "
+        "executable per width bucket): encode_service.prewarm() drives "
+        "it through EncodeService._launch, the call the I/O path makes, "
+        "for every warmed width at EC map-install warmup and the "
+        "benchmark's warm_shapes",
 }
 
 #: host-side entry points that dispatch straight into a jitted program:
@@ -77,8 +77,7 @@ JIT_ENTRYPOINTS: frozenset[str] = frozenset({
     "gf_bitmatmul_pallas_acc",
     "gf_bitmatmul_pallas_grouped",
     "batched_crc32c_device",
-    "batch_encode_dp",
-    "sharded_encode_tp",
+    "mesh_encode_cols",
 })
 
 #: the pow2-bucket helpers whose outputs are legitimate launch

@@ -3,17 +3,13 @@
 Maps Ceph's parallelism strategies (SURVEY.md §2.9) onto a
 ``jax.sharding.Mesh``:
 
-- stripe-batch data parallelism (many objects/stripes at once) —
+- column-split encode (many objects/stripes laid side by side along
+  the column dimension, one equal block per device, no collective) —
   the analogue of Ceph's per-PG sharded op queues and
-  ``ParallelPGMapper`` thread fan-out;
-- chunk sharding with psum-combined partial GF sums — the analogue of
-  EC shard fan-out (``MOSDECSubOpWrite`` to k+m OSDs, reference
-  src/osd/ECBackend.cc:943) when shard owners are co-located on one
-  pod slice: the XOR combine rides ICI collectives instead of TCP.
+  ``ParallelPGMapper`` thread fan-out: a GF(2^8) matrix product is
+  independent column by column, so one compiled launch serves a whole
+  window of ops on every chip at once.
 """
 
 from ceph_tpu.parallel.decode_batcher import DecodeAggregator  # noqa: F401
-from ceph_tpu.parallel.encode_farm import (  # noqa: F401
-    batch_encode_dp,
-    sharded_encode_tp,
-)
+from ceph_tpu.parallel.encode_farm import mesh_encode_cols  # noqa: F401

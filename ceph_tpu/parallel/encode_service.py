@@ -1,13 +1,15 @@
-"""EncodeService: the in-daemon microbatching bridge onto the encode farm.
+"""EncodeService: the in-daemon microbatching bridge onto the device(s).
 
-This is the production wiring of the multi-chip shardings
-(ceph_tpu/parallel/encode_farm.py) into the I/O path: OSD write/recovery
-ops running as concurrent asyncio tasks enqueue their GF(2^8) matrix
-applications here; requests that land within one coalescing window and
-share a matrix are padded into a single (B, k, S) batch and dispatched
-through :func:`batch_encode_dp` over the device mesh.  A lone large
-request takes the chunk-sharded :func:`sharded_encode_tp` path instead
-(partial GF sums psum-combined over ICI).
+OSD write/recovery ops running as concurrent asyncio tasks enqueue their
+GF(2^8) matrix applications here; requests that land within one
+coalescing window and share a matrix are laid side by side along the
+column dimension S (a GF matmul is independent column by column), padded
+to a fixed power-of-two width bucket and dispatched as ONE launch.  With
+several local devices that launch is the column-split mesh program of
+ceph_tpu/parallel/encode_farm.py (:func:`mesh_encode_cols`: every device
+applies the replicated bit-matrix to its own column block, no
+collective, no batch padding), a lone request and a full window alike;
+with one accelerator it is the same kernel on that device.
 
 This is the seam the reference implements as the ECSubWrite fan-out /
 per-op `ECUtil::encode` loop (reference src/osd/ECCommon.cc:749
@@ -15,9 +17,9 @@ generate_transactions -> ECTransaction.cc:37 encode_and_write, and
 src/osd/OSDMapMapping.h:18 ParallelPGMapper for the batch-parallel
 pattern): independent per-PG ops become one batched TPU computation.
 
-Single-device processes (or payloads under ``min_bytes``) fall back to
-the caller's host/1-chip path — the service is then inactive and
-``apply`` is never awaited (callers check :meth:`active`).
+Cpu-only single-device processes (or payloads under ``min_bytes``) fall
+back to the caller's host/1-chip path — the service is then inactive
+and ``apply`` is never awaited (callers check :meth:`active`).
 """
 
 from __future__ import annotations
@@ -38,14 +40,17 @@ DEFAULT_MIN_BYTES = 32768
 
 _BITS_CACHE_SIZE = 64
 
+#: a launch's transfer-guard window, by what the launch counts itself as
+_GUARD_KIND = {"single": "encode_single", "dp": "encode_mesh"}
+
 
 class EncodeService:
-    """Coalesces concurrent GF matrix applications onto a device mesh.
+    """Coalesces concurrent GF matrix applications into one launch.
 
-    ``mesh`` must have a ``pg`` axis (stripe-batch data parallelism) and
-    may have a ``shard`` axis (chunk sharding for the tp path).  With
-    ``mesh=None`` the service is inactive and callers use their local
-    path.
+    ``mesh`` is any ``jax.sharding.Mesh``: a launch cuts its columns
+    over every device of it, whatever its axes.  ``device`` (no mesh)
+    is single-device mode.  With neither the service is inactive and
+    callers use their local path.
     """
 
     def __init__(self, mesh=None, *, device=None,
@@ -54,8 +59,7 @@ class EncodeService:
         self.mesh = mesh
         # single-device mode: with one accelerator and no mesh, the
         # microbatching window still coalesces concurrent per-PG ops
-        # into ONE dispatch.  Requests concatenate along S (GF matmul
-        # is column-independent), so no batch padding at all.
+        # into ONE dispatch
         self.device = device
         self.min_bytes = min_bytes
         self.window_s = window_s
@@ -153,147 +157,45 @@ class EncodeService:
                 fut.set_result(out)
 
     def _run_group(self, group: list[tuple]) -> list[np.ndarray]:
-        """Worker-thread body: one farm dispatch for the whole group;
-        returns per-request outputs in order."""
+        """Worker-thread body: ONE launch for the whole group.  The
+        requests' rows are laid side by side along S (a GF matmul is
+        independent column by column, so there is no batch dimension
+        and no batch padding) and padded to the launch's fixed width
+        bucket, so jit shapes stay bounded; on a mesh the columns are
+        then cut into one block per device.  Returns per-request
+        outputs in order."""
         import jax
 
-        from ceph_tpu.parallel.encode_farm import (
-            batch_encode_dp,
-            sharded_encode_tp,
-        )
-
-        # NOTE on guard coverage: the mesh (shard_map) dispatches below
-        # are NOT wrapped in no_implicit_transfers — XLA's multi-device
-        # execution path ships tiny internal scalar constants
-        # (observed: replicated uint8[] avals) host->device on every
-        # dispatch, which the guard cannot tell apart from real payload
-        # round-trips.  Payload transfers here are explicit and
-        # mesh-sharded at source (device_put with NamedSharding, no
-        # reshard hop); the single-device paths — where the
-        # batched-vs-host gap actually lives — run fully guarded
-        # (_run_group_single, decode/scrub batchers, mgr analytics).
+        from ceph_tpu.common.transfer_guard import no_implicit_transfers
 
         M = group[0][0]
         bits = self._bits(M)
         k = M.shape[1]
-
-        if self.mesh is None:
-            return self._run_group_single(group, bits, k)
-
-        if len(group) == 1 and "shard" in self.mesh.shape:
-            rows = group[0][1]
-            nsh = self.mesh.shape["shard"]
-            if nsh > 1 and k % nsh == 0:
-                # same fixed-bucket discipline as the dp path: pad S to
-                # its pow2 bucket so the tp program shape set is bounded
-                S = pow2_bucket(rows.shape[1], 1)
-                if S != rows.shape[1]:
-                    padded = np.zeros((rows.shape[0], S), np.uint8)
-                    padded[:, : rows.shape[1]] = rows
-                else:
-                    padded = rows
-                from ceph_tpu.parallel.encode_farm import (
-                    tp_data_sharding,
-                )
-
-                with self._note_shape(("tp", bits.shape, k, S), group,
-                                      w=S):
-                    res = sharded_encode_tp(
-                        self.mesh, bits, jax.device_put(
-                            padded, tp_data_sharding(self.mesh)))
-                    out = jax.device_get(res)
-                self._note_mesh_devices(res)
-                self.stats["tp_dispatches"] += 1
-                self.metrics.inc("launches", w=S)
-                return [np.ascontiguousarray(out[:, : rows.shape[1]])]
-
-        # data-parallel batch: pad each request's S to a fixed
-        # power-of-two width bucket and the batch dim to a power-of-two
-        # multiple of the device count, one sharded dispatch — launch
-        # shapes come from a tiny fixed set, so every compile happens
-        # at prewarm, never mid-I/O
-        ndev = 1
-        for ax in self.mesh.shape.values():
-            ndev *= ax
-        widths = [rows.shape[1] for _, rows, *_ in group]
-        S = pow2_bucket(max(widths), 1)
-        B = ndev * pow2_bucket(-(-len(group) // ndev), 1)
-        batch = np.zeros((B, k, S), np.uint8)
-        for i, (_, rows, *_) in enumerate(group):
-            batch[i, :, : rows.shape[1]] = rows
-        axes = tuple(a for a in ("pg", "shard") if a in self.mesh.shape)
-        from ceph_tpu.parallel.encode_farm import dp_batch_sharding
-
-        with self._note_shape(("dp", bits.shape, B, k, S), group, w=S,
-                              b=B):
-            res = batch_encode_dp(
-                self.mesh, bits, jax.device_put(
-                    batch, dp_batch_sharding(self.mesh, axes)),
-                axis=axes)
-            out = jax.device_get(res)
-        self._note_mesh_devices(res)
-        self.stats["dp_dispatches"] += 1
-        self.stats["coalesced"] += len(group)
-        self.metrics.inc("launches", w=S, b=B)
-        self.metrics.inc("occupied_lanes", w=S, b=B, by=len(group))
-        self.metrics.inc("padded_lanes", w=S, b=B, by=B)
-        self.metrics.inc("occupied_bytes", w=S, b=B, by=sum(widths) * k)
-        self.metrics.inc("padded_bytes", w=S, b=B, by=B * k * S)
-        return [
-            np.ascontiguousarray(out[i, :, : rows.shape[1]])
-            for i, (_, rows, _) in enumerate(group)
-        ]
-
-    def _note_mesh_devices(self, res) -> None:
-        """Most devices any farm launch's result has sat on: a mesh
-        whose launches land on its first device only is not a farm."""
-        self.stats["mesh_devices_used"] = max(
-            self.stats["mesh_devices_used"], len(res.sharding.device_set))
-
-    def _note_shape(self, shape_key: tuple, group: list[tuple], *,
-                    w: int, b: int = 1):
-        """Track whether a launch shape was already compiled (a miss is
-        a cold in-path compile the warmup should have covered) and
-        return the device-launch profiling span wrapping the launch;
-        every traced request of ``group`` gets its ``encode_batch_wait``
-        filed (arrival -> here) and the launch names their spans."""
-        cold = shape_key not in self._warm
-        if cold:
-            self._warm.add(shape_key)
-            self.stats["cold_launches"] += 1
-            self.metrics.inc("cold_launches", w=w, b=b)
-        b_real = len(group)
-        return tracing.launch_span(
-            "encode_batch_wait", [req[3:] for req in group],
-            kind=f"encode_{shape_key[0]}", w=w, b=b, b_real=b_real,
-            occupancy=round(b_real / max(b, 1), 3), cold=cold,
-        )
-
-    def _run_group_single(self, group: list[tuple], bits, k) -> list[np.ndarray]:
-        """Single-device dispatch: concatenate every request's rows
-        along S (column-independent GF matmul), pad to a power-of-two
-        width so jit shapes stay bounded, ONE kernel launch for the
-        whole window."""
-        import jax
-
-        from ceph_tpu.common.transfer_guard import no_implicit_transfers
-        from ceph_tpu.ops.rs_kernels import BitmatrixCodec
-
         widths = [rows.shape[1] for _, rows, *_ in group]
         total = sum(widths)
-        S = pow2_bucket(total, 1)  # fixed pow2 width bucket
+        S = self._bucket(total)
         big = np.zeros((k, S), np.uint8)
         off = 0
         for (_, rows, *_), w in zip(group, widths):
             big[:, off:off + w] = rows
             off += w
-        with self._note_shape(("single", bits.shape, k, S), group,
-                              w=S), \
-                no_implicit_transfers("encode_single"):
-            out = jax.device_get(BitmatrixCodec._apply(
-                bits, jax.device_put(big), None))
-        self.stats["single_dispatches"] += 1
+        kind = self._kind()
+        with self._note_shape((kind, bits.shape, k, S), group, w=S) as span, \
+                no_implicit_transfers(_GUARD_KIND[kind]):
+            res = self._launch(bits, big)
+            out = jax.device_get(res)
+            if self.mesh is not None:
+                ndev = len(res.sharding.device_set)
+                span.tag(devices=ndev, pad_bytes=(S - total) * k)
+        self.stats[f"{kind}_dispatches"] += 1
         self.stats["coalesced"] += len(group)
+        if self.mesh is not None:
+            # a mesh whose launches land on its first device only is
+            # not a farm: keep the most devices any result has sat on
+            self.stats["mesh_devices_used"] = max(
+                self.stats["mesh_devices_used"], ndev)
+            self.stats["mesh_occupied_bytes"] += total * k
+            self.stats["mesh_padded_bytes"] += S * k
         self.metrics.inc("launches", w=S)
         self.metrics.inc("occupied_bytes", w=S, by=total * k)
         self.metrics.inc("padded_bytes", w=S, by=k * S)
@@ -304,22 +206,69 @@ class EncodeService:
             off += w
         return outs
 
+    def _kind(self) -> str:
+        """What a launch counts itself as (``<kind>_dispatches``, the
+        ``xla_launch`` span's ``encode_<kind>``): ``dp`` over a mesh,
+        ``single`` on one device."""
+        return "single" if self.mesh is None else "dp"
+
+    def _bucket(self, total: int) -> int:
+        """The fixed launch width holding ``total`` real columns."""
+        if self.mesh is None:
+            return pow2_bucket(total, 1)
+        from ceph_tpu.parallel.encode_farm import cols_width
+
+        return cols_width(self.mesh, total)
+
+    def _launch(self, bits, big: np.ndarray):
+        """Upload ``big`` where the program wants it and launch: the
+        column-split mesh program, or the one device's kernel.  The
+        warm-up and the I/O path both come through here, so they agree
+        on shapes and shardings."""
+        import jax
+
+        if self.mesh is None:
+            from ceph_tpu.ops.rs_kernels import BitmatrixCodec
+
+            return BitmatrixCodec._apply(bits, jax.device_put(big), None)
+        from ceph_tpu.parallel.encode_farm import (
+            cols_sharding,
+            mesh_encode_cols,
+        )
+
+        return mesh_encode_cols(
+            self.mesh, bits, jax.device_put(big, cols_sharding(self.mesh)))
+
+    def _note_shape(self, shape_key: tuple, group: list[tuple], *, w: int):
+        """Track whether a launch shape was already compiled (a miss is
+        a cold in-path compile the warmup should have covered) and
+        return the device-launch profiling span wrapping the launch;
+        every traced request of ``group`` gets its ``encode_batch_wait``
+        filed (arrival -> here) and the launch names their spans."""
+        cold = shape_key not in self._warm
+        if cold:
+            self._warm.add(shape_key)
+            self.stats["cold_launches"] += 1
+            self.metrics.inc("cold_launches", w=w)
+        return tracing.launch_span(
+            "encode_batch_wait", [req[3:] for req in group],
+            kind=f"encode_{shape_key[0]}", w=w, b_real=len(group),
+            cold=cold,
+        )
+
     # -- warmup --------------------------------------------------------
 
     def prewarm(self, M: np.ndarray, widths, *, coalesce: int = 16) -> int:
         """Compile the fixed-bucket launch shapes this service can hit
         for matrix ``M`` and per-request payload widths ``widths``
-        (coalescing concatenates/batches up to ``coalesce`` concurrent
-        requests).  Blocking — run at daemon warmup, never in the I/O
-        path.  Returns the number of programs compiled."""
+        (a window concatenates up to ``coalesce`` concurrent requests).
+        Blocking — run at daemon warmup, never in the I/O path.
+        Returns the number of programs compiled."""
         if not self.active():
             return 0
         import jax
-        import jax.numpy as jnp
 
         from ceph_tpu.ops.compile_cache import ensure_persistent_cache
-        from ceph_tpu.ops.rs_kernels import BitmatrixCodec
-        from ceph_tpu.parallel.encode_farm import batch_encode_dp
 
         ensure_persistent_cache()  # warmed programs persist across runs
 
@@ -329,62 +278,17 @@ class EncodeService:
         for w in widths:
             f = 1
             while f <= coalesce:
-                buckets.add(pow2_bucket(w * f, 1))
+                buckets.add(self._bucket(w * f))
                 f <<= 1
         n = 0
-        if self.mesh is not None:
-            from ceph_tpu.parallel.encode_farm import dp_batch_sharding
-
-            ndev = 1
-            for ax in self.mesh.shape.values():
-                ndev *= ax
-            axes = tuple(
-                a for a in ("pg", "shard") if a in self.mesh.shape)
-            bbs = sorted({
-                ndev * pow2_bucket(-(-g // ndev), 1)
-                for g in range(1, coalesce + 1)
-            })
-            # warm with the SAME input shardings the dispatch path
-            # uses (executables are keyed by sharding, not just shape)
-            dp_spec = dp_batch_sharding(self.mesh, axes)
-            for S in sorted(pow2_bucket(w, 1) for w in widths):
-                for B in bbs:
-                    key = ("dp", bits.shape, B, k, S)
-                    if key in self._warm:
-                        continue
-                    jax.block_until_ready(batch_encode_dp(
-                        self.mesh, bits,
-                        jax.device_put(
-                            np.zeros((B, k, S), np.uint8), dp_spec),
-                        axis=axes))
-                    self._warm.add(key)
-                    n += 1
-            nsh = self.mesh.shape.get("shard", 1)
-            if nsh > 1 and k % nsh == 0:
-                from ceph_tpu.parallel.encode_farm import (
-                    sharded_encode_tp,
-                    tp_data_sharding,
-                )
-
-                tp_spec = tp_data_sharding(self.mesh)
-                for S in sorted(pow2_bucket(w, 1) for w in widths):
-                    key = ("tp", bits.shape, k, S)
-                    if key in self._warm:
-                        continue
-                    jax.block_until_ready(sharded_encode_tp(
-                        self.mesh, bits, jax.device_put(
-                            np.zeros((k, S), np.uint8), tp_spec)))
-                    self._warm.add(key)
-                    n += 1
-        else:
-            for S in sorted(buckets):
-                key = ("single", bits.shape, k, S)
-                if key in self._warm:
-                    continue
-                jax.block_until_ready(BitmatrixCodec._apply(
-                    bits, jnp.zeros((k, S), np.uint8), None))
-                self._warm.add(key)
-                n += 1
+        for S in sorted(buckets):
+            key = (self._kind(), bits.shape, k, S)
+            if key in self._warm:
+                continue
+            jax.block_until_ready(
+                self._launch(bits, np.zeros((k, S), np.uint8)))
+            self._warm.add(key)
+            n += 1
         self.stats["prewarmed_shapes"] += n
         self.metrics.inc("prewarmed_shapes", by=n)
         return n
@@ -394,8 +298,9 @@ _shared: EncodeService | None = None
 
 
 def shared() -> EncodeService:
-    """Process-wide service; builds a mesh over all local devices on
-    first use.  A single TPU gets single-device coalescing mode; a
+    """Process-wide service; builds a one-axis mesh over all local
+    devices on first use (the device count the process sees is the only
+    thing that chooses the launch path).  A single TPU gets single-device coalescing mode; a
     cpu-only process (one CPU device, or no jax at all) stays inactive
     so host paths keep their exact semantics/costs.  A backend that
     fails to start (chip held by another process, bad platform env)
@@ -414,9 +319,7 @@ def shared() -> EncodeService:
 
             devs = jax.devices()
             if len(devs) > 1:
-                nsh = 2 if len(devs) % 2 == 0 else 1
-                devgrid = np.asarray(devs).reshape(len(devs) // nsh, nsh)
-                mesh = Mesh(devgrid, ("pg", "shard"))
+                mesh = Mesh(np.asarray(devs), ("cols",))
             elif devs[0].platform == "tpu":
                 device = devs[0]
         _shared = EncodeService(mesh, device=device)

@@ -398,14 +398,13 @@ def _check_farm(svc, d: dict, min_requests: int) -> None:
     if svc.mesh is None:
         launches = d.get("single_dispatches", 0)
     else:
-        launches = d.get("dp_dispatches", 0) + d.get("tp_dispatches", 0)
+        launches = d.get("dp_dispatches", 0)
         check(svc.stats["mesh_devices_used"] == svc.mesh.size,
               f"farm launches sat on {svc.stats['mesh_devices_used']} of "
               f"{svc.mesh.size} devices")
     check(launches > 0,
           f"no encode-service device launch in this phase: {d}")
-    # a lone request on a mesh takes the tp path, which counts launches
-    served = d.get("coalesced", 0) + d.get("tp_dispatches", 0)
+    served = d.get("coalesced", 0)
     check(served >= min_requests,
           f"encode service served {served} < {min_requests} requests: {d}")
     check(d["fallbacks"] == 0, f"encode service fell back: {d}")

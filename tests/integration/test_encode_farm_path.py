@@ -2,9 +2,9 @@
 
 Runs on the virtual 8-device CPU mesh (tests/conftest.py): client writes
 to an EC pool flow through the daemon's EncodeService, which coalesces
-concurrent ops into sharded batch_encode_dp dispatches; degraded reads
-and recovery route reconstruction the same way (sharded_encode_tp for a
-lone large decode).  Reference seam: src/osd/ECCommon.cc:749 fan-out /
+concurrent ops into one column-split mesh launch (mesh_encode_cols);
+degraded reads and recovery route reconstruction the same way, a lone
+decode included.  Reference seam: src/osd/ECCommon.cc:749 fan-out /
 ECUtil.cc:123 per-op encode loop becoming one batched TPU computation.
 """
 
@@ -46,11 +46,15 @@ class TestFarmInWritePath:
                     io.write_full(f"obj-{i}", _payload(i)) for i in range(12)
                 ))
                 stats = dict(svc.stats)
-                assert stats.get("dp_dispatches", 0) + stats.get(
-                    "tp_dispatches", 0) > 0, f"farm never dispatched: {stats}"
+                assert stats.get("dp_dispatches", 0) > 0, \
+                    f"farm never dispatched: {stats}"
+                # the chunk-sharded path is gone: every launch is the
+                # column-split one, and it served every request
+                assert "tp_dispatches" not in stats
+                assert stats["coalesced"] >= 12
                 # coalescing: fewer dispatches than encoded ops
-                if stats.get("dp_dispatches"):
-                    assert stats["coalesced"] > stats["dp_dispatches"]
+                assert stats["coalesced"] > stats["dp_dispatches"]
+                assert stats["mesh_devices_used"] == 8
                 for i in range(12):
                     assert await io.read(f"obj-{i}") == _payload(i)
 
@@ -87,8 +91,10 @@ class TestFarmInWritePath:
                 # degraded read must reconstruct — and use the farm
                 assert await io.read("victim") == data
                 after = dict(svc.stats)
-                total = lambda d: d.get("dp_dispatches", 0) + d.get("tp_dispatches", 0)
-                assert total(after) > total(before), (before, after)
+                assert after["dp_dispatches"] > before["dp_dispatches"], (
+                    before, after)
+                assert "tp_dispatches" not in after
+                assert after["coalesced"] > before["coalesced"]
 
         run(go())
 
@@ -111,13 +117,17 @@ class TestServiceUnit:
             outs = await asyncio.gather(*(svc.apply(M, r) for r in rows))
             for r, o in zip(rows, outs):
                 assert np.array_equal(o, gf_matmul(M, r))
-            assert svc.stats["dp_dispatches"] >= 1
+            assert svc.stats["dp_dispatches"] == 1
             assert svc.stats["coalesced"] == 5
-            # lone request takes the chunk-sharded tp path (k=4 % 2 == 0)
+            # a lone request rides the same column-split launch (no
+            # chunk-sharded path, whatever the mesh's axes)
             one = rng.integers(0, 256, (4, 4096), dtype=np.uint8)
             out = await svc.apply(M, one)
             assert np.array_equal(out, gf_matmul(M, one))
-            assert svc.stats["tp_dispatches"] == 1
+            assert svc.stats["dp_dispatches"] == 2
+            assert svc.stats["coalesced"] == 6
+            assert "tp_dispatches" not in svc.stats
+            assert svc.stats["mesh_devices_used"] == 8
 
         asyncio.run(go())
 

@@ -10,7 +10,7 @@ from jax.sharding import Mesh
 from ceph_tpu.models import matrices as mx
 from ceph_tpu.ops import gf256 as gf
 from ceph_tpu.ops.rs_kernels import BitmatrixCodec
-from ceph_tpu.parallel import batch_encode_dp, sharded_encode_tp
+from ceph_tpu.parallel import encode_farm as ef
 
 
 @pytest.fixture(scope="module")
@@ -29,33 +29,33 @@ def mesh2x4():
     return Mesh(np.array(devs[:8]).reshape(2, 4), ("pg", "shard"))
 
 
-def test_batch_encode_dp_matches_host(mesh8):
+@pytest.mark.parametrize("meshname", ["mesh8", "mesh2x4"])
+def test_mesh_encode_cols_matches_host(meshname, request):
+    """The column-split launch cuts S over every device of the mesh,
+    whatever its axes: 8 x 1-D and 2 x 4 give the same bytes."""
+    mesh = request.getfixturevalue(meshname)
     rng = np.random.default_rng(0)
     k, m = 8, 3
     codec = BitmatrixCodec(mx.isa_cauchy_matrix(k, m))
-    batch = rng.integers(0, 256, (16, k, 256), dtype=np.uint8)
-    out = np.asarray(batch_encode_dp(mesh8, codec.encode_bits, jnp.asarray(batch)))
-    for b in range(16):
-        assert np.array_equal(out[b], gf.gf_matmul(codec.C, batch[b]))
+    S = ef.cols_width(mesh, 3000)           # 8 blocks of 512
+    assert S == 4096
+    data = np.zeros((k, S), np.uint8)
+    data[:, :3000] = rng.integers(0, 256, (k, 3000), dtype=np.uint8)
+    out = ef.mesh_encode_cols(
+        mesh, jax.device_put(codec.encode_bits, ef.replicated_sharding(mesh)),
+        jax.device_put(data, ef.cols_sharding(mesh)))
+    assert len(out.sharding.device_set) == 8
+    assert np.array_equal(np.asarray(out), gf.gf_matmul(codec.C, data))
 
 
-def test_sharded_encode_tp_matches_host(mesh2x4):
-    rng = np.random.default_rng(1)
-    k, m = 8, 3  # 8k=64 bit-columns over 4-way shard axis -> 16 each
-    codec = BitmatrixCodec(mx.isa_cauchy_matrix(k, m))
-    data = rng.integers(0, 256, (k, 512), dtype=np.uint8)
-    out = np.asarray(
-        sharded_encode_tp(mesh2x4, codec.encode_bits, jnp.asarray(data))
-    )
-    assert np.array_equal(out, gf.gf_matmul(codec.C, data))
-
-
-def test_tp_then_decode_roundtrip(mesh2x4):
+def test_mesh_encode_then_decode_roundtrip(mesh2x4):
     rng = np.random.default_rng(2)
     k, m = 8, 3
     codec = BitmatrixCodec(mx.jerasure_rs_vandermonde_matrix(k, m))
     data = rng.integers(0, 256, (k, 512), dtype=np.uint8)
-    parity = np.asarray(sharded_encode_tp(mesh2x4, codec.encode_bits, jnp.asarray(data)))
+    parity = np.asarray(ef.mesh_encode_cols(
+        mesh2x4, codec.encode_bits,
+        jax.device_put(data, ef.cols_sharding(mesh2x4))))
     chunks = np.concatenate([data, parity], axis=0)
     rec = np.asarray(codec.decode(jnp.asarray(chunks), (1, 6, 9)))
     assert np.array_equal(rec, chunks[[1, 6, 9]])
