@@ -213,6 +213,7 @@ class RadosClient:
             ev, self._map_event = self._map_event, asyncio.Event()
             ev.set()  # wake everyone waiting for "a newer map than X"
         elif isinstance(msg, MOSDOpReply):
+            msg.own_blobs()     # callers get bytes, never a view
             fut = self._op_waiters.get(msg.tid)
             if fut and not fut.done():
                 fut.set_result(msg)

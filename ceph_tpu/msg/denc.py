@@ -29,9 +29,20 @@ class EncodingError(Exception):
     pass
 
 
+# in place of a blob's length: the blob is not in this buffer, it is
+# the frame's data segment (Encoder.blob / Decoder.blob).  No length
+# is ever that large (frames.MAX_FRAME_LEN).
+DETACHED = 0xFFFFFFFF
+# a blob this large that is copied through bytes_ all the same is
+# counted (Encoder.copied / Decoder.copied -> msgr_blob_copied_bytes)
+BLOB_COPY_FLOOR = 64 * 1024
+
+
 class Encoder:
     def __init__(self) -> None:
         self._buf = bytearray()
+        self.data = None    # the blob that travels beside this buffer
+        self.copied = 0     # bytes of large blobs copied into it
 
     # scalars (little-endian, like ceph_le types)
     def u8(self, v: int) -> None:
@@ -56,8 +67,25 @@ class Encoder:
         self.u8(1 if v else 0)
 
     def bytes_(self, b: bytes) -> None:
-        self.u32(len(b))
+        n = len(b)
+        if n >= BLOB_COPY_FLOOR:
+            self.copied += n
+        self.u32(n)
         self._buf += b
+
+    def blob(self, b) -> None:
+        """A message's payload.  The first one that is not empty is
+        not copied: it stays in ``self.data`` as the caller gave it,
+        for whoever frames this buffer to send beside it, and only a
+        marker is encoded (the same object given again encodes the
+        marker again, so a reply's ``data`` and its first ``outs``
+        entry travel once).  An empty blob, or a further one, goes
+        inline like any ``bytes_``."""
+        if len(b) and (self.data is None or self.data is b):
+            self.data = b
+            self.u32(DETACHED)
+        else:
+            self.bytes_(b)
 
     def str_(self, s: str) -> None:
         self.bytes_(s.encode("utf-8"))
@@ -84,9 +112,12 @@ class Encoder:
 
 
 class Decoder:
-    def __init__(self, data: bytes | bytearray | memoryview, off: int = 0):
+    def __init__(self, data: bytes | bytearray | memoryview, off: int = 0,
+                 blob: memoryview | None = None):
         self._d = memoryview(data)
         self._off = off
+        self._blob = blob   # the frame's data segment, if it has one
+        self.copied = 0     # bytes of large blobs copied out
 
     def _take(self, n: int) -> memoryview:
         if self._off + n > len(self._d):
@@ -118,9 +149,24 @@ class Decoder:
     def bool_(self) -> bool:
         return bool(self.u8())
 
-    def bytes_(self) -> bytes:
-        n = self.u32()
+    def _copy_out(self, n: int) -> bytes:
+        if n >= BLOB_COPY_FLOOR:
+            self.copied += n
         return bytes(self._take(n))
+
+    def bytes_(self) -> bytes:
+        return self._copy_out(self.u32())
+
+    def blob(self) -> bytes | memoryview:
+        """What ``Encoder.blob`` wrote: the data segment itself (a view
+        of the frame's buffer, not a copy) where the marker stands,
+        else the inline bytes."""
+        n = self.u32()
+        if n != DETACHED:
+            return self._copy_out(n)
+        if self._blob is None:
+            raise EncodingError("detached blob but no data segment")
+        return self._blob
 
     def str_(self) -> str:
         return self.bytes_().decode("utf-8")

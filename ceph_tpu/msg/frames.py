@@ -55,9 +55,14 @@ class FrameError(ConnectionError):
 # what a Messenger counts about its wire (``Messenger.stats``, ``perf
 # dump``'s ``msgr_*``): recv_calls / frames_in and reader_wakeups /
 # frames_in say how many socket reads and how many resumptions of the
-# reader coroutine one frame costs
+# reader coroutine one frame costs; data_segs / data_bytes count the
+# message blobs that travelled as a data segment, uncopied, and
+# blob_copied_bytes the bytes of blobs of denc.BLOB_COPY_FLOOR or more
+# that went through denc's bytes_ copies all the same
 STATS = ("frames_in", "bytes_in", "recv_calls", "reader_wakeups",
-         "frames_out", "bytes_out")
+         "frames_out", "bytes_out",
+         "data_segs_out", "data_segs_in", "data_bytes_out",
+         "data_bytes_in", "blob_copied_bytes")
 
 
 class FrameStream(asyncio.BufferedProtocol):
@@ -297,11 +302,12 @@ def _count(stream, key: str) -> None:
 
 async def write_frame(writer, tag: int, segments: list, crypto=None) -> None:
     """One frame, handed to the transport in one ``writelines``: the
-    segments go as they are (no copy of what is already bytes-like),
-    each crc is computed once."""
+    segments go as they are (anything else that holds bytes, such as a
+    C-contiguous uint8 numpy row, as a view of them), each crc is
+    computed once."""
     assert 0 < len(segments) <= MAX_SEGMENTS
-    segs = [s if isinstance(s, (bytes, bytearray, memoryview)) else bytes(s)
-            for s in segments]
+    segs = [s if isinstance(s, (bytes, bytearray, memoryview))
+            else memoryview(s) for s in segments]
     seg_lens = [len(s) for s in segs]
     if crypto is not None:
         ct = crypto.encrypt(b"".join([_head(tag, seg_lens), *segs]))

@@ -280,6 +280,12 @@ OP_CACHE_FLUSH = 27
 OP_CACHE_EVICT = 28
 OP_COPY_FROM = 29
 
+# the ops whose ``data`` is object payload: it rides the frame's data
+# segment and arrives as a view (Encoder.blob).  Every other op's
+# ``data`` is a small argument (an xattr value, a class call's input,
+# a notify payload) that its handler indexes or decodes: inline, bytes.
+PAYLOAD_OPS = frozenset({OP_WRITE_FULL, OP_WRITE, OP_APPEND})
+
 WRITE_OPS = frozenset({
     OP_WRITE_FULL, OP_DELETE, OP_WRITE, OP_APPEND, OP_ZERO, OP_TRUNCATE,
     OP_CREATE, OP_SETXATTR, OP_RMXATTR, OP_OMAP_SETKEYS, OP_OMAP_RMKEYS,
@@ -314,7 +320,10 @@ class OSDOp:
         enc.u64(self.off)
         enc.u64(self.length)
         enc.str_(self.name)
-        enc.bytes_(self.data)
+        if self.op in PAYLOAD_OPS:
+            enc.blob(self.data)
+        else:
+            enc.bytes_(self.data)
         _enc_map_str_bytes(enc, self.kv)
         enc.u32(len(self.keys))
         for k in self.keys:
@@ -323,7 +332,7 @@ class OSDOp:
     @classmethod
     def decode(cls, dec: Decoder) -> "OSDOp":
         return cls(
-            dec.u8(), dec.u64(), dec.u64(), dec.str_(), dec.bytes_(),
+            dec.u8(), dec.u64(), dec.u64(), dec.str_(), dec.blob(),
             _dec_map_str_bytes(dec), [dec.str_() for _ in range(dec.u32())],
         )
 
@@ -433,25 +442,39 @@ class MOSDOpReply(Message):
         # one (result, outdata, out_kv) per request op
         self.outs = outs or []
 
+    def own_blobs(self) -> None:
+        """What leaves the system is ``bytes``: where the reply reaches
+        the client, a read's data, a view of the frame it came in,
+        is copied out, once (``data`` and its ``outs`` entry are the
+        one view and stay the one object)."""
+        view = self.data
+        if isinstance(view, memoryview):
+            self.data = bytes(view)
+        self.outs = [
+            (r, self.data if d is view
+             else bytes(d) if isinstance(d, memoryview) else d, kv)
+            for r, d, kv in self.outs
+        ]
+
     def encode_payload(self, enc):
         enc.u64(self.tid)
         enc.i32(self.result)
-        enc.bytes_(self.data)
+        enc.blob(self.data)
         enc.u32(self.epoch)
         enc.u64(self.size)
         enc.u32(len(self.outs))
         for r, d, kv in self.outs:
             enc.i32(r)
-            enc.bytes_(d)
+            enc.blob(d)
             _enc_map_str_bytes(enc, kv)
 
     @classmethod
     def decode_payload(cls, dec):
         tid, result, data, epoch, size = (
-            dec.u64(), dec.i32(), dec.bytes_(), dec.u32(), dec.u64()
+            dec.u64(), dec.i32(), dec.blob(), dec.u32(), dec.u64()
         )
         outs = [
-            (dec.i32(), dec.bytes_(), _dec_map_str_bytes(dec))
+            (dec.i32(), dec.blob(), _dec_map_str_bytes(dec))
             for _ in range(dec.u32())
         ]
         return cls(tid, result, data, epoch, size, outs)
@@ -511,7 +534,7 @@ class MOSDECSubOpWrite(Message):
         enc.i32(self.from_osd)
         enc.str_(self.oid)
         enc.u64(self.off)
-        enc.bytes_(self.data)
+        enc.blob(self.data)
         _enc_map_str_bytes(enc, self.attrs)
         enc.u32(self.epoch)
         enc.i64(self.truncate)
@@ -533,7 +556,7 @@ class MOSDECSubOpWrite(Message):
         pg, shard = _dec_pg(dec)
         msg = cls(
             tid, pg, shard, dec.i32(), dec.str_(), dec.u64(),
-            dec.bytes_(), _dec_map_str_bytes(dec), dec.u32(),
+            dec.blob(), _dec_map_str_bytes(dec), dec.u32(),
             dec.i64(), dec.bool_(), _dec_ev(dec), _dec_ev(dec),
         )
         msg.rmattrs = [dec.str_() for _ in range(dec.u32())]
@@ -653,7 +676,7 @@ class MOSDECSubOpReadReply(Message):
         _enc_pg(enc, self.pg, self.shard)
         enc.i32(self.from_osd)
         enc.i32(self.result)
-        enc.bytes_(self.data)
+        enc.blob(self.data)
         _enc_map_str_bytes(enc, self.attrs)
         enc.u32(self.epoch)
 
@@ -662,7 +685,7 @@ class MOSDECSubOpReadReply(Message):
         tid = dec.u64()
         pg, shard = _dec_pg(dec)
         return cls(
-            tid, pg, shard, dec.i32(), dec.i32(), dec.bytes_(),
+            tid, pg, shard, dec.i32(), dec.i32(), dec.blob(),
             _dec_map_str_bytes(dec), dec.u32(),
         )
 
@@ -700,7 +723,7 @@ class MOSDRepOp(Message):
         _enc_pg(enc, self.pg)
         enc.i32(self.from_osd)
         enc.str_(self.oid)
-        enc.bytes_(self.data)
+        enc.blob(self.data)
         _enc_map_str_bytes(enc, self.attrs)
         enc.bool_(self.delete)
         enc.u32(self.epoch)
@@ -715,7 +738,7 @@ class MOSDRepOp(Message):
         tid = dec.u64()
         pg, _ = _dec_pg(dec)
         msg = cls(
-            tid, pg, dec.i32(), dec.str_(), dec.bytes_(),
+            tid, pg, dec.i32(), dec.str_(), dec.blob(),
             _dec_map_str_bytes(dec), dec.bool_(), dec.u32(), _dec_ev(dec),
         )
         msg.ops = [OSDOp.decode(dec) for _ in range(dec.u32())]
@@ -782,7 +805,7 @@ class MOSDPGPush(Message):
         enc.u32(len(self.pushes))
         for oid, data, attrs in self.pushes:
             enc.str_(oid)
-            enc.bytes_(data)
+            enc.blob(data)
             _enc_map_str_bytes(enc, attrs)
         enc.bool_(self.force)
         enc.u64(self.tid)
@@ -793,7 +816,7 @@ class MOSDPGPush(Message):
         from_osd = dec.i32()
         epoch = dec.u32()
         pushes = [
-            (dec.str_(), dec.bytes_(), _dec_map_str_bytes(dec))
+            (dec.str_(), dec.blob(), _dec_map_str_bytes(dec))
             for _ in range(dec.u32())
         ]
         msg = cls(pg, shard, from_osd, pushes, epoch)

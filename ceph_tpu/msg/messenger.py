@@ -10,8 +10,11 @@ threads; per-connection send serialization replaces the write-queue
 locks.
 
 Messages subclass :class:`Message` and register a wire type id; the
-MESSAGE frame is [header segment | payload segment] like the
-reference's msgr2 message frames (header: type, source entity, seq).
+MESSAGE frame is [header segment | payload segment | data segment]
+like the reference's msgr2 message frames (header: type, source
+entity, seq).  The data segment is there when the message carries a
+payload blob (``Encoder.blob``): the blob is sent from the caller's
+buffer and received as a view of the frame's, never copied between.
 """
 
 from __future__ import annotations
@@ -67,7 +70,10 @@ class Message:
         return f"<{type(self).__name__}>"
 
 
-def encode_message(msg: Message, src: tuple[str, int], seq: int) -> list[bytes]:
+def encode_message(msg: Message, src: tuple[str, int], seq: int,
+                   stats: dict | None = None) -> list:
+    """-> [head, payload], or [head, payload, data] when the message
+    gave a blob: the blob itself, as the caller holds it."""
     head = Encoder()
     head.u32(type(msg).TYPE)
     head.str_(src[0])
@@ -82,10 +88,21 @@ def encode_message(msg: Message, src: tuple[str, int], seq: int) -> list[bytes]:
         trace.encode(head)
     payload = Encoder()
     msg.encode_payload(payload)
-    return [head.bytes(), payload.bytes()]
+    segs = [head.bytes(), payload.bytes()]
+    data = payload.data
+    if data is not None:
+        segs.append(data)
+    if stats is not None:
+        stats["blob_copied_bytes"] += payload.copied
+        if data is not None:
+            stats["data_segs_out"] += 1
+            stats["data_bytes_out"] += len(data)
+    return segs
 
 
-def decode_message(segments: list[bytes]) -> Message:
+def decode_message(segments: list, stats: dict | None = None) -> Message:
+    """The third segment, if there is one, becomes the message's blob
+    as it is: a view of the buffer the frame was received into."""
     dec = Decoder(segments[0])
     mtype = dec.u32()
     src = (dec.str_(), dec.i64())
@@ -98,7 +115,14 @@ def decode_message(segments: list[bytes]) -> Message:
     cls = _REGISTRY.get(mtype)
     if cls is None:
         raise frames.FrameError(f"unknown message type {mtype}")
-    msg = cls.decode_payload(Decoder(segments[1]))
+    data = segments[2] if len(segments) > 2 else None
+    payload = Decoder(segments[1], blob=data)
+    msg = cls.decode_payload(payload)
+    if stats is not None:
+        stats["blob_copied_bytes"] += payload.copied
+        if data is not None:
+            stats["data_segs_in"] += 1
+            stats["data_bytes_in"] += len(data)
     msg.src = src
     msg.trace = trace
     return msg
@@ -165,7 +189,8 @@ class Connection:
         to this peer), ``encode_ms``, ``write_ms`` (``write_frame``
         incl. the drain), and the frame's ``bytes``."""
         self._seq += 1
-        segs = encode_message(msg, self.messenger.entity, self._seq)
+        segs = encode_message(msg, self.messenger.entity, self._seq,
+                              self.messenger.stats)
         tag = frames.Tag.MESSAGE
         if (
             self.compressor is not None
@@ -274,7 +299,7 @@ class Connection:
                 segs = [
                     self.compressor.decompress(s) for s in segs
                 ]
-            msg = decode_message(segs)
+            msg = decode_message(segs, self.messenger.stats)
             msg.conn = self
             await self.messenger._dispatch(msg)
         elif tag == frames.Tag.COMPRESSION_REQUEST:

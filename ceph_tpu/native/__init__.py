@@ -74,10 +74,16 @@ def _load():
                 "on the pure-Python fallbacks", exc)
             _build_failed = True
             return None
-        lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
-        lib.ceph_tpu_crc32c.argtypes = [
-            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
-        ]
+        # the same function twice: through ``lib`` ctypes lets go of
+        # the GIL around the call, through the PyDLL handle it keeps it
+        # (see _GIL_KEPT_BELOW)
+        lib.crc32c_gil_kept = ctypes.PyDLL(so).ceph_tpu_crc32c
+        for fn in (lib.ceph_tpu_crc32c, lib.crc32c_gil_kept):
+            fn.restype = ctypes.c_uint32
+            fn.argtypes = [
+                ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t,
+                ctypes.c_int,
+            ]
         lib.ceph_tpu_crc_backend.restype = ctypes.c_char_p
         lib.ceph_tpu_crc_backend.argtypes = []
         lib.ceph_tpu_xor_region.restype = None
@@ -143,6 +149,18 @@ def _py_crc32c(data: bytes, seed: int) -> int:
 
 # -- public API -------------------------------------------------------------
 
+# A crc of fewer bytes than this is over in a few microseconds, and is
+# made without letting go of the GIL.  The event loop checks some 300
+# frame preambles and small segments per 4 MiB EC write; each release
+# hands the GIL to a store or launch thread, and the loop then waits
+# for it (up to the interpreter's switch interval) before it goes on.
+_GIL_KEPT_BELOW = 64 * 1024
+
+
+def _crc_fn(lib, n: int):
+    return lib.crc32c_gil_kept if n < _GIL_KEPT_BELOW else lib.ceph_tpu_crc32c
+
+
 def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
     """Reference ceph_crc32c(seed, data, len): reflected CRC32C update,
     no init/final inversion (sctp_crc32.c:update_crc32).  ``table``
@@ -157,7 +175,8 @@ def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
     # ctypes as they are, a writable buffer (bytearray, a received
     # segment's memoryview) lends its memory for the call
     if isinstance(data, bytes):
-        return lib.ceph_tpu_crc32c(seed, data, len(data), table)
+        n = len(data)
+        return _crc_fn(lib, n)(seed, data, n, table)
     if isinstance(data, bytearray) or (
             isinstance(data, memoryview) and not data.readonly
             and data.c_contiguous):
@@ -166,13 +185,13 @@ def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
             return seed
         # held for the call: it pins the buffer against a resize
         first = ctypes.c_char.from_buffer(data)
-        return lib.ceph_tpu_crc32c(seed, ctypes.byref(first), n, table)
+        return _crc_fn(lib, n)(seed, ctypes.byref(first), n, table)
     arr = np.ascontiguousarray(
         np.frombuffer(data, dtype=np.uint8)
         if isinstance(data, memoryview)
         else np.asarray(data, dtype=np.uint8).reshape(-1)
     )
-    return lib.ceph_tpu_crc32c(seed, arr.ctypes.data, arr.nbytes, table)
+    return _crc_fn(lib, arr.nbytes)(seed, arr.ctypes.data, arr.nbytes, table)
 
 
 def crc_backend() -> str:

@@ -130,7 +130,7 @@ class ECBackendMixin:
         for shard, osd in live:
             payload = shard_payloads.get(shard, b"")
             if not isinstance(payload, bytes):
-                payload = payload.tobytes()
+                payload = ecutil.row_view(payload)
             if osd == self.id:
                 c = self._shard_coll(pool, pg, shard)
                 o = ghobject_t(oid, shard=shard)

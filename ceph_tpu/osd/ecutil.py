@@ -75,6 +75,15 @@ class StripeInfo:
         return start, self.logical_to_next_stripe_offset((off - start) + length)
 
 
+def row_view(row: np.ndarray) -> memoryview:
+    """A shard row of an encode or decode result, handed on as a view
+    of its bytes and not as a copy of them: to the wire it is a
+    frame's data segment, to the local store the buffer of its pwrite.
+    Nobody writes to such a result again, and nobody may while a full
+    socket or a queued commit still holds the view."""
+    return memoryview(np.ascontiguousarray(row, dtype=np.uint8))
+
+
 def bucket_lanes(
     nbytes: int, *, min_bucket: int, tile_cap: int
 ) -> list[tuple[int, int, int]]:
@@ -109,7 +118,7 @@ def encode(
     arr = (
         np.asarray(data, dtype=np.uint8).reshape(-1)
         if isinstance(data, np.ndarray)
-        else np.frombuffer(bytes(data), dtype=np.uint8)
+        else np.frombuffer(data, dtype=np.uint8)    # bytes or a view
     )
     sw, cs = sinfo.stripe_width, sinfo.chunk_size
     if arr.nbytes % sw:
@@ -182,7 +191,7 @@ async def encode_async(
     arr = (
         np.asarray(data, dtype=np.uint8).reshape(-1)
         if isinstance(data, np.ndarray)
-        else np.frombuffer(bytes(data), dtype=np.uint8)
+        else np.frombuffer(data, dtype=np.uint8)    # bytes or a view
     )
     if not _farm_ready(service, ec_impl, arr.nbytes):
         return encode(sinfo, ec_impl, arr, want)

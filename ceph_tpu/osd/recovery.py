@@ -1408,7 +1408,7 @@ class RecoveryMixin:
                       sum(v.nbytes for v in rebuilt.values()))
         with self.tracer.span("recovery_push", parent=obj_sp):
             results = await asyncio.gather(*(
-                self._push(pool, pg, s, o, oid, rebuilt[s].tobytes(),
+                self._push(pool, pg, s, o, oid, ecutil.row_view(rebuilt[s]),
                            src_attrs, force=force_push)
                 for s, o in targets
             ), return_exceptions=True)  # dead targets retry next pass
@@ -1791,7 +1791,7 @@ class RecoveryMixin:
                     try:
                         await self._push(
                             pool, pg, s, o, oid,
-                            rebuilt[s].tobytes(),
+                            ecutil.row_view(rebuilt[s]),
                             dict(have_attrs or {}), snap=cl.id)
                     except (OSError, asyncio.TimeoutError,
                             ConnectionError):

@@ -352,7 +352,7 @@ class ScrubMixin:
                 osd_of = dict(pairs)
                 await asyncio.gather(*(
                     self._push(pool, pg, s, osd_of[s], oid,
-                               rebuilt[s].tobytes(), src_attrs or {},
+                               ecutil.row_view(rebuilt[s]), src_attrs or {},
                                force=True)
                     for s in bad_shards
                 ))

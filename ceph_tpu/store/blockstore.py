@@ -581,8 +581,7 @@ class BlockStore(ObjectStore):
         elif kind == TxOp.WRITE:
             _, c, o, off, data = op
             meta = self._meta(c, o, view) or _new_meta()
-            wrote = self._write_range(view, c, o, meta, off, bytes(data),
-                                      freed)
+            wrote = self._write_range(view, c, o, meta, off, data, freed)
         elif kind == TxOp.ZERO:
             # zeros need no storage: punch the range out of the extent
             # map — read() zero-fills gaps (BlueStore punch-hole zeroing)

@@ -174,6 +174,8 @@ class MemStore(ObjectStore):
             obj = self._obj(c, o, create=True)
             if len(obj.data) < off + len(data):
                 obj.data.extend(b"\0" * (off + len(data) - len(obj.data)))
+            # the store keeps these bytes: this slice assignment is
+            # the one copy of a borrowed view (Transaction.write)
             obj.data[off : off + len(data)] = data
         elif kind == TxOp.ZERO:
             _, c, o, off, length = op

@@ -92,7 +92,16 @@ class Transaction:
         return self
 
     def write(self, c: coll_t, o: ghobject_t, off: int, data: bytes) -> "Transaction":
-        self.ops.append((TxOp.WRITE, c, o, off, bytes(data)))
+        """``data`` as ``bytes`` or a ``memoryview`` is borrowed, not
+        copied: a view (a received frame's data segment, a row of an
+        encode result) is lent until the transaction has been applied,
+        and whoever lends it does not write to it meanwhile.  A store
+        that keeps the bytes past that copies them when it applies the
+        op.  Anything else (a ``bytearray`` its owner may go on
+        editing) is copied here."""
+        if not isinstance(data, (bytes, memoryview)):
+            data = bytes(data)
+        self.ops.append((TxOp.WRITE, c, o, off, data))
         return self
 
     def zero(self, c: coll_t, o: ghobject_t, off: int, length: int) -> "Transaction":

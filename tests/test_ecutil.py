@@ -145,6 +145,23 @@ def test_crc32c_input_kinds(kind):
         assert native.crc32c(empty, 99) == 99
 
 
+@pytest.mark.parametrize("kind", ["bytes", "memoryview", "numpy"])
+@pytest.mark.parametrize("n", [native._GIL_KEPT_BELOW - 1,
+                               native._GIL_KEPT_BELOW])
+def test_crc32c_same_value_with_the_gil_kept_or_let_go(n, kind):
+    """Below the threshold the call keeps the GIL (a PyDLL handle of
+    the same function), from it on it lets go: one value either way."""
+    buf = _crc_bytes(n, salt=3)
+    data = {"bytes": buf, "memoryview": memoryview(bytearray(buf)),
+            "numpy": np.frombuffer(buf, dtype=np.uint8)}[kind]
+    lib = native._load()
+    kept = n < native._GIL_KEPT_BELOW
+    assert (native._crc_fn(lib, n) is lib.crc32c_gil_kept) == kept
+    assert (native._crc_fn(lib, n) is lib.ceph_tpu_crc32c) != kept
+    assert native.crc32c(data, 5) == native._py_crc32c(buf, 5)
+    assert native.crc32c(data, 5, table=True) == native._py_crc32c(buf, 5)
+
+
 @pytest.mark.parametrize("n", [1000, 6144, 3 * 6144 + 5, 70000])
 def test_crc32c_chaining_across_paths(n):
     # crc(b, crc(a)) == crc(a + b), whichever path computed either part

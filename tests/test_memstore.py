@@ -24,6 +24,27 @@ class TestBasics:
         assert store.read(C, O1) == b"hello"
         assert store.stat(C, O1) == 5
 
+    @pytest.mark.parametrize("size", [100, 300_000])
+    def test_write_borrows_a_view_and_the_store_keeps_its_own(
+            self, store, size):
+        """A memoryview is not copied when the op is built; once the
+        transaction is applied the store holds the bytes itself."""
+        buf = bytearray(bytes(range(256)) * (size // 256 + 1))[:size]
+        want = bytes(buf)
+        t = Transaction().write(C, O1, 0, memoryview(buf))
+        assert t.ops[-1][4].obj is buf
+        store.queue_transaction(t)
+        buf[:] = bytes(size)        # the lender's buffer moves on
+        assert store.read(C, O1) == want
+
+    def test_write_copies_a_bytearray_when_the_op_is_built(self, store):
+        buf = bytearray(b"0123456789" * 1000)
+        want = bytes(buf)
+        t = Transaction().write(C, O1, 0, buf)
+        buf[:5] = b"xxxxx"          # its owner goes on editing
+        store.queue_transaction(t)
+        assert store.read(C, O1) == want
+
     def test_write_extends_with_zero_fill(self, store):
         store.queue_transaction(Transaction().write(C, O1, 8, b"xy"))
         assert store.read(C, O1) == b"\0" * 8 + b"xy"
