@@ -28,5 +28,8 @@ fuzz:
 	$(PY) tools/chaos_fuzz.py --scenarios osd_thrash --budget 2 \
 		--settle-timeout 45
 
+# one cell of the yardstick (needs the TPU: there is no CPU mode);
+# BENCHMARK.json lists the cells, PERF.md says what each metric means
 bench:
-	$(PY) tools/bench_all.py
+	python3 benchmarks/run.py --workload ec83_write --seed 0 --seconds 51 \
+		--trace 0

@@ -213,36 +213,6 @@ declare(
            "inject a connection reset every N sent frames (0 = off); "
            "the reference's ms_inject_socket_failures "
            "(src/common/options/global.yaml.in:1242)"),
-    Option("osd_ec_encode_farm", str, "auto", LEVEL_ADVANCED,
-           "route EC encode/decode matmuls through the multi-device "
-           "encode farm (ceph_tpu/parallel/encode_service.py): auto = "
-           "when the process sees >1 jax device, on, off",
-           enum=("auto", "on", "off")),
-    Option("osd_ec_farm_min_bytes", int, 32768, LEVEL_ADVANCED,
-           "payloads below this stay on the single-device path even "
-           "when the farm is active", min=0),
-    Option("osd_recovery_decode_batch", str, "on", LEVEL_ADVANCED,
-           "coalesce concurrent recovery decodes sharing an erasure "
-           "signature into fixed-shape batched launches "
-           "(ceph_tpu/parallel/decode_batcher.py)",
-           enum=("on", "off")),
-    Option("osd_recovery_decode_batch_window", float, 0.002,
-           LEVEL_ADVANCED,
-           "coalescing window (s) the decode aggregator waits to "
-           "collect concurrent per-object recovery decodes", min=0.0),
-    Option("osd_scrub_verify_batch", str, "on", LEVEL_ADVANCED,
-           "coalesce concurrent deep-scrub shard verifications (crc32c "
-           "+ parity re-encode) across objects and PGs into fixed-shape "
-           "batched launches (ceph_tpu/parallel/scrub_batcher.py)",
-           enum=("on", "off")),
-    Option("osd_scrub_verify_batch_window", float, 0.002,
-           LEVEL_ADVANCED,
-           "coalescing window (s) the scrub verifier waits to collect "
-           "concurrent per-object verification chunks", min=0.0),
-    Option("osd_ec_warmup", str, "on", LEVEL_ADVANCED,
-           "compile the fixed-bucket batched encode/decode shapes of "
-           "each EC profile at map-install time so no XLA compile "
-           "happens inside the I/O path", enum=("on", "off")),
     Option("osd_max_object_read_errors", int, 3, LEVEL_ADVANCED,
            "distinct objects with local medium errors (checksum-at-rest "
            "EIO) before the osd marks ITSELF failed so peering "

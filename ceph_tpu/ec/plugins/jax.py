@@ -54,9 +54,6 @@ class ErasureCodeJax(MatrixErasureCode):
                 f"technique={technique} is not a valid coding technique. "
                 "Choose one of cauchy, reed_sol_van",
             )
-        self.device_min_bytes = self.to_int(
-            "device-min-bytes", profile, str(self.device_min_bytes)
-        )
 
     def get_alignment(self) -> int:
         return TPU_LANE_ALIGN

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import collections
 import logging
-import os
 from typing import Iterable, Mapping
 
 import numpy as np
@@ -41,7 +40,7 @@ log = logging.getLogger("ceph_tpu.ec")
 #: Below this many payload bytes per encode/decode call, host numpy XOR
 #: beats device dispatch latency (SURVEY.md §7 hard part 3: the per-op
 #: path needs a host fallback below a batch-size threshold).
-DEVICE_MIN_BYTES = int(os.environ.get("CEPH_TPU_EC_DEVICE_MIN_BYTES", 1 << 20))
+DEVICE_MIN_BYTES = 1 << 20
 
 #: Decode-matrix LRU capacity (tables are tiny; the reference caches
 #: per-signature decode tables the same way).
@@ -70,9 +69,6 @@ class MatrixErasureCode(ErasureCode):
         self.packetsize = 0
         self.per_chunk_alignment = False
         self._C: np.ndarray | None = None  # row-space coding part
-        # device bit-matrix LRU: erasure signatures rotate during
-        # multi-PG recovery, so one slot would thrash retraces
-        self._device_bits: collections.OrderedDict = collections.OrderedDict()
         self.device_min_bytes = DEVICE_MIN_BYTES
         self._decode_cache: collections.OrderedDict[
             tuple[int, ...], np.ndarray
@@ -169,16 +165,11 @@ class MatrixErasureCode(ErasureCode):
         import jax
 
         from ceph_tpu.ops.rs_kernels import BitmatrixCodec
+        from ceph_tpu.parallel.batcher import device_matrices, matrix_key
 
-        key = M.tobytes()
-        bits = self._device_bits.get(key)
-        if bits is None:
-            bits = jax.device_put(gf_matrix_to_bitmatrix(M))
-            self._device_bits[key] = bits
-            if len(self._device_bits) > DECODE_CACHE_SIZE:
-                self._device_bits.popitem(last=False)
-        else:
-            self._device_bits.move_to_end(key)
+        # the process's device bit-matrix LRU, shared with the engines
+        bits = device_matrices.get(
+            matrix_key(M), lambda: gf_matrix_to_bitmatrix(M))
         # explicit put/get pair: the per-op sync path's one upload and
         # its one by-design host exit (chunks persist to the store)
         out = BitmatrixCodec._apply(bits, jax.device_put(rows), None)

@@ -29,7 +29,7 @@ def _mix_int(a: int, b: int, c: int) -> tuple[int, int, int]:
     """One Jenkins mix round on plain Python ints (scalar fast path:
     the numpy scalar version pays ~µs of ufunc dispatch per op — 135
     per hash — which made per-PG scalar CRUSH mapping stall OSD event
-    loops for seconds; see tools/bench_all.py config 5).  Values are
+    loops for seconds; BASELINE.md config 5).  Values are
     kept masked to 32 bits so >> is a logical shift."""
     a = (a - b - c) & _M32; a ^= c >> 13
     b = (b - c - a) & _M32; b ^= (a << 8) & _M32

@@ -196,21 +196,12 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         self._read_error_ledger: dict[str, int] = {}
         self._disk_escalated = False
         self._death_task: asyncio.Task | None = None
-        # multi-device encode farm (production ECSubWrite-fan-out seam,
-        # SURVEY.md §2.9); resolved lazily so single-device processes
-        # never touch jax at boot
+        # the encode service (production ECSubWrite-fan-out seam,
+        # SURVEY.md §2.9): the one given, else the process's shared one
+        # where it is active; resolved lazily so single-device
+        # processes never touch jax at boot
         self._encode_service = encode_service
         self._encode_service_resolved = encode_service is not None
-        # recovery-decode batching aggregator (parallel/decode_batcher):
-        # per-object recovery decodes coalesce into fixed-shape batched
-        # launches; resolved lazily like the farm
-        self._decode_aggregator = None
-        self._decode_aggregator_resolved = False
-        # deep-scrub verification batcher (parallel/scrub_batcher):
-        # per-object crc32c + parity re-encode checks coalesce into
-        # fixed-shape batched launches; resolved lazily like the farm
-        self._scrub_verifier = None
-        self._scrub_verifier_resolved = False
         # EC profiles whose fixed-bucket shapes have been prewarmed (the
         # no-compile-in-the-I/O-path discipline; see _warm_ec_profiles)
         self._warmed_profiles: set[str] = set()
@@ -889,18 +880,15 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
 
     @property
     def encode_service(self):
-        """The process encode farm, per osd_ec_encode_farm config:
-        'auto' = farm when >1 local jax device, 'on' = always attach the
-        shared service, 'off' = never.  Resolved once, lazily."""
+        """The service EC matmuls coalesce in: the one handed to the
+        constructor, else the process's shared one when it is active (a
+        mesh of several devices, or one TPU).  Resolved once, lazily."""
         if not self._encode_service_resolved:
-            mode = self.conf["osd_ec_encode_farm"]
-            if mode != "off":
-                from ceph_tpu.parallel import encode_service as es
+            from ceph_tpu.parallel import encode_service as es
 
-                svc = es.shared()
-                if svc.active() or mode == "on":
-                    svc.min_bytes = self.conf["osd_ec_farm_min_bytes"]
-                    self._encode_service = svc
+            svc = es.shared()
+            if svc.active():
+                self._encode_service = svc
             # only once shared() has answered: a backend that failed to
             # start raises again on the next use instead of leaving the
             # daemon resolved-to-nothing, serving from numpy
@@ -909,42 +897,24 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
 
     @property
     def decode_aggregator(self):
-        """The process recovery-decode aggregator, per
-        osd_recovery_decode_batch config.  Device-agnostic (the batched
-        XLA kernel is bit-exact on CPU and TPU), so default on."""
-        if not self._decode_aggregator_resolved:
-            self._decode_aggregator_resolved = True
-            if self.conf["osd_recovery_decode_batch"] != "off":
-                from ceph_tpu.parallel import decode_batcher as db
+        """The process recovery-decode aggregator (device-agnostic: the
+        batched XLA kernel is bit-exact on CPU and TPU)."""
+        from ceph_tpu.parallel import decode_batcher
 
-                agg = db.shared()
-                agg.window_s = self.conf[
-                    "osd_recovery_decode_batch_window"]
-                self._decode_aggregator = agg
-        return self._decode_aggregator
+        return decode_batcher.shared()
 
     @property
     def scrub_verifier(self):
-        """The process deep-scrub verification batcher, per
-        osd_scrub_verify_batch config.  Device-agnostic (batched
-        crc32c and re-encode-compare are bit-exact on CPU and TPU),
-        so default on."""
-        if not self._scrub_verifier_resolved:
-            self._scrub_verifier_resolved = True
-            if self.conf["osd_scrub_verify_batch"] != "off":
-                from ceph_tpu.parallel import scrub_batcher as sb
+        """The process deep-scrub verification batcher (device-agnostic
+        like the aggregator)."""
+        from ceph_tpu.parallel import scrub_batcher
 
-                ver = sb.shared()
-                ver.window_s = self.conf["osd_scrub_verify_batch_window"]
-                self._scrub_verifier = ver
-        return self._scrub_verifier
+        return scrub_batcher.shared()
 
     def _dump_scrub_batch(self) -> dict:
         import os as _os
 
         ver = self.scrub_verifier
-        if ver is None:
-            return {"active": False}
         # pid lets multi-process harnesses dedupe the process-wide
         # verifier across co-hosted daemons' sockets
         return {"active": True, "pid": _os.getpid(),
@@ -956,8 +926,6 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         import os as _os
 
         agg = self.decode_aggregator
-        if agg is None:
-            return {"active": False}
         # pid lets multi-process harnesses dedupe the process-wide
         # aggregator across co-hosted daemons' sockets
         out = {"active": True, "pid": _os.getpid(),
@@ -980,7 +948,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         (the discipline the decode aggregator's cold_launches counter
         verifies).  Idempotent per profile name."""
         om = self.osdmap
-        if om is None or self.conf["osd_ec_warmup"] == "off":
+        if om is None:
             return
         fresh = [
             (name, dict(prof))
@@ -1011,10 +979,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     sinfo = self._sinfo(ec)
                     cs = sinfo.chunk_size
                     widths = [max(cs >> 2, 1), cs, cs << 2]
-                    if agg is not None:
-                        agg.prewarm(ec, widths)
-                    if ver is not None:
-                        ver.prewarm(ec, widths)
+                    agg.prewarm(ec, widths)
+                    ver.prewarm(ec, widths)
                     if (svc is not None and farm_warm
                             and hasattr(ec, "coding_matrix")):
                         svc.prewarm(ec.coding_matrix, widths)

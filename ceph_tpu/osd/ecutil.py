@@ -84,28 +84,6 @@ def row_view(row: np.ndarray) -> memoryview:
     return memoryview(np.ascontiguousarray(row, dtype=np.uint8))
 
 
-def bucket_lanes(
-    nbytes: int, *, min_bucket: int, tile_cap: int
-) -> list[tuple[int, int, int]]:
-    """Stripe -> bucket shaping for the batched dispatch layers
-    (parallel/decode_batcher, parallel/scrub_batcher): split a shard
-    payload of ``nbytes`` into column lanes of ``(offset, width,
-    bucket)`` where every bucket is drawn from the CLOSED power-of-two
-    ladder [min_bucket .. tile_cap].  Payloads wider than ``tile_cap``
-    split into full tile_cap lanes (GF matmuls and crc folds are both
-    column-composable); narrower ones pad up to their pow2 bucket —
-    so a prewarmed ladder covers every payload size an OSD can see."""
-    if nbytes <= 0:
-        return []
-    if nbytes <= tile_cap:
-        b = max(nbytes, min_bucket, 1)
-        return [(0, nbytes, 1 << (b - 1).bit_length())]
-    lanes = []
-    for off in range(0, nbytes, tile_cap):
-        lanes.append((off, min(tile_cap, nbytes - off), tile_cap))
-    return lanes
-
-
 def encode(
     sinfo: StripeInfo,
     ec_impl: ErasureCodeInterface,
@@ -263,7 +241,7 @@ async def decode_shards_async(
                              packed_repair=packed_repair)
     inv = {ec_impl.chunk_index(c): c for c in range(ec_impl.get_chunk_count())}
     want_chunks = [inv[s] for s in need]
-    if aggregator is not None and aggregator.active() and to_decode:
+    if aggregator is not None and to_decode:
         rec = await _decode_chunks_batched(
             ec_impl, to_decode, want_chunks, aggregator)
         if rec is not None:

@@ -26,7 +26,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ceph_tpu.ops.rs_kernels import BitmatrixCodec
-from ceph_tpu.parallel.decode_batcher import pow2_bucket
+from ceph_tpu.parallel.batcher import pow2_bucket
 
 
 # -- input shardings --------------------------------------------------------

@@ -1,129 +1,86 @@
 """ScrubVerifier: batched deep-scrub verification with fixed shapes.
 
-Deep scrub was the last per-object host loop in the EC data plane:
-`ScrubMixin._scrub_object` verified one object at a time with host
-`native.crc32c` and re-encoded parity per object (when it checked
-parity at all).  Scrub chunks are a stream of small independent
-checks — the same launch-bound regime the recovery-decode aggregator
-(`parallel/decode_batcher.py`) batches, per the repair-pipelining
-discipline (arxiv 1908.01527) and program-shaped XOR verification
-(arxiv 2108.02692).  This module is that layer for scrub:
+Scrub chunks are a stream of small independent checks, the launch-bound
+regime the decode aggregator batches too (arxiv 1908.01527, 2108.02692).
+Checks in flight in one window — across objects AND PGs: the verifier is
+process-wide — split every shard payload into the CLOSED bucket ladder
+(`batcher.bucket_lanes`) and share two kinds of launches on the skeleton
+of parallel/batcher.py:
 
-- concurrent in-flight scrub checks — across objects AND across PGs
-  (the verifier is process-wide, so co-scheduled PG scrubs sharing an
-  EC profile coalesce) — are collected during a short window;
-- every shard payload splits into the CLOSED power-of-two bucket
-  ladder (`ecutil.bucket_lanes`: pad to pow2 below the 64 KiB tile
-  cap, fixed tile_cap column lanes above it), and two kinds of fixed
-  -shape launches cover a whole group:
+- **batched crc32c**: a (B, W) stack of payload lanes is ONE GF(2)
+  bit-matmul (`ops.hashing.batched_crc32c_device`; crc32c is linear over
+  GF(2)); folding with native ``crc32c_zeros`` / ``crc32c_unadvance``
+  on the host gives the exact per-shard crc32c;
+- **RS re-encode compare**: (B, k, W) data lanes re-encode through the
+  profile's bit-matrix and compare with the stored (B, m, W) parity on
+  device (`ops.rs_kernels.gf_encode_compare`); only a (B, m) mismatch
+  mask comes back: silent parity divergence, which crc chains miss.
 
-  1. **batched crc32c**: a (B, W) stack of payload lanes is ONE
-     GF(2) bit-matmul (`ops.hashing.batched_crc32c_device`) — crc32c
-     is GF(2)-linear, so the device returns every lane's crc
-     contribution at once; host-side folding via native
-     ``crc32c_zeros`` / ``crc32c_unadvance`` recovers the exact
-     per-shard crc32c (bit-identical to the per-object host loop);
-  2. **RS re-encode compare**: (B, k, W) data-shard lanes re-encode
-     through the profile's bit-matrix and compare against the stored
-     (B, m, W) parity lanes on device (`ops.rs_kernels.
-     gf_encode_compare`), returning only a (B, m) mismatch mask —
-     parity never materializes off-device.  This catches silent
-     parity divergence that per-shard crc chains cannot see.
-
-- launch shapes come from the tiny fixed set (#width-buckets x
-  #batch-buckets [x #profiles for the compare kernel]), all compiled
-  by :meth:`prewarm` at daemon map-install — after warmup no XLA
-  compile can occur inside the scrub path, proven by the
-  ``cold_launches`` counter.
-
-Padding is exact in both kernels: encode of zero columns is zero
-columns, and crc of a zero-padded lane is the injective linear
-advance of the true crc — so batched results are bit-identical to the
-per-object host path (pinned by tests/test_scrub_batcher.py).
+:meth:`ScrubVerifier.prewarm` compiles the whole shape set (#buckets x 2
+batch shapes [x #profiles]) at map install; ``cold_launches`` then stays
+0 in the scrub path.  Padding is exact in both kernels (encode of zero
+columns is zero columns; the crc of a zero-padded lane is the injective
+linear advance of the true crc): results are bit-identical to the
+per-object host path (tests/test_scrub_batcher.py).
 """
 
 from __future__ import annotations
 
 import asyncio
-import collections
-import threading
+from typing import NamedTuple
 
 import numpy as np
 
-from ceph_tpu.common.metrics import BucketCounters
-from ceph_tpu.parallel.decode_batcher import (
-    DEFAULT_MAX_BATCH,
-    DEFAULT_MIN_BUCKET,
-    DEFAULT_TILE_CAP,
-)
+from ceph_tpu.parallel import batcher
+from ceph_tpu.parallel.batcher import LaunchBatcher, Request
 
-#: ceiling on the lane dimension of one batched crc launch (crc lanes
-#: are single shard payloads, so many more fit per launch than the
-#: (k, W) re-encode items)
+#: ceiling on the lanes of one crc launch (single shard payloads: many
+#: more fit per launch than the (k, W) re-encode items)
 DEFAULT_CRC_LANES = 32
 
 _SEED = 0xFFFFFFFF
-_BITS_CACHE_SIZE = 64
 
 
-class ObjectCheck:
-    """One object's batched verification result.
-
-    ``crcs`` maps shard id -> crc32c of the shard payload (seed -1,
-    reference ceph_crc32c semantics — bit-identical to the host
-    ``native.crc32c`` loop).  ``parity_bad`` is the set of shard ids
-    whose stored parity disagrees with a re-encode of the data shards,
-    or None when the parity check was not applicable (caller falls
-    back to the host re-encode path)."""
-
-    __slots__ = ("crcs", "parity_bad")
-
-    def __init__(self, crcs: dict[int, int],
-                 parity_bad: frozenset[int] | None):
-        self.crcs = crcs
-        self.parity_bad = parity_bad
+class ObjectCheck(NamedTuple):
+    """One object's batched verification result."""
+    #: shard id -> crc32c of its payload (seed -1, ceph_crc32c semantics)
+    crcs: dict[int, int]
+    #: shard ids whose stored parity disagrees with a re-encode of the
+    #: data shards; None when the check did not apply (host path then)
+    parity_bad: frozenset[int] | None
 
 
-class ScrubVerifier:
+class CrcLane(NamedTuple):
+    """Item of a ``("crc", bucket)`` group: one payload lane."""
+    arr: np.ndarray
+    width: int
+
+
+class EncLane(NamedTuple):
+    """Item of an ``("enc", matrix signature, bucket)`` group: one
+    object's (k, width) data and (m, width) stored parity lanes."""
+    C: np.ndarray
+    data: np.ndarray
+    parity: np.ndarray
+
+
+class ScrubVerifier(LaunchBatcher):
     """Coalesces concurrent deep-scrub checks into fixed-shape batched
-    crc32c + re-encode-compare launches.
+    crc32c + re-encode-compare launches (jitted XLA, bit-exact on CPU
+    and TPU; a failed dispatch answers its lanes from the host path)."""
 
-    Device-agnostic: both kernels are jitted XLA paths that run
-    bit-exactly on CPU and TPU; any dispatch failure answers the
-    affected lanes from the native host path, so behavior is always
-    identical to per-object verification.
-    """
+    fallback_stat = "dispatch_fallbacks"   # ``fallbacks``: whole objects
 
     def __init__(self, *, window_s: float = 0.002,
-                 max_batch: int = DEFAULT_MAX_BATCH,
+                 max_batch: int = batcher.DEFAULT_MAX_BATCH,
                  crc_lanes: int = DEFAULT_CRC_LANES,
-                 min_bucket: int = DEFAULT_MIN_BUCKET,
-                 tile_cap: int = DEFAULT_TILE_CAP):
-        self.window_s = window_s
+                 min_bucket: int = batcher.DEFAULT_MIN_BUCKET,
+                 tile_cap: int = batcher.DEFAULT_TILE_CAP):
+        super().__init__("scrub_verify_batch", window_s=window_s)
         self.max_batch = max_batch
         self.crc_lanes = crc_lanes
         self.min_bucket = min_bucket
         self.tile_cap = tile_cap
-        #: bucket width -> [(lane view, width, fut)] awaiting a crc
-        self._crc_pending: dict[int, list[tuple]] = {}
-        #: (matrix signature, bucket) -> [(C, data, parity, fut)]
-        self._enc_pending: dict[tuple, list[tuple]] = {}
-        self._flush_handle = None
-        self._bits_cache: collections.OrderedDict = collections.OrderedDict()
-        self._warm: set[tuple] = set()
-        # guards ONLY the warm/claimed sets — never held across a
-        # compile (device-sync-under-lock); see decode_batcher for the
-        # claim/compile/notify pattern
-        self._warm_lock = threading.Lock()
-        self._warm_cv = threading.Condition(self._warm_lock)
-        self._warm_claimed: set[tuple] = set()
-        self.stats = collections.Counter()
-        self.metrics = BucketCounters("scrub_verify_batch")
-
-    # -- gating --------------------------------------------------------
-
-    def active(self) -> bool:
-        return True
 
     @staticmethod
     def _parity_eligible(ec_impl, payloads) -> bool:
@@ -132,29 +89,30 @@ class ScrubVerifier:
         ``parity_bad=None`` and the scrubber keeps its host path."""
         from ceph_tpu.ec.plugins.matrix_base import MatrixErasureCode
 
-        if not isinstance(ec_impl, MatrixErasureCode):
-            return False
-        if ec_impl.rows_per_chunk != 1 or ec_impl.get_sub_chunk_count() != 1:
+        if (not isinstance(ec_impl, MatrixErasureCode)
+                or ec_impl.rows_per_chunk != 1
+                or ec_impl.get_sub_chunk_count() != 1):
             return False
         n = ec_impl.get_chunk_count()
-        shards = {ec_impl.chunk_index(c) for c in range(n)}
-        if set(payloads) != shards:
+        if set(payloads) != {ec_impl.chunk_index(c) for c in range(n)}:
             return False
         sizes = {len(p) for p in payloads.values()}
         return len(sizes) == 1 and sizes.pop() > 0
+
+    def _lanes(self, nbytes: int) -> list[tuple[int, int, int]]:
+        return batcher.bucket_lanes(
+            nbytes, min_bucket=self.min_bucket, tile_cap=self.tile_cap)
 
     # -- request side --------------------------------------------------
 
     async def verify_object(
         self, ec_impl, payloads: dict[int, np.ndarray]
     ) -> ObjectCheck | None:
-        """Verify one object's shard payloads, coalescing the device
-        work with every other concurrent caller.  Returns None when the
-        whole check could not run batched (callers then take the
-        per-object host path verbatim)."""
-        from ceph_tpu.osd.ecutil import bucket_lanes
+        """Verify one object's shard payloads, batched with every other
+        concurrent caller's.  None: take the per-object host path."""
+        from ceph_tpu.native import crc32c_zeros
+        from ceph_tpu.ops.hashing import crc32c_unadvance
 
-        loop = asyncio.get_running_loop()
         arrs = {
             s: (np.frombuffer(bytes(p), dtype=np.uint8)
                 if isinstance(p, (bytes, bytearray, memoryview))
@@ -162,348 +120,187 @@ class ScrubVerifier:
                     np.asarray(p, dtype=np.uint8).reshape(-1)))
             for s, p in payloads.items()
         }
-        crc_futs: dict[int, list[tuple[int, int, asyncio.Future]]] = {}
-        for s, arr in arrs.items():
-            lanes = bucket_lanes(
-                arr.nbytes, min_bucket=self.min_bucket,
-                tile_cap=self.tile_cap)
-            futs = []
-            for off, width, bucket in lanes:
-                fut = loop.create_future()
-                self._crc_pending.setdefault(bucket, []).append(
-                    (arr[off:off + width], width, fut))
-                futs.append((width, bucket, fut))
-            crc_futs[s] = futs
-
+        crc_futs = {
+            s: [(width, bucket, self.submit(
+                    ("crc", bucket), CrcLane(arr[off:off + width], width)))
+                for off, width, bucket in self._lanes(arr.nbytes)]
+            for s, arr in arrs.items()
+        }
         enc_futs: list[asyncio.Future] | None = None
-        k = m = 0
         if ec_impl is not None and self._parity_eligible(ec_impl, arrs):
             k = ec_impl.get_data_chunk_count()
-            m = ec_impl.get_chunk_count() - k
+            parity_ids = [ec_impl.chunk_index(c)
+                          for c in range(k, ec_impl.get_chunk_count())]
             C = np.asarray(ec_impl.coding_matrix, dtype=np.uint8)
-            sig = C.shape[0].to_bytes(2, "little") + C.tobytes()
-            size = len(next(iter(arrs.values())))
-            enc_futs = []
-            for off, width, bucket in bucket_lanes(
-                    size, min_bucket=self.min_bucket,
-                    tile_cap=self.tile_cap):
-                fut = loop.create_future()
-                data = np.stack([
-                    arrs[ec_impl.chunk_index(c)][off:off + width]
-                    for c in range(k)
-                ])
-                parity = np.stack([
-                    arrs[ec_impl.chunk_index(k + j)][off:off + width]
-                    for j in range(m)
-                ])
-                self._enc_pending.setdefault((sig, bucket), []).append(
-                    (C, data, parity, fut))
-                enc_futs.append(fut)
-
+            sig = batcher.matrix_key(C)
+            enc_futs = [
+                self.submit(("enc", sig, bucket), EncLane(
+                    C,
+                    np.stack([arrs[ec_impl.chunk_index(c)][off:off + width]
+                              for c in range(k)]),
+                    np.stack([arrs[s][off:off + width]
+                              for s in parity_ids])))
+                for off, width, bucket in self._lanes(
+                    len(next(iter(arrs.values()))))
+            ]
         self.stats["objects"] += 1
-        if self._flush_handle is None and (
-                self._crc_pending or self._enc_pending):
-            self._flush_handle = loop.call_later(self.window_s, self._flush)
-
-        from ceph_tpu.native import crc32c_zeros
-
-        from ceph_tpu.ops.hashing import crc32c_unadvance
-
         try:
             crcs: dict[int, int] = {}
             for s, futs in crc_futs.items():
-                c = _SEED
-                pad = 0
+                c, pad = _SEED, 0
                 for width, bucket, fut in futs:
                     c = crc32c_zeros(bucket, c) ^ await fut
                     pad = bucket - width
                 crcs[s] = crc32c_unadvance(c, pad)
-            parity_bad: frozenset[int] | None = None
-            if enc_futs is not None:
-                bad: set[int] = set()
-                for fut in enc_futs:
-                    mask = await fut
-                    bad.update(
-                        ec_impl.chunk_index(k + j)
-                        for j in range(m) if mask[j]
-                    )
-                parity_bad = frozenset(bad)
-            return ObjectCheck(crcs, parity_bad)
+            if enc_futs is None:
+                return ObjectCheck(crcs, None)
+            bad: set[int] = set()
+            for fut in enc_futs:
+                mask = await fut
+                bad.update(s for s, hit in zip(parity_ids, mask) if hit)
+            return ObjectCheck(crcs, frozenset(bad))
         except Exception:
             self.stats["fallbacks"] += 1
             return None
 
-    # -- dispatch side -------------------------------------------------
+    # -- the plans -----------------------------------------------------
 
-    def _flush(self) -> None:
-        """call_later callback: hand pending groups to worker threads —
-        JAX dispatch must not run on the event loop."""
-        self._flush_handle = None
-        crc_pending, self._crc_pending = self._crc_pending, {}
-        enc_pending, self._enc_pending = self._enc_pending, {}
-        loop = asyncio.get_running_loop()
-        for bucket, group in crc_pending.items():
-            loop.create_task(self._dispatch(
-                group, lambda g, w=bucket: self._run_crc_group(w, g),
-                lambda g, w=bucket: self._host_crc_group(w, g)))
-        for (_sig, bucket), group in enc_pending.items():
-            loop.create_task(self._dispatch(
-                group, lambda g, w=bucket: self._run_enc_group(w, g),
-                self._host_enc_group))
+    def _run_group(self, key, group: list[Request]) -> list:
+        return getattr(self, f"_run_{key[0]}_group")(key[-1], group)
 
-    async def _dispatch(self, group, run, host_fallback) -> None:
-        try:
-            outs = await asyncio.to_thread(run, group)
-        except Exception:
-            self.stats["dispatch_fallbacks"] += 1
-            outs = await asyncio.to_thread(host_fallback, group)
-        for item, out in zip(group, outs):
-            fut = item[-1]
-            if not fut.done():
-                fut.set_result(out)
+    def _host_group(self, key, group: list[Request]) -> list:
+        return getattr(self, f"_host_{key[0]}_group")(key[-1], group)
 
     def _crc_mat(self, bucket: int):
-        import jax.numpy as jnp
-
-        from ceph_tpu.ops.compile_cache import ensure_persistent_cache
         from ceph_tpu.ops.hashing import crc32c_matrix
 
-        key = ("crc", bucket)
-        hit = self._bits_cache.get(key)
-        if hit is None:
-            ensure_persistent_cache()
-            hit = jnp.asarray(crc32c_matrix(bucket))
-            self._bits_cache[key] = hit
-            if len(self._bits_cache) > _BITS_CACHE_SIZE:
-                self._bits_cache.popitem(last=False)
-        else:
-            self._bits_cache.move_to_end(key)
-        return hit
+        return self._matrix(("crc", bucket), lambda: crc32c_matrix(bucket))
 
-    def _enc_bits(self, C: np.ndarray):
-        import jax.numpy as jnp
-
-        from ceph_tpu.ops.compile_cache import ensure_persistent_cache
-        from ceph_tpu.ops.gf256 import gf_matrix_to_bitmatrix
-
-        key = ("enc", C.shape[0].to_bytes(2, "little") + C.tobytes())
-        hit = self._bits_cache.get(key)
-        if hit is None:
-            ensure_persistent_cache()
-            hit = jnp.asarray(gf_matrix_to_bitmatrix(C))
-            self._bits_cache[key] = hit
-            if len(self._bits_cache) > _BITS_CACHE_SIZE:
-                self._bits_cache.popitem(last=False)
-        else:
-            self._bits_cache.move_to_end(key)
-        return hit
-
-    def _note_launch(self, shape_key, kind, w, b, b_real,
-                     real_bytes, padded_bytes):
-        cold = shape_key not in self._warm
-        if cold:
-            self._warm.add(shape_key)
-            self.stats["cold_launches"] += 1
-            self.metrics.inc("cold_launches", w=w, b=b, k=kind)
+    def _count(self, kind: str, b_real: int) -> None:
         self.stats["launches"] += 1
         self.stats[f"{kind}_launches"] += 1
         self.stats["batched_lanes"] += b_real
-        self.metrics.inc("launches", w=w, b=b, k=kind)
-        self.metrics.inc("occupied_lanes", w=w, b=b, k=kind, by=b_real)
-        self.metrics.inc("padded_lanes", w=w, b=b, k=kind, by=b)
-        self.metrics.inc("occupied_bytes", w=w, b=b, k=kind, by=real_bytes)
-        self.metrics.inc("padded_bytes", w=w, b=b, k=kind, by=padded_bytes)
-        # device-launch profiling span (common/tracing.device_tracer):
-        # wraps the launch via the returned context manager, tagged
-        # with bucket shape, occupancy and cold-compile verdict
-        from ceph_tpu.common.tracing import device_tracer
 
-        return device_tracer().span(
-            "xla_launch", stage="device", kind=f"scrub_{kind}",
-            w=w, b=b, b_real=b_real, occupancy=round(b_real / b, 3),
-            cold=cold,
-        )
-
-    def _run_crc_group(self, w: int, group: list[tuple]) -> list[int]:
+    def _run_crc_group(self, w: int, group: list[Request]) -> list[int]:
         """Worker-thread body: batched crc32c launches over one bucket;
         returns each lane's raw device crc (L_W of the padded lane)."""
         import jax
 
-        from ceph_tpu.common.transfer_guard import no_implicit_transfers
         from ceph_tpu.ops.hashing import batched_crc32c_device
 
         mat = self._crc_mat(w)
-        outs: list[int] = [0] * len(group)
-        for at in range(0, len(group), self.crc_lanes):
-            chunk = group[at:at + self.crc_lanes]
-            b_real = len(chunk)
-            # two batch shapes only (1 and max): one compiled program
-            # per bucket regardless of how many lanes coalesced
-            b = 1 if b_real == 1 else self.crc_lanes
+        outs: list[int] = []
+        for chunk, b in batcher.batch_chunks(group, self.crc_lanes):
             batch = np.zeros((b, w), np.uint8)
-            for j, (arr, width, _f) in enumerate(chunk):
-                batch[j, :width] = arr
+            for j, req in enumerate(chunk):
+                batch[j, :req.item.width] = req.item.arr
             # explicit put/get only: one upload of the lane batch, one
-            # (B,)-word gather of the crc contributions (the by-design
-            # host exit — crcs fold host-side via crc32c_zeros algebra)
-            with self._note_launch(
-                ("crc", b, w), "crc", w, b, b_real,
-                sum(width for _, width, _ in chunk), b * w,
-            ), no_implicit_transfers("scrub_crc"):
+            # (B,)-word gather (by design: crcs fold on the host)
+            with self._launching(
+                ("crc", b, w), (), kind="scrub_crc", guard="scrub_crc",
+                w=w, b=b, k="crc", b_real=len(chunk),
+                real_bytes=sum(req.item.width for req in chunk),
+                padded_bytes=b * w,
+            ):
                 out = jax.device_get(jax.block_until_ready(
                     batched_crc32c_device(mat, jax.device_put(batch))))
-            for j in range(b_real):
-                outs[at + j] = int(out[j])
+            self._count("crc", len(chunk))
+            outs += [int(c) for c in out[:len(chunk)]]
         return outs
 
     @staticmethod
-    def _host_crc_group(w: int, group: list[tuple]) -> list[int]:
+    def _host_crc_group(w: int, group: list[Request]) -> list[int]:
         from ceph_tpu.native import crc32c, crc32c_zeros
 
         # L_W of the padded lane == advance of the seed-0 crc through
         # the pad, so the host answer folds identically downstream
-        return [
-            crc32c_zeros(w - width, crc32c(arr, 0))
-            for arr, width, _f in group
-        ]
+        return [crc32c_zeros(w - req.item.width, crc32c(req.item.arr, 0))
+                for req in group]
 
-    def _run_enc_group(self, w: int, group: list[tuple]) -> list[np.ndarray]:
+    def _run_enc_group(self, w: int,
+                       group: list[Request]) -> list[np.ndarray]:
         """Worker-thread body: batched re-encode-compare launches for
         one (profile, bucket); returns each item's (m,) mismatch mask."""
         import jax
 
-        from ceph_tpu.common.transfer_guard import no_implicit_transfers
         from ceph_tpu.ops.rs_kernels import gf_encode_compare
 
-        C = group[0][0]
-        bits = self._enc_bits(C)
+        C = group[0].item.C
+        bits = self._bits(C)
         m, k = C.shape
-        outs: list[np.ndarray] = [None] * len(group)
-        for at in range(0, len(group), self.max_batch):
-            chunk = group[at:at + self.max_batch]
-            b_real = len(chunk)
-            b = 1 if b_real == 1 else self.max_batch
+        outs: list[np.ndarray] = []
+        for chunk, b in batcher.batch_chunks(group, self.max_batch):
             data = np.zeros((b, k, w), np.uint8)
             parity = np.zeros((b, m, w), np.uint8)
-            for j, (_C, d, p, _f) in enumerate(chunk):
-                data[j, :, :d.shape[1]] = d
-                parity[j, :, :p.shape[1]] = p
+            for j, req in enumerate(chunk):
+                data[j, :, :req.item.data.shape[1]] = req.item.data
+                parity[j, :, :req.item.parity.shape[1]] = req.item.parity
             # explicit put/get only; the gather is the tiny (B, m)
             # mismatch mask — parity itself never leaves the device
-            with self._note_launch(
-                (bits.shape, b, k, w), "enc", w, b, b_real,
-                sum((k + m) * d.shape[1] for _C, d, _p, _f in chunk),
-                b * (k + m) * w,
-            ), no_implicit_transfers("scrub_enc"):
+            with self._launching(
+                (bits.shape, b, k, w), (), kind="scrub_enc",
+                guard="scrub_enc", w=w, b=b, k="enc", b_real=len(chunk),
+                real_bytes=sum(
+                    (k + m) * req.item.data.shape[1] for req in chunk),
+                padded_bytes=b * (k + m) * w,
+            ):
                 out = jax.device_get(jax.block_until_ready(
                     gf_encode_compare(bits, jax.device_put(data),
                                       jax.device_put(parity))))
-            for j in range(b_real):
-                outs[at + j] = out[j]
+            self._count("enc", len(chunk))
+            outs += list(out[:len(chunk)])
         return outs
 
     @staticmethod
-    def _host_enc_group(group: list[tuple]) -> list[np.ndarray]:
+    def _host_enc_group(_w: int, group: list[Request]) -> list[np.ndarray]:
         from ceph_tpu.ops.gf256 import gf_matmul
 
-        return [
-            np.any(gf_matmul(C, d) != p, axis=-1)
-            for C, d, p, _f in group
-        ]
+        return [np.any(gf_matmul(req.item.C, req.item.data)
+                       != req.item.parity, axis=-1) for req in group]
 
     # -- warmup --------------------------------------------------------
 
     def prewarm(self, ec_impl=None, widths=None, *, batches=None) -> int:
-        """Compile every launch shape this verifier can dispatch: the
-        crc kernel over the full bucket ladder, plus the re-encode
-        compare for ``ec_impl``'s code when given.  Blocking — call
-        from daemon warmup (map install), never the scrub path.
-        Returns the number of programs compiled."""
-        import jax
+        """Compile every launch shape this verifier can dispatch: crc
+        over the full bucket ladder, plus the re-encode compare of
+        ``ec_impl``'s code.  Blocking: warmup only.  Returns the count."""
         import jax.numpy as jnp
 
-        from ceph_tpu.ops.compile_cache import ensure_persistent_cache
         from ceph_tpu.ops.hashing import batched_crc32c_device
         from ceph_tpu.ops.rs_kernels import gf_encode_compare
 
-        ensure_persistent_cache()
-        buckets = set()
-        w = self.min_bucket
-        while w <= self.tile_cap:
-            buckets.add(w)
-            w <<= 1
-        for x in widths or ():
-            x = max(min(x, self.tile_cap), self.min_bucket, 1)
-            buckets.add(1 << (x - 1).bit_length())
-        n = 0
-        wanted: list[tuple] = []
-        todo: list[tuple] = []  # (key, compile thunk) claimed by US
-        ec_bits = None
+        buckets = batcher.bucket_ladder(
+            self.min_bucket, self.tile_cap, widths)
+        shapes = [("crc", b, w)
+                  for w in buckets for b in (1, self.crc_lanes)]
         if ec_impl is not None and getattr(
                 ec_impl, "rows_per_chunk", 1) == 1 and hasattr(
                 ec_impl, "coding_matrix"):
             C = np.asarray(ec_impl.coding_matrix, dtype=np.uint8)
             ec_m, ec_k = C.shape
-            ec_bits = self._enc_bits(C)
-        with self._warm_cv:
-            for w in sorted(buckets):
-                for b in (1, self.crc_lanes):
-                    key = ("crc", b, w)
-                    wanted.append(key)
-                    if key in self._warm or key in self._warm_claimed:
-                        continue
-                    self._warm_claimed.add(key)
-                    todo.append(key)
-            if ec_bits is not None:
-                for w in sorted(buckets):
-                    for b in (batches or (1, self.max_batch)):
-                        key = (ec_bits.shape, b, ec_k, w)
-                        wanted.append(key)
-                        if key in self._warm or key in self._warm_claimed:
-                            continue
-                        self._warm_claimed.add(key)
-                        todo.append(key)
-        try:
-            for key in todo:
-                if key[0] == "crc":
-                    _, b, w = key
-                    jax.block_until_ready(batched_crc32c_device(
-                        self._crc_mat(w), jnp.zeros((b, w), np.uint8)))
-                else:
-                    _, b, k_, w = key
-                    jax.block_until_ready(gf_encode_compare(
-                        ec_bits, jnp.zeros((b, k_, w), np.uint8),
-                        jnp.zeros((b, ec_m, w), np.uint8)))
-                with self._warm_cv:
-                    self._warm.add(key)
-                    self._warm_cv.notify_all()
-                n += 1
-        finally:
-            with self._warm_cv:
-                self._warm_claimed.difference_update(todo)
-                self._warm_cv.notify_all()
-        with self._warm_cv:
-            self._warm_cv.wait_for(lambda: all(
-                key in self._warm or key not in self._warm_claimed
-                for key in wanted), timeout=120.0)
-        self.stats["prewarmed_shapes"] += n
-        self.metrics.inc("prewarmed_shapes", by=n)
-        return n
+            ec_bits = self._bits(C)
+            shapes += [(ec_bits.shape, b, ec_k, w) for w in buckets
+                       for b in batches or (1, self.max_batch)]
 
+        def compile_one(key: tuple):
+            if key[0] == "crc":
+                _, b, w = key
+                return batched_crc32c_device(
+                    self._crc_mat(w), jnp.zeros((b, w), np.uint8))
+            _, b, k, w = key
+            return gf_encode_compare(
+                ec_bits, jnp.zeros((b, k, w), np.uint8),
+                jnp.zeros((b, ec_m, w), np.uint8))
 
-_shared: ScrubVerifier | None = None
+        return self._prewarm(shapes, compile_one)
 
 
 def shared() -> ScrubVerifier:
-    """Process-wide verifier (one compiled-shape set per process, so
-    co-hosted daemons' scrubs coalesce across PGs)."""
-    global _shared
-    if _shared is None:
-        _shared = ScrubVerifier()
-    return _shared
+    """Process-wide verifier (co-hosted daemons' scrubs coalesce across
+    PGs in it)."""
+    return batcher.shared("scrub", ScrubVerifier)
 
 
 def reset_shared() -> None:
     """Test hook: drop the process-wide verifier."""
-    global _shared
-    _shared = None
+    batcher.reset_shared("scrub")

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from ceph_tpu.ops.rs_kernels import gf_bitmatmul
-from ceph_tpu.parallel.decode_batcher import pow2_bucket
+from ceph_tpu.parallel.batcher import pow2_bucket
 
 _dispatch_lock = threading.Lock()
 

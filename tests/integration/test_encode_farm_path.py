@@ -171,10 +171,7 @@ class TestSingleDeviceCoalescing:
             svc = es.EncodeService(
                 device=jax.devices()[0], min_bytes=4096, window_s=0.005)
 
-            async with Cluster(
-                n_osds=6,
-                osd_conf={"osd_ec_encode_farm": "on"},
-            ) as c:
+            async with Cluster(n_osds=6) as c:
                 for o in c.osds:
                     o._encode_service = svc
                     o._encode_service_resolved = True

@@ -18,7 +18,8 @@ import pytest
 
 from ceph_tpu.ec import registry
 from ceph_tpu.osd import ecutil
-from ceph_tpu.parallel.decode_batcher import DecodeAggregator, pow2_bucket
+from ceph_tpu.parallel.batcher import pow2_bucket
+from ceph_tpu.parallel.decode_batcher import DecodeAggregator
 
 
 def _ec(k=4, m=2):

@@ -203,8 +203,7 @@ def test_cluster_writes_through_the_mesh_service_store_reference_shards(
     svc = es.EncodeService(mesh4, min_bytes=4096, window_s=0.005)
 
     async def go():
-        async with Cluster(n_osds=6,
-                           osd_conf={"osd_ec_encode_farm": "on"}) as c:
+        async with Cluster(n_osds=6) as c:
             for o in c.osds:
                 o._encode_service = svc
                 o._encode_service_resolved = True

@@ -22,6 +22,7 @@ import pytest
 from ceph_tpu.ec import registry
 from ceph_tpu.native import crc32c
 from ceph_tpu.osd import ecutil
+from ceph_tpu.parallel.batcher import bucket_lanes
 from ceph_tpu.parallel.scrub_batcher import ScrubVerifier
 
 
@@ -53,14 +54,14 @@ def _host_parity_bad(ec, shards):
 
 class TestBucketLanes:
     def test_closed_ladder(self):
-        assert ecutil.bucket_lanes(0, min_bucket=4096, tile_cap=65536) == []
-        assert ecutil.bucket_lanes(100, min_bucket=4096, tile_cap=65536) == [
+        assert bucket_lanes(0, min_bucket=4096, tile_cap=65536) == []
+        assert bucket_lanes(100, min_bucket=4096, tile_cap=65536) == [
             (0, 100, 4096)]
-        assert ecutil.bucket_lanes(4097, min_bucket=4096, tile_cap=65536) == [
+        assert bucket_lanes(4097, min_bucket=4096, tile_cap=65536) == [
             (0, 4097, 8192)]
-        assert ecutil.bucket_lanes(65536, min_bucket=4096, tile_cap=65536) == [
+        assert bucket_lanes(65536, min_bucket=4096, tile_cap=65536) == [
             (0, 65536, 65536)]
-        lanes = ecutil.bucket_lanes(150000, min_bucket=4096, tile_cap=65536)
+        lanes = bucket_lanes(150000, min_bucket=4096, tile_cap=65536)
         assert lanes == [(0, 65536, 65536), (65536, 65536, 65536),
                          (131072, 18928, 65536)]
         # every bucket is on the pow2 ladder => prewarm covers them all
