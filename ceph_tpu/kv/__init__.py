@@ -130,8 +130,12 @@ class MemDB:
     """Ordered in-RAM KeyValueDB."""
 
     def __init__(self):
-        # prefix -> {key: value}; sorted key list derived on iteration
+        # prefix -> {key: value}
         self._cf: dict[str, dict[str, bytes]] = {}
+        # prefix -> the family's keys in order, kept from one
+        # get_iterator to the next while the key SET stands; _apply
+        # drops it (never edits it: open iterators hold the old list)
+        self._sorted: dict[str, list[str]] = {}
         self._lock = threading.RLock()
 
     def submit(self, batch: WriteBatch, sync: bool = True) -> None:
@@ -143,26 +147,40 @@ class MemDB:
             kind = op[0]
             if kind == "set":
                 _, p, k, v = op
-                self._cf.setdefault(p, {})[k] = v
+                cf = self._cf.setdefault(p, {})
+                if k not in cf:
+                    self._sorted.pop(p, None)
+                cf[k] = v
             elif kind == "rm":
                 _, p, k = op
-                self._cf.get(p, {}).pop(k, None)
+                if self._cf.get(p, {}).pop(k, None) is not None:
+                    self._sorted.pop(p, None)
             elif kind == "rmrange":
                 _, p, s, e = op
                 cf = self._cf.get(p, {})
-                for k in [k for k in cf if s <= k < e]:
+                dead = [k for k in cf if s <= k < e]
+                for k in dead:
                     del cf[k]
+                if dead:
+                    self._sorted.pop(p, None)
             elif kind == "rmprefix":
                 self._cf.pop(op[1], None)
+                self._sorted.pop(op[1], None)
 
     def get(self, prefix: str, key: str) -> bytes | None:
         with self._lock:
             return self._cf.get(prefix, {}).get(key)
 
     def get_iterator(self, prefix: str) -> Iterator:
+        """The family as it is now: what is submitted afterwards does
+        not reach an open iterator (values are copied; the key list is
+        shared between iterators and never edited)."""
         with self._lock:
             cf = self._cf.get(prefix, {})
-            return Iterator(sorted(cf), dict(cf))
+            keys = self._sorted.get(prefix)
+            if keys is None:
+                keys = self._sorted[prefix] = sorted(cf)
+            return Iterator(keys, dict(cf))
 
     def prefixes(self) -> list[str]:
         with self._lock:
@@ -286,6 +304,7 @@ class FileDB(MemDB):
             (nk,) = struct.unpack_from("<I", blob, off)
             off += 4
             cf = self._cf.setdefault(p, {})
+            self._sorted.pop(p, None)
             for _ in range(nk):
                 k = take().decode()
                 cf[k] = bytes(take())

@@ -1227,7 +1227,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                 if "kv" in m:       # the commit ran through every phase
                     phases = {
                         "lock_wait_ms": 1e3 * (m["locked"] - m["enter"]),
-                        "data_ms": 1e3 * (m["data"] - m["locked"]),
+                        "validate_ms": 1e3 * (m["validated"] - m["locked"]),
+                        "data_ms": 1e3 * (m["data"] - m["validated"]),
                         "fsync_ms": 1e3 * (m["fsync"] - m["data"]),
                         "kv_ms": 1e3 * (m["kv"] - m["fsync"]),
                     }

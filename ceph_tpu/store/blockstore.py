@@ -480,7 +480,8 @@ class BlockStore(ObjectStore):
         marks["enter"] = time.monotonic()
         with self._txn_lock:
             marks["locked"] = time.monotonic()
-            self._validate(txn)
+            validate_transaction(self, txn)
+            marks["validated"] = time.monotonic()
             batch = WriteBatch()
             view = _TxnView(self.db, batch)
             freed: list[str] = []     # blobs to free AFTER commit
@@ -778,9 +779,83 @@ class BlockStore(ObjectStore):
                 view.set(prefix, dbase + key[len(sbase):], val)
         return False
 
-    # -- validation (shared shape with KStore) -------------------------
 
-    _validate = None  # assigned below
+def validate_transaction(store: ObjectStore, txn: Transaction) -> None:
+    """MemStore-grade structural checks, raising before anything is
+    written so a transaction applies whole or not at all.  Called under
+    the store's transaction lock; KStore imports it back.
+
+    It costs what the transaction names, not what the store holds: a
+    collection is asked for by name (``collection_exists``, one point
+    lookup, once a transaction) and an object likewise (``exists``);
+    ``colls`` and ``objs`` overlay what earlier ops of this same
+    transaction made or removed.  Only RMCOLL lists, and only the
+    objects of the collection it removes."""
+    colls: dict[coll_t, bool] = {}
+    objs: dict[tuple, bool] = {}
+
+    def coll_exists(c):
+        have = colls.get(c)
+        if have is None:
+            have = colls[c] = store.collection_exists(c)
+        return have
+
+    def obj_exists(c, o):
+        key = (c, o)
+        if key not in objs:
+            objs[key] = store.exists(c, o)
+        return objs[key]
+
+    for op in txn.ops:
+        kind = op[0]
+        if kind == TxOp.MKCOLL:
+            if coll_exists(op[1]):
+                raise FileExistsError(f"collection {op[1]} exists")
+            colls[op[1]] = True
+        elif kind == TxOp.RMCOLL:
+            if not coll_exists(op[1]):
+                raise FileNotFoundError(f"collection {op[1]}")
+            # ENOTEMPTY semantics (MemStore parity): account for
+            # objects created/removed earlier in this same txn
+            residual = set()
+            if store.collection_exists(op[1]):
+                residual = {(op[1], o) for o in store.collection_list(op[1])}
+            for (oc, oo), alive in objs.items():
+                if oc == op[1]:
+                    (residual.add if alive else residual.discard)((oc, oo))
+            if residual:
+                raise OSError(f"collection {op[1]} not empty")
+            colls[op[1]] = False
+        elif kind == TxOp.COLL_MOVE_RENAME:
+            _, src_c, src_o, dst_c, dst_o = op
+            if not coll_exists(src_c) or not obj_exists(src_c, src_o):
+                raise FileNotFoundError(f"{src_c}/{src_o}")
+            if not coll_exists(dst_c):
+                raise FileNotFoundError(f"collection {dst_c}")
+            if obj_exists(dst_c, dst_o):
+                raise FileExistsError(f"{dst_c}/{dst_o}")
+            objs[(src_c, src_o)] = False
+            objs[(dst_c, dst_o)] = True
+        else:
+            c = op[1]
+            if not coll_exists(c):
+                raise FileNotFoundError(f"collection {c}")
+            if kind == TxOp.CLONE:
+                _, _, src, dst = op
+                if not obj_exists(c, src):
+                    raise FileNotFoundError(f"{c}/{src}")
+                objs[(c, dst)] = True
+            elif kind == TxOp.REMOVE:
+                _, _, o = op
+                if not obj_exists(c, o):
+                    raise FileNotFoundError(f"{c}/{o}")
+                objs[(c, o)] = False
+            elif kind == TxOp.RMATTR:
+                _, _, o, _name = op
+                if not obj_exists(c, o):
+                    raise FileNotFoundError(f"{c}/{o}")
+            else:
+                objs[(op[1], op[2])] = True
 
 
 def _new_meta() -> dict:
@@ -796,9 +871,3 @@ def _parse_blob(blob: str) -> tuple[int, int, int, str, int]:
     if len(parts) == 5:
         return unit, units, crc, parts[3], int(parts[4])
     return unit, units, crc, "", 0
-
-
-# the structural validation rules are identical to KStore's
-from ceph_tpu.store.kstore import KStore as _KStore  # noqa: E402
-
-BlockStore._validate = _KStore._validate

@@ -339,6 +339,7 @@ class BlueFSLite(MemDB):
             (nk,) = struct.unpack_from("<I", blob, off)
             off += 4
             cf = self._cf.setdefault(p, {})
+            self._sorted.pop(p, None)
             for _ in range(nk):
                 k = take().decode()
                 cf[k] = bytes(take())

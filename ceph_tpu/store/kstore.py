@@ -265,8 +265,13 @@ class KStore(ObjectStore):
         # to ONE atomic WriteBatch (the all-or-nothing contract); a
         # _TxnView overlays the batch's own mutations so later ops in
         # the same txn read their predecessors' effects
+        # BlockStore owns the one definition of the validation (it
+        # imports this module's key helpers, so the way back is taken
+        # at call time)
+        from ceph_tpu.store.blockstore import validate_transaction
+
         with self._txn_lock:
-            self._validate(txn)
+            validate_transaction(self, txn)
             batch = WriteBatch()
             view = _TxnView(self.db, batch)
             for op in txn.ops:
@@ -395,66 +400,3 @@ class KStore(ObjectStore):
         base = _okey(c, o) + SEP
         for prefix in ("D", "X", "M"):
             view.rm_range(prefix, base, _prefix_end(base))
-
-    # -- validation (MemStore-grade structural checks) -----------------
-
-    def _validate(self, txn: Transaction) -> None:
-        have_coll = {c for c in self.list_collections()}
-        objs: dict[tuple, bool] = {}
-
-        def obj_exists(c, o):
-            key = (c, o)
-            if key not in objs:
-                objs[key] = self.exists(c, o)
-            return objs[key]
-
-        for op in txn.ops:
-            kind = op[0]
-            if kind == TxOp.MKCOLL:
-                if op[1] in have_coll:
-                    raise FileExistsError(f"collection {op[1]} exists")
-                have_coll.add(op[1])
-            elif kind == TxOp.RMCOLL:
-                if op[1] not in have_coll:
-                    raise FileNotFoundError(f"collection {op[1]}")
-                # ENOTEMPTY semantics (MemStore parity): account for
-                # objects created/removed earlier in this same txn
-                residual = set()
-                if self.collection_exists(op[1]):
-                    residual = {(op[1], o) for o in self.collection_list(op[1])}
-                for (oc, oo), alive in objs.items():
-                    if oc == op[1]:
-                        (residual.add if alive else residual.discard)((oc, oo))
-                if residual:
-                    raise OSError(f"collection {op[1]} not empty")
-                have_coll.discard(op[1])
-            elif kind == TxOp.COLL_MOVE_RENAME:
-                _, src_c, src_o, dst_c, dst_o = op
-                if src_c not in have_coll or not obj_exists(src_c, src_o):
-                    raise FileNotFoundError(f"{src_c}/{src_o}")
-                if dst_c not in have_coll:
-                    raise FileNotFoundError(f"collection {dst_c}")
-                if obj_exists(dst_c, dst_o):
-                    raise FileExistsError(f"{dst_c}/{dst_o}")
-                objs[(src_c, src_o)] = False
-                objs[(dst_c, dst_o)] = True
-            else:
-                c = op[1]
-                if c not in have_coll:
-                    raise FileNotFoundError(f"collection {c}")
-                if kind == TxOp.CLONE:
-                    _, _, src, dst = op
-                    if not obj_exists(c, src):
-                        raise FileNotFoundError(f"{c}/{src}")
-                    objs[(c, dst)] = True
-                elif kind == TxOp.REMOVE:
-                    _, _, o = op
-                    if not obj_exists(c, o):
-                        raise FileNotFoundError(f"{c}/{o}")
-                    objs[(c, o)] = False
-                elif kind == TxOp.RMATTR:
-                    _, _, o, _name = op
-                    if not obj_exists(c, o):
-                        raise FileNotFoundError(f"{c}/{o}")
-                else:
-                    objs[(op[1], op[2])] = True

@@ -78,9 +78,9 @@ class Transaction:
 
     ``marks`` is the store's to fill while it applies the transaction:
     ``time.monotonic()`` at the boundaries of its commit phases
-    (BlockStore: ``enter``, ``locked``, ``data``, ``fsync``, ``kv``), for
-    whoever submitted it to read afterwards.  A store with no phases
-    worth telling apart leaves it empty."""
+    (BlockStore: ``enter``, ``locked``, ``validated``, ``data``,
+    ``fsync``, ``kv``), for whoever submitted it to read afterwards.
+    A store with no phases worth telling apart leaves it empty."""
 
     ops: list[tuple] = field(default_factory=list)
     on_applied: list[Callable[[], None]] = field(default_factory=list)

@@ -26,6 +26,12 @@ def store(tmp_path):
 
 
 class TestBlockStoreSpecifics:
+    def test_a_write_transaction_opens_no_iterator(self, store, monkeypatch):
+        """validation asks for the collections a transaction names; it
+        does not list the OSD's (PR 31): a shard commit's whole
+        ``queue_transaction`` sorts no column family."""
+        assert iterators_opened_by_a_shard_write(store, monkeypatch) == []
+
     def test_large_write_lands_in_block_file_with_checksum(self, store):
         data = os.urandom(3 * MIN_ALLOC + 123)
         store.queue_transaction(Transaction().write(C, O1, 0, data))

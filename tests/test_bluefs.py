@@ -4,7 +4,13 @@ generations, WAL replay after kill, checkpoint compaction, shared
 space accounting (reference src/os/bluestore/BlueFS.cc)."""
 
 import os
+import sys
+import threading
+import time
 
+import pytest
+
+from ceph_tpu.kv import FileDB, MemDB, WriteBatch
 from ceph_tpu.store import Transaction, coll_t, ghobject_t
 from ceph_tpu.store.blockstore import MIN_ALLOC, BlockStore
 from ceph_tpu.store.bluefs import SUPER_UNITS, BlueFSLite
@@ -165,3 +171,152 @@ def test_torn_superblock_falls_back_to_previous_generation(tmp_path):
     # (freed extents are not reused until a later allocation)
     assert s2.read(C, _obj("o")) == b"keep"
     s2.umount()
+
+
+# -- the iterator all three KeyValueDBs inherit from MemDB --------------------
+
+@pytest.fixture(params=["memdb", "filedb", "bluefs"])
+def db(request, tmp_path):
+    if request.param == "memdb":
+        return MemDB()
+    if request.param == "filedb":
+        d = FileDB(str(tmp_path / "kv"))
+        d.mount()
+        return d
+    s = BlockStore(str(tmp_path / "bs"))
+    s.mount()
+    assert isinstance(s.db, BlueFSLite)
+    return s.db
+
+
+def _drain(it):
+    out = []
+    while it.valid():
+        out.append((it.key(), it.value()))
+        it.next()
+    return out
+
+
+def _seed(db):
+    b = WriteBatch()
+    for k in ("m", "c", "x", "a"):
+        b.set("T", k, k.encode())
+    db.submit(b)
+    return [(k, k.encode()) for k in ("a", "c", "m", "x")]
+
+
+def test_open_iterator_keeps_keys_and_values_of_its_opening(db):
+    was = _seed(db)
+    it = db.get_iterator("T").seek_to_first()
+    db.submit(WriteBatch().set("T", "b", b"new").set("T", "c", b"over")
+              .rmkey("T", "m").rm_range("T", "w", "y"))
+    assert _drain(it) == was
+    assert _drain(db.get_iterator("T").seek_to_first()) == [
+        ("a", b"a"), ("b", b"new"), ("c", b"over")]
+
+
+_KEY_SET_CHANGES = {
+    "set_of_a_new_key": (
+        lambda b: b.set("T", "b", b"new"),
+        [("a", b"a"), ("b", b"new"), ("c", b"c"), ("m", b"m"), ("x", b"x")]),
+    "rmkey": (
+        lambda b: b.rmkey("T", "c"),
+        [("a", b"a"), ("m", b"m"), ("x", b"x")]),
+    "rm_range": (
+        lambda b: b.rm_range("T", "b", "n"),
+        [("a", b"a"), ("x", b"x")]),
+    "rm_prefix": (
+        lambda b: b.rm_prefix("T"), []),
+    "rm_prefix_then_set": (
+        lambda b: b.rm_prefix("T").set("T", "z", b"z").set("T", "d", b"d"),
+        [("d", b"d"), ("z", b"z")]),
+    "value_overwrite_with_the_key_set_as_it_was": (
+        lambda b: b.set("T", "m", b"M2"),
+        [("a", b"a"), ("c", b"c"), ("m", b"M2"), ("x", b"x")]),
+    "rmkey_of_a_key_that_is_not_there": (
+        lambda b: b.rmkey("T", "q"),
+        [("a", b"a"), ("c", b"c"), ("m", b"m"), ("x", b"x")]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KEY_SET_CHANGES))
+def test_fresh_iterator_is_ordered_and_complete_after(db, case):
+    change, now = _KEY_SET_CHANGES[case]
+    was = _seed(db)
+    assert _drain(db.get_iterator("T").seek_to_first()) == was  # list kept
+    db.submit(change(WriteBatch()))
+    assert _drain(db.get_iterator("T").seek_to_first()) == now
+    it = db.get_iterator("T").lower_bound("b")
+    assert _drain(it) == [kv for kv in now if kv[0] >= "b"]
+    # another family's keys were never touched
+    assert _drain(db.get_iterator("U").seek_to_first()) == []
+
+
+def test_iterators_share_the_key_list_while_the_key_set_stands(db):
+    _seed(db)
+    first = db.get_iterator("T")
+    db.submit(WriteBatch().set("T", "m", b"M2"))
+    second = db.get_iterator("T")
+    assert second._keys is first._keys           # no second sort
+    db.submit(WriteBatch().set("T", "b", b"new"))
+    third = db.get_iterator("T")
+    assert third._keys is not first._keys
+    assert first._keys == ["a", "c", "m", "x"]   # dropped, never edited
+
+
+def test_remount_lists_what_was_committed(tmp_path):
+    """checkpoint load and WAL replay fill the families behind
+    ``_apply``'s back or through it: either way a fresh iterator after
+    mount is ordered and complete."""
+    d = FileDB(str(tmp_path / "kv"))
+    d.mount()
+    _seed(d)
+    d.umount()                                    # checkpoints
+    d = FileDB(str(tmp_path / "kv"))
+    d.mount()
+    d.submit(WriteBatch().set("T", "b", b"new"))  # into the WAL
+    want = [("a", b"a"), ("b", b"new"), ("c", b"c"), ("m", b"m"),
+            ("x", b"x")]
+    assert _drain(d.get_iterator("T").seek_to_first()) == want
+    d2 = FileDB(str(tmp_path / "kv"))             # replay, no umount
+    d2.mount()
+    assert _drain(d2.get_iterator("T").seek_to_first()) == want
+
+
+def test_iterators_opened_while_other_threads_submit_are_whole():
+    """The kept key list is shared state: writers drop it while readers
+    take it.  Every iterator must still be one moment of the family —
+    its keys in order and exactly the keys of its own values."""
+    d = MemDB()
+    stop = time.monotonic() + 0.5
+    torn = []
+
+    def write(n):
+        i = 0
+        while time.monotonic() < stop:
+            i += 1
+            d.submit(WriteBatch().set("T", f"{n}-{i % 50:03d}", b"v")
+                     .rmkey("T", f"{n}-{(i + 25) % 50:03d}"))
+
+    def read():
+        while time.monotonic() < stop:
+            it = d.get_iterator("T")
+            if it._keys != sorted(it._data):
+                torn.append((list(it._keys), sorted(it._data)))
+                return
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=write, args=(n,))
+                   for n in range(4)]
+        threads += [threading.Thread(target=read) for _ in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert torn == []
+

@@ -29,6 +29,11 @@ def store(request, tmp_path):
 
 
 class TestKStoreSpecifics:
+    def test_a_write_transaction_opens_no_iterator(self, store, monkeypatch):
+        """validation asks for the collections a transaction names; it
+        does not list the store's (PR 31)."""
+        assert iterators_opened_by_a_shard_write(store, monkeypatch) == []
+
     def test_blocking_commit_forwards_db(self, tmp_path):
         assert KStore().blocking_commit is False
         assert KStore(FileDB(str(tmp_path / "kv"))).blocking_commit is True

@@ -170,3 +170,144 @@ class TestAtomicity:
             )
         assert store.read(c2, O2) == b"live"  # untouched
         assert store.read(C, O1) == b"src"
+
+
+# -- validation: what a transaction may name, op for op -----------------------
+
+C2 = coll_t(1, 1, 2)
+MISSING = coll_t(9, 9)
+GONE = ghobject_t("nope", shard=2)
+
+
+def _contents(store):
+    return {
+        c: {o: (store.read(c, o), store.getattrs(c, o), store.omap_get(c, o))
+            for o in store.collection_list(c)}
+        for c in store.list_collections()
+    }
+
+
+# every rejected transaction first clobbers O1, which validation lets
+# through, so "the store is as it was" has something to show
+_REJECTED = {
+    "second_mkcoll": (
+        lambda t: t.create_collection(C),
+        FileExistsError, "collection .* exists"),
+    "mkcoll_twice_in_one_txn": (
+        lambda t: t.create_collection(C2).create_collection(C2),
+        FileExistsError, "collection .* exists"),
+    "write_into_missing_collection": (
+        lambda t: t.write(MISSING, O1, 0, b"x"),
+        FileNotFoundError, "collection "),
+    "rmcoll_of_missing_collection": (
+        lambda t: t.remove_collection(MISSING),
+        FileNotFoundError, "collection "),
+    "rmcoll_of_nonempty_collection": (
+        lambda t: t.remove_collection(C),
+        OSError, "not empty"),
+    "rmcoll_of_one_emptied_then_refilled_in_the_txn": (
+        lambda t: t.remove(C, O1).touch(C, O2).remove_collection(C),
+        OSError, "not empty"),
+    "rmcoll_then_write_in_one_txn": (
+        lambda t: t.remove(C, O1).remove_collection(C).write(C, O2, 0, b"x"),
+        FileNotFoundError, "collection "),
+    "rmcoll_twice_in_one_txn": (
+        lambda t: t.remove(C, O1).remove_collection(C).remove_collection(C),
+        FileNotFoundError, "collection "),
+    "move_rename_of_missing_object": (
+        lambda t: t.collection_move_rename(C, GONE, C, O2),
+        FileNotFoundError, "/"),
+    "move_rename_out_of_missing_collection": (
+        lambda t: t.collection_move_rename(MISSING, O1, C, O2),
+        FileNotFoundError, "/"),
+    "move_rename_into_missing_collection": (
+        lambda t: t.collection_move_rename(C, O1, MISSING, O2),
+        FileNotFoundError, "collection "),
+    "move_rename_onto_object_made_in_the_txn": (
+        lambda t: t.touch(C, O2).collection_move_rename(C, O1, C, O2),
+        FileExistsError, "/"),
+    "clone_of_missing_object": (
+        lambda t: t.clone(C, GONE, O2),
+        FileNotFoundError, "/"),
+    "remove_of_missing_object": (
+        lambda t: t.remove(C, GONE),
+        FileNotFoundError, "/"),
+    "remove_twice_in_one_txn": (
+        lambda t: t.remove(C, O1).remove(C, O1),
+        FileNotFoundError, "/"),
+    "rmattr_of_missing_object": (
+        lambda t: t.rmattr(C, GONE, "a"),
+        FileNotFoundError, "/"),
+}
+
+_ACCEPTED = {
+    "rmcoll_of_one_emptied_earlier_in_the_txn": (
+        lambda t: t.remove(C, O1).remove_collection(C),
+        lambda s: not s.collection_exists(C)),
+    "mkcoll_then_write_in_one_txn": (
+        lambda t: t.create_collection(C2).write(C2, O2, 0, b"new"),
+        lambda s: s.read(C2, O2) == b"new"),
+    "move_rename_into_collection_made_in_the_txn": (
+        lambda t: t.create_collection(C2).collection_move_rename(
+            C, O1, C2, O2),
+        lambda s: s.read(C2, O2) == b"keep" and not s.exists(C, O1)),
+    "rmcoll_then_mkcoll_then_write_in_one_txn": (
+        lambda t: t.remove(C, O1).remove_collection(C).create_collection(C)
+        .write(C, O2, 0, b"again"),
+        lambda s: s.read(C, O2) == b"again" and not s.exists(C, O1)),
+    "mkcoll_then_rmcoll_in_one_txn": (
+        lambda t: t.create_collection(C2).remove_collection(C2),
+        lambda s: not s.collection_exists(C2) and s.read(C, O1) == b"keep"),
+    "remove_then_recreate_then_rmattr_in_one_txn": (
+        lambda t: t.remove(C, O1).touch(C, O1).rmattr(C, O1, "a"),
+        lambda s: s.read(C, O1) == b"" and s.getattrs(C, O1) == {}),
+}
+
+
+class TestValidation:
+    @pytest.fixture
+    def filled(self, store):
+        store.queue_transaction(
+            Transaction().write(C, O1, 0, b"keep")
+            .setattrs(C, O1, {"a": b"1"}).omap_setkeys(C, O1, {"k": b"v"}))
+        return store
+
+    @pytest.mark.parametrize("case", sorted(_REJECTED))
+    def test_rejected_with_its_error_and_nothing_written(self, filled, case):
+        build, exc, text = _REJECTED[case]
+        before = _contents(filled)
+        with pytest.raises(exc, match=text) as caught:
+            filled.queue_transaction(
+                build(Transaction().write(C, O1, 0, b"clobber")))
+        assert type(caught.value) is exc
+        assert _contents(filled) == before
+
+    @pytest.mark.parametrize("case", sorted(_ACCEPTED))
+    def test_accepted_when_an_earlier_op_made_it_valid(self, filled, case):
+        build, holds = _ACCEPTED[case]
+        filled.queue_transaction(build(Transaction()))
+        assert holds(filled)
+
+
+def iterators_opened_by_a_shard_write(store, monkeypatch, collections=200):
+    """Fill a kv-backed store with ``collections`` collections, then
+    commit one transaction shaped like an EC shard write and count the
+    iterators its db was asked for (each one sorts a column family)."""
+    t = Transaction()
+    for ps in range(collections):
+        t.create_collection(coll_t(7, ps, 2))
+    store.queue_transaction(t)
+    opened = []
+    real = store.db.get_iterator
+    monkeypatch.setattr(
+        store.db, "get_iterator",
+        lambda prefix: opened.append(prefix) or real(prefix))
+    c = coll_t(7, collections // 2, 2)
+    store.queue_transaction(
+        Transaction().touch(c, O1).write(c, O1, 0, b"s" * 70000)
+        .truncate(c, O1, 70000)
+        .setattrs(c, O1, {"hinfo": b"h", "v": b"1", "reqid": b"r"})
+        .omap_setkeys(c, O1, {"log": b"e"}))
+    assert store.read(c, O1) == b"s" * 70000
+    return opened
+

@@ -23,7 +23,8 @@ from ceph_tpu.parallel import encode_service as es
 
 K, M, N_OSDS, PG_NUM, OBJ_BYTES = 2, 1, 4, 4, 64 << 10
 OFF = {"trace_sample_rate": 0.0, "trace_tail_slow_s": 0.0}
-PHASE_TAGS = {"lock_wait_ms", "data_ms", "fsync_ms", "kv_ms", "bytes"}
+PHASE_TAGS = {"lock_wait_ms", "validate_ms", "data_ms", "fsync_ms", "kv_ms",
+              "bytes"}
 SEND_TAGS = {"lock_wait_ms", "encode_ms", "write_ms", "bytes"}
 #: the zero-length arrival marker PR 25 removed (spelled so that a grep
 #: for the name over ceph_tpu/ and tests/ finds nothing)
