@@ -1,5 +1,10 @@
 """One deployment in this process: 1 mon + n OSDs on BlockStore and a
-client, the pool built from the configuration file.
+client, the pool built from the configuration file: ``pool`` gives type,
+size or plugin, k, m, failure domain, PGs, and for an EC pool optionally
+``technique`` and ``profile``, a dict of strings that goes into the
+erasure-code profile as it is (``d``, ``scalar_mds``, ``l``, ...).
+``reference`` names the file that says what its stored copies must be
+(``harness/verify.py``).
 
 A copy of ``chip_smoke.SmokeCluster`` (PR 21), kept here so that later
 PRs can change the program's smoke without moving the yardstick.  The
@@ -26,6 +31,7 @@ class Cluster:
     def __init__(self, config: dict, data_dir: str, *, op_timeout: float,
                  encode_service=None):
         self.pool = config["pool"]
+        self.reference = config.get("reference")
         self.n_osds = int(config["osds"])
         self.data_dir = data_dir
         self.op_timeout = op_timeout
@@ -101,9 +107,12 @@ class Cluster:
         if self.erasure:
             from ceph_tpu.ec import registry
 
-            profile = {"plugin": p["plugin"], "technique": p["technique"],
-                       "k": str(p["k"]), "m": str(p["m"]),
-                       "crush-failure-domain": p["failure_domain"]}
+            profile = {"plugin": p["plugin"], "k": str(p["k"]),
+                       "m": str(p["m"]),
+                       "crush-failure-domain": p["failure_domain"],
+                       **p.get("profile", {})}
+            if "technique" in p:
+                profile["technique"] = p["technique"]
             await self.client.ec_profile_set(POOL, dict(profile))
             await self.client.pool_create(
                 POOL, pg_num=p["pg_num"], pool_type="erasure",
@@ -183,8 +192,12 @@ class Cluster:
                    if om.max_osd > o and om.is_up(o))
 
     def counters(self) -> dict:
-        """Every counter a per-layer metric or ``correct`` reads, as one
-        flat dict of running totals."""
+        """Every counter the program keeps, as one flat dict of running
+        totals: the engines' and the guard's under their own prefixes,
+        every numeric key of the live OSDs' ``perf.dump()`` summed under
+        ``osd.<key>``, every key of the messengers' ``stats`` (live OSDs,
+        mon, client) summed under ``msgr.<key>``.  A key nobody has
+        counted yet is absent: read it with ``.get(key, 0)``."""
         from ceph_tpu.common import transfer_guard
         from ceph_tpu.ec.plugins.matrix_base import MatrixErasureCode
         from ceph_tpu.parallel import decode_batcher as db
@@ -195,8 +208,13 @@ class Cluster:
                     for k, v in transfer_guard.snapshot().items()})
         out.update({f"plugin.{k}": v
                     for k, v in MatrixErasureCode.device_stats.items()})
-        dumps = [o.perf.dump() for o in self.live_osds()]
-        for key in ("recovery_ops", "recovery_decode_bytes",
-                    "recovery_decode_seconds"):
-            out[f"osd.{key}"] = sum(d.get(key, 0) for d in dumps)
+        for osd in self.live_osds():
+            for k, v in osd.perf.dump().items():
+                if isinstance(v, (int, float)):
+                    key = "osd." + k
+                    out[key] = out.get(key, 0) + v
+        for daemon in (*self.live_osds(), self.mon, self.client):
+            for k, v in daemon.messenger.stats.items():
+                key = "msgr." + k
+                out[key] = out.get(key, 0) + v
         return out

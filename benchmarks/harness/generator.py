@@ -6,11 +6,22 @@
                                   after one object to every PG (see
                                   ``touch_every_pg``)
   loop: {op} | null               closed loop, ``in_flight`` ops, through
-                                  warm-up and window
+                                  warm-up and window.  ``write_full``: a new
+                                  object per op.  ``read``: one whole
+                                  prefilled object per op, drawn uniformly
+                                  with replacement by a generator seeded by a
+                                  constant; every read's bytes are compared
+                                  with the payload acknowledged for that name
   warmup_ops                      loop ops that must end before the window
-  fault: {stop_osd} | null        applied last in set-up: the OSD is stopped,
-                                  marked down by the mon, then marked out;
-                                  the window opens when that is acknowledged
+  fault: {stop_osd, out} | null   the OSD is stopped and marked down by the
+                                  mon.  ``out`` true (or absent): then marked
+                                  out, and the window opens when that is
+                                  acknowledged.  ``out`` false: it stays in
+                                  (degraded, nothing may recover), and the
+                                  window opens once the client's map shows
+                                  it down.  Applied last in set-up; before a
+                                  ``read`` loop starts, so that no read is in
+                                  flight to an OSD that stops
   counter, counter_metric         a ``Cluster.counters()`` key read through
                                   the window, and the end-to-end metric its
                                   growth per second is reported as
@@ -20,8 +31,12 @@
   slice_seconds, op_timeout_s, verify_sample
   trace: {start_s, seconds}       where in the window a traced run traces
 
-Object names never depend on the seed, so every op lands on the same PG
-and OSDs in every run; the seed makes the bytes.
+Object names, and which of them a read loop reads in what order, never
+depend on the seed, so every op lands on the same PG and OSDs in every
+run; the seed makes the bytes.  A mix with another object size, a read
+loop or an OSD that is down and in is a data file; a pool with another
+code or plugin is a configuration file (``harness/cluster.py``) and, for
+what its shards must hold, a reference beside it (``harness/verify.py``).
 """
 
 from __future__ import annotations
@@ -41,6 +56,11 @@ log = logging.getLogger("bench")
 SET_UP_TIMEOUT = 300.0
 POLL_S = 0.05       # how often the traffic's counter is read
 DONE_S = 1.0        # a counter still for this long at the end has ended
+READ_ORDER_SEED = 33    # which objects a read loop reads: never ``--seed``
+
+
+class WrongBytes(Exception):
+    """A read was answered, with other bytes than were acknowledged."""
 
 
 def payload(seed: int, i: int, nbytes: int) -> bytes:
@@ -57,38 +77,69 @@ class Traffic:
                       for i in range(params["distinct_payloads"])]
         self.next_n = 0
         self.acked: dict[str, int] = {}     # name -> index of its payload
+        self.readable: list[str] = []       # the prefilled names, in order
         self.ended = 0
+        self.wrong = 0      # reads answered with other bytes, at any time
+        self.reads = (params.get("loop") or {}).get("op") == "read"
+        self.lost_osd: int | None = None
+        self.loss: str | None = None    # "recovers" | "stays_degraded"
         self.window: Window | None = None
         self.stop = False
         self._workers: list[asyncio.Task] = []
+        self._read_order = np.random.default_rng(READ_ORDER_SEED)
+        self._loop_ops = {"write_full": self._write_one,
+                          "read": self._read_one}
 
     def name(self, n: int) -> str:
         return self.p["object_name"].format(n=n)
 
-    async def _one(self, name: str | None = None) -> None:
-        n, self.next_n = self.next_n, self.next_n + 1
-        name = name or self.name(n)
-        blob = self.blobs[n % len(self.blobs)]
+    async def _timed(self, name: str, op) -> bool:
+        """One op from submit to answer: ``op()`` returns the bytes it
+        moved.  An op that raises is counted as failed, the run goes on."""
         t0 = time.monotonic()
         try:
-            await self.c.io.write_full(name, blob)
-            ok = True
-        except Exception as exc:     # counted as failed, the run goes on
-            log.warning("%s not acknowledged: %r", name, exc)
-            ok = False
+            nbytes, ok = await op(), True
+        except Exception as exc:
+            log.warning("%s failed: %r", name, exc)
+            nbytes, ok = 0, False
         t1 = time.monotonic()
         self.ended += 1
-        if ok:
-            self.acked[name] = n % len(self.blobs)
         if self.window is not None:
-            self.window.op_ended(t1, t1 - t0, len(blob), ok)
+            self.window.op_ended(t1, t1 - t0, nbytes, ok)
+        return ok
+
+    async def _write_one(self, name: str | None = None) -> None:
+        n, self.next_n = self.next_n, self.next_n + 1
+        name, i = name or self.name(n), n % len(self.blobs)
+
+        async def op() -> int:
+            await self.c.io.write_full(name, self.blobs[i])
+            return len(self.blobs[i])
+
+        if await self._timed(name, op):
+            self.acked[name] = i
+
+    async def _read_one(self) -> None:
+        name = self.readable[int(self._read_order.integers(
+            len(self.readable)))]
+        want = self.blobs[self.acked[name]]
+
+        async def op() -> int:
+            got = await self.c.io.read(name)
+            if got != want:
+                self.wrong += 1
+                raise WrongBytes(f"{len(got)} bytes read are not the "
+                                 f"{len(want)} acknowledged")
+            return len(got)
+
+        await self._timed(name, op)
 
     async def _bounded(self, names: list) -> None:
         sem = asyncio.Semaphore(self.p["in_flight"])
 
         async def one(name):
             async with sem:
-                await self._one(name)
+                await self._write_one(name)
 
         before = len(self.acked)
         await asyncio.gather(*(one(name) for name in names))
@@ -110,20 +161,25 @@ class Traffic:
         await self._bounded(list(by_pg.values()))
 
     async def prefill(self) -> None:
-        await self._bounded([None] * self.p["prefill_objects"])
+        first, n = self.next_n, self.p["prefill_objects"]
+        await self._bounded([None] * n)
+        self.readable = [self.name(i) for i in range(first, first + n)]
 
-    async def _worker(self) -> None:
+    async def _worker(self, one) -> None:
         while not self.stop:
-            await self._one()
+            await one()
 
     async def start_loop_and_warm_up(self) -> None:
         loop = self.p.get("loop")
         if not loop:
             return
-        if loop["op"] != "write_full":
+        one = self._loop_ops.get(loop["op"])
+        if one is None:
             raise ValueError(f"unknown loop op {loop['op']!r}")
+        if self.reads and not self.readable:
+            raise ValueError("a read loop needs prefill_objects")
         target = self.ended + self.p["warmup_ops"]
-        self._workers = [asyncio.ensure_future(self._worker())
+        self._workers = [asyncio.ensure_future(self._worker(one))
                          for _ in range(self.p["in_flight"])]
         deadline = time.monotonic() + SET_UP_TIMEOUT
         while self.ended < target:
@@ -132,12 +188,16 @@ class Traffic:
             await asyncio.sleep(0.01)
 
     async def apply_fault(self) -> int | None:
-        """Stop the OSD, wait for the mon to mark it down, mark it out.
-        Returns the epoch the client's map must reach before verify."""
+        """Stop the OSD, wait until the client's map shows it down and,
+        unless the fault says ``"out": false``, mark it out.  Returns
+        the epoch the client's map must reach before verify."""
         fault = self.p.get("fault")
         if not fault:
             return None
         c, victim = self.c, fault["stop_osd"] % self.c.n_osds
+        self.lost_osd = victim
+        self.loss = "recovers" if fault.get("out", True) \
+            else "stays_degraded"
         await c.osds[victim].stop()
         c.osds[victim] = None
         deadline = time.monotonic() + SET_UP_TIMEOUT
@@ -145,6 +205,8 @@ class Traffic:
             if time.monotonic() > deadline:
                 raise TimeoutError(f"mon never marked osd.{victim} down")
             await c.client._wait_new_map(c.client.osdmap.epoch, timeout=1.0)
+        if self.loss == "stays_degraded":
+            return None
         code, rs, _ = await c.client.command(
             {"prefix": "osd out", "id": str(victim)})
         if code != 0:
@@ -168,7 +230,7 @@ class Traffic:
         value, grew_at = None, w.t0
         while (now := time.monotonic()) < w.t_end:
             if key:
-                read = self.c.counters()[key]
+                read = self.c.counters().get(key, 0)
                 if value is None or read > value:
                     value, grew_at = read, now
                 if now >= w.t0 + len(w.readings) * w.slice_s:
@@ -176,7 +238,8 @@ class Traffic:
             await asyncio.sleep(min(POLL_S if key else seconds,
                                     w.t_end - now))
         if key:
-            w.readings.append((time.monotonic(), self.c.counters()[key]))
+            w.readings.append((time.monotonic(),
+                               self.c.counters().get(key, 0)))
             if w.readings[-1][1] == value and w.t_end - grew_at > DONE_S:
                 w.t_done = grew_at
         self.stop = True
@@ -200,4 +263,5 @@ class Traffic:
     async def verify(self, acting_before: dict | None) -> dict:
         return await verify.verify_sample(
             self.c, self.sample(), in_flight=self.p["in_flight"],
-            acting_before=acting_before)
+            acting_before=acting_before, lost_osd=self.lost_osd,
+            loss=self.loss)

@@ -8,6 +8,10 @@ of an object, the concatenation over the object's stripes of chunk
 ``gf_gen_cauchy1_matrix`` coding rows (``C[i][j] = 1 / ((k+i) ^ j)``
 over GF(2^8), polynomial 0x11d) applied to the k data shards.  A
 replicated pool stores the object's bytes on every replica.
+
+This is the reference of every configuration whose file names no other;
+one that does (``"reference": "<name>"``) brings
+``benchmarks/references/<name>.py`` with the same ``expected_copies``.
 """
 
 from __future__ import annotations
@@ -68,3 +72,10 @@ def ec_shards(blob: bytes, k: int, m: int, stripe_unit: int) -> list[bytes]:
             acc ^= gf_scale(int(row[j]), data[j])
         shards.append(acc.tobytes())
     return shards
+
+
+def expected_copies(pool: dict, blob: bytes) -> list[bytes]:
+    """What position 0..n-1 of the acting set must hold."""
+    if pool["type"] == "erasure":
+        return ec_shards(blob, pool["k"], pool["m"], pool["stripe_unit"])
+    return [blob] * pool["size"]

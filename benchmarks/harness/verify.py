@@ -6,28 +6,50 @@ replicas as the configuration's guarantees state."""
 from __future__ import annotations
 
 import asyncio
+import functools
+import importlib.util
+import logging
+import os
 
 from . import reference
 
-
-def expected_copies(pool: dict, blob: bytes) -> list[bytes]:
-    """What position 0..n-1 of the acting set must hold."""
-    if pool["type"] == "erasure":
-        return reference.ec_shards(blob, pool["k"], pool["m"],
-                                   pool["stripe_unit"])
-    return [blob] * pool["size"]
+log = logging.getLogger("bench")
+REFERENCES_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "references")
+COUNTS = ("equal", "differ", "absent", "absent_live", "rebuilt")
 
 
-def stored_copies(c, name: str, blob: bytes, *, moved_from=None) -> dict:
+@functools.lru_cache(maxsize=None)
+def load_reference(name: str | None):
+    """The module whose ``expected_copies(pool, blob) -> list[bytes]``
+    says what position 0..n-1 of the acting set must hold: a
+    configuration's own (its file's ``"reference": "<name>"`` is
+    ``benchmarks/references/<name>.py``, loaded by path), or
+    ``harness/reference.py``.  A reference imports nothing of the
+    program."""
+    if name is None:
+        return reference
+    spec = importlib.util.spec_from_file_location(
+        "reference_" + name.replace(".", "_"),
+        os.path.join(REFERENCES_DIR, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def stored_copies(c, name: str, blob: bytes, *, moved_from=None,
+                  lost_osd=None) -> dict:
     """Compare every copy of ``name`` present on its acting OSDs with
     the reference.  Returns counts: ``equal``, ``differ``, ``absent``
-    (no such OSD, OSD stopped, or object not there yet) and ``rebuilt``
-    (equal copies on another OSD than ``moved_from`` had there)."""
+    (no such OSD, OSD stopped, or object not there yet), ``absent_live``
+    (those of them at a position that ``lost_osd`` did not hold in
+    ``moved_from``) and ``rebuilt`` (equal copies on another OSD than
+    ``moved_from`` had there)."""
     from ceph_tpu.store import coll_t, ghobject_t
 
-    want = expected_copies(c.pool, blob)
+    want = load_reference(c.reference).expected_copies(c.pool, blob)
     pg, acting = c.acting_of(name)
-    n = {"equal": 0, "differ": 0, "absent": 0, "rebuilt": 0}
+    n = dict.fromkeys(COUNTS, 0)
     if len(acting) != len(want):
         n["differ"] += 1
         return n
@@ -37,6 +59,8 @@ def stored_copies(c, name: str, blob: bytes, *, moved_from=None) -> dict:
         if not (0 <= osd < c.n_osds) or c.osds[osd] is None \
                 or not c.osds[osd].store.exists(coll, obj):
             n["absent"] += 1
+            if moved_from is None or moved_from[pos] != lost_osd:
+                n["absent_live"] += 1
         elif bytes(c.osds[osd].store.read(coll, obj)) == want[pos]:
             n["equal"] += 1
             if moved_from is not None and moved_from[pos] != osd:
@@ -67,33 +91,64 @@ def moved_bytes_present(c, acting_before: dict) -> int:
 
 
 async def verify_sample(c, sample: dict[str, bytes], *, in_flight: int,
-                        acting_before: dict | None = None) -> dict:
-    """``sample`` maps object names to the bytes acknowledged for them.
-    With ``acting_before`` (a loss happened) copies may still be absent
-    and at least one rebuilt copy must be found; without it every copy
-    the guarantees state must be there."""
+                        acting_before: dict | None = None,
+                        lost_osd: int | None = None,
+                        loss: str | None = None) -> dict:
+    """``sample`` maps object names to the bytes acknowledged for them;
+    ``loss`` says what became of ``lost_osd`` (``"recovers"``: marked
+    out, ``"stays_degraded"``: down and in).  Returns what was counted;
+    ``limits`` says what each count may be."""
     names = list(sample)
     sem = asyncio.Semaphore(in_flight)
 
     async def read(name):
         async with sem:
-            return await c.io.read(name)
+            try:
+                return await c.io.read(name)
+            except Exception as exc:    # no answer is not the answer
+                log.warning("read back of %s failed: %r", name, exc)
+                return None
 
     got = await asyncio.gather(*(read(n) for n in names))
     read_equal = sum(a == sample[n] for a, n in zip(got, names))
-    total = {"equal": 0, "differ": 0, "absent": 0, "rebuilt": 0}
+    total = dict.fromkeys(COUNTS, 0)
     for name in names:
         counts = await asyncio.to_thread(
             stored_copies, c, name, sample[name],
-            moved_from=(acting_before or {}).get(name))
+            moved_from=(acting_before or {}).get(name), lost_osd=lost_osd)
         for k, v in counts.items():
             total[k] += v
-    ok = read_equal == len(names) and total["differ"] == 0 and (
-        total["rebuilt"] > 0 if acting_before is not None
-        else total["absent"] == 0)
-    out = {"ok": bool(ok and names), "objects": len(names),
-           "read_back_equal": read_equal, "stored": total}
+    out = {"objects": len(names), "read_back_equal": read_equal,
+           "stored": total, "loss": loss}
     if acting_before is not None:
         out["moved_bytes_present"] = await asyncio.to_thread(
             moved_bytes_present, c, acting_before)
     return out
+
+
+def limits(verdict: dict) -> dict:
+    """Each number ``verify_sample`` counted beside its limit (``max``
+    or ``min``).  No loss: every copy the guarantees state is there.
+    A loss that recovers (the OSD marked out): copies may still be
+    absent, at least one rebuilt copy is found.  A loss that stays
+    degraded (down and in): copies are absent only where the stopped
+    OSD held them, and none is rebuilt."""
+    stored, loss = verdict["stored"], verdict["loss"]
+    out = {"objects_compared": {"value": verdict["objects"], "min": 1},
+           "read_back_differ": {"value": verdict["objects"]
+                                - verdict["read_back_equal"], "max": 0},
+           "stored_differ": {"value": stored["differ"], "max": 0}}
+    if loss is None:
+        out["stored_absent"] = {"value": stored["absent"], "max": 0}
+    elif loss == "recovers":
+        out["stored_rebuilt"] = {"value": stored["rebuilt"], "min": 1}
+    else:
+        out["stored_absent_live"] = {"value": stored["absent_live"], "max": 0}
+        out["stored_rebuilt"] = {"value": stored["rebuilt"], "max": 0}
+    return out
+
+
+def within(compared: dict) -> bool:
+    """Every number inside its limit."""
+    return all(x["value"] <= x["max"] if "max" in x else
+               x["value"] >= x["min"] for x in compared.values())

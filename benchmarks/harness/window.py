@@ -94,8 +94,11 @@ def median(values) -> float | None:
 
 
 def last_line(*, correct: bool, attempted: int, failed: int,
-              metrics: dict, device: dict, breakdown: dict | None) -> str:
-    """The contract's last stdout line: these keys and no other."""
+              metrics: dict, device: dict, breakdown: dict | None,
+              compared: dict) -> str:
+    """The contract's last stdout line: these keys and no other; last of
+    them ``compared``, each number that decided ``correct`` beside its
+    limit (``{"value": v, "max": limit}`` or ``"min"``)."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed),
            "metrics": {name: {"value": float(v), "unit": unit}
@@ -103,4 +106,5 @@ def last_line(*, correct: bool, attempted: int, failed: int,
            "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["compared"] = compared
     return json.dumps(out)

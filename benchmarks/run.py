@@ -30,7 +30,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path[:0] = [HERE, ROOT]
 
-from harness import reduce, window  # noqa: E402
+from harness import reduce, verify, window  # noqa: E402
 from harness.cluster import Cluster  # noqa: E402
 from harness.generator import SET_UP_TIMEOUT, Traffic  # noqa: E402
 
@@ -226,11 +226,15 @@ async def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
                    if trace else [])
         tracing = Tracing(os.path.join(data_dir, "trace"),
                           **p["trace"]) if trace else None
-        await t.start_loop_and_warm_up()
         acting_before = None
         if p.get("fault"):
             acting_before = {name: c.acting_of(name)[1] for name in t.acked}
-        epoch = await t.apply_fault()
+        if t.reads:     # no read is in flight to an OSD that stops
+            epoch = await t.apply_fault()
+            await t.start_loop_and_warm_up()
+        else:
+            await t.start_loop_and_warm_up()
+            epoch = await t.apply_fault()
         before = {**c.counters(), "jax.lowerings": LOWERINGS["n"]}
         emit("setup", compile_s=compile_s, ops_ended=t.ended,
              fault=p.get("fault"), out_epoch=epoch)
@@ -252,9 +256,11 @@ async def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
         "jax.lowerings", "encode.cold_launches", "decode.cold_launches",
         "encode.fallbacks", "decode.fallbacks", "plugin.fallbacks",
         "guard.host_transfers")}
+    if verdict["loss"] == "stays_degraded":    # down and in: nothing recovers
+        must_be_0["osd.recovery_ops"] = delta.get("osd.recovery_ops", 0)
     acked = w.acked
     if p.get("counter"):
-        attempted = int(delta[p["attempted_counter"]])
+        attempted = int(delta.get(p["attempted_counter"], 0))
         failed = int(sum(v for k, v in must_be_0.items() if "fallbacks" in k))
     else:
         attempted, failed = len(w.ops), len(w.ops) - len(acked)
@@ -262,23 +268,33 @@ async def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
                    + delta.get("encode.dp_dispatches", 0)
                    + delta.get("encode.tp_dispatches", 0)
                    + delta.get("decode.launches", 0))
-    correct = (verdict["ok"] and not any(must_be_0.values())
-               and attempted > 0 and (device_work > 0 or not c.erasure))
+    compared = {
+        **verify.limits(verdict),
+        "reads_wrong": {"value": t.wrong, "max": 0},
+        "attempted": {"value": attempted, "min": 1},
+        **{f"must_be_0.{k}": {"value": v, "max": 0}
+           for k, v in must_be_0.items()}}
+    if c.erasure:
+        compared["device_launches"] = {"value": device_work, "min": 1}
     if p.get("counter"):    # the program's bytes, held to the stores'
-        correct = correct and delta[p["counter"]] <= 1.05 * verdict[
-            "moved_bytes_present"]
+        compared["counter_bytes"] = {
+            "value": delta.get(p["counter"], 0),
+            "max": 1.05 * verdict["moved_bytes_present"]}
     emit("window", seconds=seconds, ops_ended=len(w.ops),
          ops_acked=len(acked), slices=w.n_slices, t_done_s=None
          if w.t_done is None else w.t_done - w.t0, must_be_0=must_be_0,
-         device_launches=device_work, verify=verdict, counters=delta)
+         device_launches=device_work, verify=verdict,
+         reads_compared=len(w.ops) if t.reads else 0,
+         reads_wrong=t.wrong, counters=delta)
 
     values: dict[str, float | None] = {"setup_s": setup_s}
     if acked:
         values["throughput_MiB_s"] = w.throughput_MiB_s()
     if p.get("counter"):
         values[p["counter_metric"]] = w.counter_rate_MiB_s()
-    out = {"correct": correct, "attempted": attempted, "failed": failed,
-           "device": dict(device), "breakdown": None}
+    out = {"correct": verify.within(compared), "attempted": attempted,
+           "failed": failed, "device": dict(device), "breakdown": None,
+           "compared": compared}
     if not trace:
         missing = [m["name"] for m in spec["end_to_end"]
                    if values.get(m["name"]) is None]
@@ -380,6 +396,9 @@ def main(argv=None) -> int:
     finally:
         shutil.rmtree(data_dir, ignore_errors=True)
     out["device"]["memory_peak_bytes"] = memory_peak_bytes()
+    for name, x in out["compared"].items():     # stderr's last lines
+        print(f"compared {name} {json.dumps(x)}", file=sys.stderr)
+    sys.stderr.flush()
     print(window.last_line(**out), flush=True)
     return 0
 

@@ -27,7 +27,8 @@ from harness import reduce, reference, window  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LAST_LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                  "compared"}
 
 
 def _bench() -> dict:
@@ -43,8 +44,12 @@ def _tiny(workload: str) -> dict:
     pool["pg_num"] = 8
     if pool["type"] == "erasure":
         pool["k"], pool["m"] = 2, 1
+        spec["traffic"]["warm_matrices"] = [    # at most m erasures
+            "decode1" if name.startswith("decode") else name
+            for name in spec["traffic"]["warm_matrices"]]
     spec["traffic"].update(
-        object_bytes=64 << 10, distinct_payloads=4, in_flight=4,
+        object_bytes=min(64 << 10, spec["traffic"]["object_bytes"]),
+        distinct_payloads=4, in_flight=4,
         warmup_ops=4, verify_sample=16, slice_seconds=0.5,
         trace={"start_s": 0.5, "seconds": 1.0})
     if spec["traffic"]["prefill_objects"]:
@@ -64,7 +69,7 @@ def _run(spec: dict, tmp_path, *, trace: bool, seconds: float = 2.0) -> dict:
         encode_service=es.EncodeService(device=devs[0])), 180))
 
 
-@pytest.mark.parametrize("workload", ["ec83_write", "rep3_write"])
+@pytest.mark.parametrize("workload", ["ec83_write", "rep3_write_4k"])
 def test_write_cells_tiny(workload, tmp_path):
     spec = _tiny(workload)
     out = _run(spec, tmp_path, trace=False)
@@ -72,8 +77,10 @@ def test_write_cells_tiny(workload, tmp_path):
     assert set(out["metrics"]) == {"throughput_MiB_s", "setup_s"}
     assert all(v > 0 for v, _unit in out["metrics"].values())
     line = json.loads(window.last_line(**out))
-    assert set(line) == LAST_LINE_KEYS
+    assert set(line) == LAST_LINE_KEYS and list(line)[-1] == "compared"
     assert line["metrics"]["setup_s"]["unit"] == "s"
+    assert all(set(x) in ({"value", "max"}, {"value", "min"})
+               for x in line["compared"].values())
 
 
 def test_traced_write_reports_per_layer_metrics_and_breakdown(tmp_path):
@@ -178,8 +185,31 @@ def test_reference_encode_equals_the_programs_host_encode():
     assert [want[i].tobytes() for i in range(11)] == got
 
 
+@pytest.mark.parametrize("cell", [w["name"] for w in _bench()["workloads"]])
+def test_cell_resolves_to_configuration_traffic_reference_and_readers(cell):
+    from harness import generator, verify
+
+    spec = bench_run.load_cell(cell)
+    loop = spec["traffic"]["loop"]
+    assert loop is None or loop["op"] in ("write_full", "read")
+    assert set(spec["traffic"].get("fault") or {}) <= {"stop_osd", "out"}
+    assert generator.Traffic.name(
+        type("T", (), {"p": spec["traffic"]}), 3).endswith("3")
+    ref = verify.load_reference(spec["config"].get("reference"))
+    assert callable(ref.expected_copies)
+    with open(ref.__file__) as f:
+        assert "ceph_tpu" not in f.read()       # nothing of the program
+    assert spec["per_layer"] and len(spec["end_to_end"]) >= 2
+    for m in spec["per_layer"]:
+        assert os.path.exists(os.path.join(
+            spec["metrics_dir"], m["name"] + ".py")), m["name"]
+
+
 def test_every_cell_resolves_to_files_and_every_name_is_allowed():
     bench = _bench()
+    assert "rep3_write" not in json.dumps(bench).replace("rep3_write_4k", "")
+    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == [
+        "ec83_write_4chip"]
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     e2e = {m["name"] for m in bench["end_to_end"]}

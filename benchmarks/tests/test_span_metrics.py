@@ -133,7 +133,7 @@ def test_new_reader_reads_its_cells_and_nothing_where_spans_are_absent(
     assert mod.compute([], {}, None, empty) is None
 
 
-@pytest.mark.parametrize("cell", ["ec83_write", "rep3_write"])
+@pytest.mark.parametrize("cell", ["ec83_write", "rep3_write_4k"])
 def test_store_phases_and_critical_path_close_on_the_rehearsal(
         cell, rehearsal):
     got = {k: v for k, (v, _unit) in rehearsal[cell]["metrics"].items()}
