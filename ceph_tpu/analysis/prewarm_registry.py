@@ -54,10 +54,6 @@ PREWARMED: dict[str, str] = {
         "compiled once per (map, rule) at mapper construction — remap "
         "builds mappers at map-install/peering, never per-op; the "
         "executable is reused across epochs (osd/remap.py)",
-    "ceph_tpu.ec.plugins.clay_jit:ClayRepairProgram.__init__":
-        "CLAY repair programs are staged per (profile, lost-node) at "
-        "recovery planning time via stage(), outside the shard-read "
-        "critical path; executables persist in the XLA disk cache",
     "ceph_tpu.parallel.encode_farm:_program.encode_mesh_cols":
         "the one mesh program (built and jitted once per mesh, one "
         "executable per width bucket): encode_service.prewarm() drives "

@@ -43,6 +43,17 @@ and the erasure-dot count is invariant under that swap), and duplicate
 pair solves write identical bytes.  Repair with aloof nodes (d <
 k+m-1) keeps the sequential path — its pair fills read another
 plane's U mid-level.
+
+Served device path: every step above is a GF(2^8)-linear map applied to
+each byte column alike, so encode and the single-chunk repair of each
+node are ONE matrix over sub-chunk rows: :meth:`ErasureCodeClay.
+encode_matrix` (m*alpha, k*alpha) on the data chunks' sub-chunks and
+:meth:`ErasureCodeClay.repair_matrix` (alpha, d*alpha/q) on the helpers'
+repair sub-chunks (alpha = sub_chunk_no).  Each is read off this host
+path once, by encoding or repairing an identity, and then travels like
+any scalar code's matrix: osd/ecutil.py files it with the encode service
+and the decode aggregator (parallel/), which coalesce, pad, launch and
+prewarm it.  The host path stays for several losses and for d < k+m-1.
 """
 
 from __future__ import annotations
@@ -59,6 +70,19 @@ __erasure_code_version__ = "0.1.0"
 
 def _pow_int(a: int, x: int) -> int:
     return a**x
+
+
+#: (code signature, what) -> derived matrix.  Process-wide: co-hosted
+#: daemons each hold their own plugin instance of one profile.
+_LINEAR_MAPS: dict[tuple, object] = {}
+
+
+def _identity_rows(n_rows: int, first: int, width: int) -> np.ndarray:
+    """(n_rows * width,) payload whose sub-chunk ``r`` is the unit
+    vector ``first + r`` of a ``width``-byte sub-chunk."""
+    out = np.zeros((n_rows, width), dtype=np.uint8)
+    out[np.arange(n_rows), first + np.arange(n_rows)] = 1
+    return out.reshape(-1)
 
 
 class _PftBatch:
@@ -99,6 +123,8 @@ class _PftBatch:
 class ErasureCodeClay(ErasureCode):
     DEFAULT_K = "4"
     DEFAULT_M = "2"
+    #: what a launch of a repair matrix calls itself (its span's kind)
+    REPAIR_KIND = "clay_repair"
 
     def __init__(self) -> None:
         super().__init__()
@@ -268,6 +294,59 @@ class ErasureCodeClay(ErasureCode):
             minimum.setdefault(chunk, list(runs))
         assert len(minimum) == self.d, (len(minimum), self.d)
         return minimum
+
+    # -- the code as matrices over sub-chunk rows (the device path) ----------
+
+    def _linear_map(self, what, derive):
+        key = (self.k, self.m, self.d, self._profile.get("scalar_mds"),
+               self._profile.get("technique"), what)
+        hit = _LINEAR_MAPS.get(key)
+        if hit is None:
+            hit = _LINEAR_MAPS[key] = derive()
+            hit.flags.writeable = False     # one array for every caller
+        return hit
+
+    def encode_matrix(self) -> np.ndarray:
+        """(m * alpha, k * alpha) over GF(2^8): row ``j * alpha + z`` is
+        sub-chunk z of parity chunk k + j, column ``i * alpha + z'``
+        sub-chunk z' of data chunk i.  The host encode of an identity."""
+        def derive() -> np.ndarray:
+            a, n = self.sub_chunk_no, self.k * self.sub_chunk_no
+            enc = {i: _identity_rows(a, i * a, n) for i in range(self.k)}
+            for j in range(self.k, self.k + self.m):
+                enc[j] = np.zeros(a * n, dtype=np.uint8)
+            self.encode_chunks(set(range(self.k + self.m)), enc)
+            return np.ascontiguousarray(np.concatenate([
+                enc[j].reshape(a, n)
+                for j in range(self.k, self.k + self.m)]))
+        return self._linear_map("encode", derive)
+
+    def repair_helpers(self, lost: int) -> dict[int, list[tuple[int, int]]]:
+        """minimum_to_decode for ``lost`` with every other chunk there."""
+        return self.minimum_to_decode(
+            {lost}, set(range(self.k + self.m)) - {lost})
+
+    def repair_matrix(self, lost: int) -> np.ndarray | None:
+        """(alpha, d * alpha / q) over GF(2^8): chunk ``lost``'s
+        sub-chunks from the repair sub-chunks of its d helpers, helpers
+        in chunk order and each helper's sub-chunks in the order of
+        :meth:`repair_helpers`' runs (the packed ranged read).  The host
+        repair of an identity.  None where d < k+m-1: with aloof nodes
+        the helper set, and so the matrix, depends on who else is
+        missing."""
+        if self.d != self.k + self.m - 1:
+            return None
+
+        def derive() -> np.ndarray:
+            minimum = self.repair_helpers(lost)
+            beta = self.sub_chunk_no // self.q
+            n = len(minimum) * beta
+            chunks = {h: _identity_rows(beta, hi * beta, n)
+                      for hi, h in enumerate(sorted(minimum))}
+            out = self._repair({lost}, chunks, self.sub_chunk_no * n)
+            return np.ascontiguousarray(
+                out[lost].reshape(self.sub_chunk_no, n))
+        return self._linear_map(("repair", lost), derive)
 
     # -- encode / decode entry points ----------------------------------------
 

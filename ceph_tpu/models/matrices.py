@@ -75,17 +75,22 @@ def isa_cauchy_matrix(k: int, m: int) -> np.ndarray:
 
 def _big_vandermonde_distribution_matrix(rows: int, cols: int) -> np.ndarray:
     """Plank's corrected Vandermonde construction (jerasure
-    ``reed_sol_big_vandermonde_distribution_matrix``): start from
-    V[i,j] = i^j, reduce the top cols x cols to identity with elementary
-    column operations, then normalize so the first coding row and the
-    first coding column are all ones."""
+    ``reed_sol_big_vandermonde_distribution_matrix``): start from the
+    EXTENDED Vandermonde matrix (``reed_sol_extended_vandermonde_
+    matrix``: V[i,j] = i^j, but the last row is 0, ..., 0, 1), reduce
+    the top cols x cols to identity with elementary column operations,
+    then normalize so the first coding row and the first coding column
+    are all ones.  (Until PR 34 the last row was (rows-1)^j as well, so
+    the last coding row of every m >= 2 code differed from jerasure's:
+    ``reed_sol_01 7 7 8`` of its manual ends 1 187 104 210 211 105 186.)"""
     if cols >= rows:
         raise ValueError("need rows > cols")
     V = np.zeros((rows, cols), dtype=np.uint8)
-    for i in range(rows):
+    for i in range(rows - 1):
         V[i, 0] = 1
         for j in range(1, cols):
             V[i, j] = gf_mul(V[i, j - 1], np.uint8(i))
+    V[rows - 1, cols - 1] = 1
     # top cols x cols -> identity by column ops
     for i in range(cols):
         if V[i, i] == 0:

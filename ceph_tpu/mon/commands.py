@@ -12,6 +12,7 @@ import logging
 
 from ceph_tpu.ec import registry as ec_registry
 from ceph_tpu.msg.messages import MOSDScrub, MOSDScrubReply
+from ceph_tpu.osd import ecutil
 
 log = logging.getLogger("ceph_tpu.mon")
 
@@ -67,7 +68,8 @@ class CommandMixin:
                 )
                 profile.setdefault("plugin", "jax")
                 # instantiate once to validate + fill defaults
-                ec_registry.factory(profile["plugin"], profile)
+                ec = ec_registry.factory(profile["plugin"], profile)
+                ecutil.check_stripe_unit(ec)
                 await self._propose({
                     "op": "profile", "name": name, "profile": profile,
                 })

@@ -140,15 +140,23 @@ def gf_const_to_bitmatrix(c: int) -> np.ndarray:
     return np.array(cols, dtype=np.uint8).T
 
 
+@functools.lru_cache(maxsize=1)
+def _const_bitmatrices() -> np.ndarray:
+    """(256, 8, 8): :func:`gf_const_to_bitmatrix` of every constant."""
+    table = np.stack([gf_const_to_bitmatrix(c) for c in range(256)])
+    table.flags.writeable = False   # one table for every caller
+    return table
+
+
 def gf_matrix_to_bitmatrix(M: np.ndarray) -> np.ndarray:
-    """(m,k) GF(2^8) matrix -> (8m, 8k) 0/1 matrix over GF(2)."""
+    """(m,k) GF(2^8) matrix -> (8m, 8k) 0/1 matrix over GF(2): entry
+    (i, j) becomes its constant's 8x8 block.  One gather, so that a
+    vector code's hundreds of rows (CLAY(8,4,11) encode: 256 x 512)
+    cost milliseconds, not seconds of Python."""
     M = np.asarray(M, dtype=np.uint8)
     m, k = M.shape
-    out = np.zeros((8 * m, 8 * k), dtype=np.uint8)
-    for i in range(m):
-        for j in range(k):
-            out[8 * i:8 * i + 8, 8 * j:8 * j + 8] = gf_const_to_bitmatrix(int(M[i, j]))
-    return out
+    return np.ascontiguousarray(
+        _const_bitmatrices()[M].transpose(0, 2, 1, 3)).reshape(8 * m, 8 * k)
 
 
 def bytes_to_bits(a: np.ndarray) -> np.ndarray:

@@ -355,6 +355,20 @@ def gf_bitmatmul_pallas_acc(
 # Encoder/decoder objects (host-side matrix prep, cached)
 # ---------------------------------------------------------------------------
 
+#: largest bit-matrix the fused kernels are chosen for: between the
+#: largest a scalar code gives (a w=8 packet code's (192, 512), 98,304
+#: bits) and the smallest a vector code gives (CLAY(8,4,11)'s repair,
+#: (512, 1408); its encode is (2048, 4096)).  The fused kernels take
+#: those too, byte-exact and no faster (``tools/big_bitmatrix_probe.py``
+#: on the v5e, one 4 MiB object: encode 1.09 ms against XLA's 1.24,
+#: repair 0.71 against 0.64, the launch round trip either way), but
+#: Mosaic's first compile grows with the stationary operand: 112 s for
+#: the encode matrix at the tile ``_pick_tile`` gives (20 s at 2048
+#: lanes, 5.5 s at 512), once a width of the bucket ladder, where XLA's
+#: program takes 3 s: an OSD would install its map for minutes
+_PALLAS_MAX_BITS = 1 << 17
+
+
 class BitmatrixCodec:
     """Precomputed bit-matrices for one (k, m, generator) code.
 
@@ -427,7 +441,11 @@ class BitmatrixCodec:
     @staticmethod
     def _apply(bits_matrix: jax.Array, data: jax.Array, pallas: bool | None) -> jax.Array:
         if pallas is None:
-            pallas = data.ndim == 2 and jax.default_backend() == "tpu"
+            # a vector code's hundreds of sub-chunk rows (CLAY(8,4,11)
+            # encode: (2048, 4096) bits) take XLA's kernel: as fast, and
+            # compiled in seconds (_PALLAS_MAX_BITS)
+            pallas = (data.ndim == 2 and jax.default_backend() == "tpu"
+                      and bits_matrix.size <= _PALLAS_MAX_BITS)
         if pallas and data.ndim == 2:
             tile = _pick_tile(data.shape[-1])
             if tile is not None:

@@ -870,9 +870,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         return self._ec_cache[prof_name]
 
     def _sinfo(self, ec) -> ecutil.StripeInfo:
-        k = ec.get_data_chunk_count()
-        chunk = ec.get_chunk_size(STRIPE_UNIT * k)
-        return ecutil.StripeInfo(k, chunk * k)
+        return ecutil.stripe_info(ec)
 
     def _acting(self, pool: PgPool, pg: pg_t) -> tuple[list[int], int]:
         _, _, acting, primary = self.osdmap.pg_to_up_acting_osds(pg)
@@ -977,13 +975,18 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     ec = ec_registry.factory(
                         prof.get("plugin", "jax"), dict(prof))
                     sinfo = self._sinfo(ec)
-                    cs = sinfo.chunk_size
+                    # a request's columns: a chunk's bytes, or for a
+                    # vector code one of its sub-chunk rows'
+                    cs = sinfo.chunk_size // ec.get_sub_chunk_count()
                     widths = [max(cs >> 2, 1), cs, cs << 2]
                     agg.prewarm(ec, widths)
                     ver.prewarm(ec, widths)
-                    if (svc is not None and farm_warm
-                            and hasattr(ec, "coding_matrix")):
-                        svc.prewarm(ec.coding_matrix, widths)
+                    encode_m = (
+                        ec.encode_matrix() if hasattr(ec, "encode_matrix")
+                        else getattr(ec, "coding_matrix", None))
+                    if svc is not None and farm_warm \
+                            and encode_m is not None:
+                        svc.prewarm(encode_m, widths)
                 except Exception:
                     self.perf.inc("ec_warmup_failures")
                     log.exception(

@@ -28,6 +28,7 @@ import numpy as np
 from ceph_tpu.ec.interface import ECError, ErasureCodeInterface
 from ceph_tpu.ec.plugins.matrix_base import MatrixErasureCode
 from ceph_tpu.native import crc32c
+from ceph_tpu.osd.pgutil import STRIPE_UNIT
 
 
 class StripeInfo:
@@ -73,6 +74,36 @@ class StripeInfo:
     def offset_len_to_stripe_bounds(self, off: int, length: int) -> tuple[int, int]:
         start = self.logical_to_prev_stripe_offset(off)
         return start, self.logical_to_next_stripe_offset((off - start) + length)
+
+
+def stripe_unit_of(profile: Mapping[str, str]) -> int:
+    """The pool's stripe unit: its erasure-code profile's ``stripe_unit``
+    (upstream's key), else osd_pool_erasure_code_stripe_unit's 4096."""
+    return int(profile.get("stripe_unit", STRIPE_UNIT))
+
+
+def stripe_info(ec_impl: ErasureCodeInterface) -> StripeInfo:
+    """A pool's stripe geometry from its code and its profile
+    (OSDMonitor::prepare_pool_stripe_width)."""
+    k = ec_impl.get_data_chunk_count()
+    unit = stripe_unit_of(ec_impl.get_profile())
+    return StripeInfo(k, ec_impl.get_chunk_size(unit * k) * k)
+
+
+def check_stripe_unit(ec_impl: ErasureCodeInterface) -> None:
+    """A profile's ``stripe_unit`` must be a chunk size the plugin
+    would give unpadded (the check of ``osd erasure-code-profile
+    set``): a unit the plugin pads is a pool whose stripes are not the
+    width the profile states."""
+    if "stripe_unit" not in ec_impl.get_profile():
+        return
+    unit = stripe_unit_of(ec_impl.get_profile())
+    chunk = ec_impl.get_chunk_size(unit * ec_impl.get_data_chunk_count()) \
+        if unit > 0 else -1
+    if chunk != unit:
+        raise ECError(errno.EINVAL, (
+            f"stripe_unit {unit} does not match ec profile alignment. "
+            f"Would be padded to {chunk}"))
 
 
 def row_view(row: np.ndarray) -> memoryview:
@@ -147,14 +178,85 @@ def encode(
 # back to the sync single-device path, so behavior is identical.
 
 
+def _service_takes(service, nbytes: int) -> bool:
+    return (service is not None and service.active()
+            and nbytes >= service.min_bytes)
+
+
 def _farm_ready(service, ec_impl, nbytes: int) -> bool:
     return (
-        service is not None
-        and service.active()
-        and nbytes >= service.min_bytes
+        _service_takes(service, nbytes)
         and isinstance(ec_impl, MatrixErasureCode)
         and ec_impl.rows_per_chunk == 1
     )
+
+
+# A vector code (sub-chunks) whose plugin gives its encode and its
+# single-chunk repair as matrices over sub-chunk rows (CLAY:
+# ``encode_matrix`` / ``repair_matrix``) takes the same engines with a
+# reshaped operand: a chunk payload of ns stripes is (ns, alpha, sc)
+# bytes, and sub-chunk z of every stripe lies side by side in row z.
+
+def _is_linear_vector_code(ec_impl) -> bool:
+    return (ec_impl.get_sub_chunk_count() > 1
+            and hasattr(ec_impl, "encode_matrix"))
+
+
+def _to_subchunk_rows(payload: np.ndarray, n_sub: int, sc: int) -> np.ndarray:
+    """(ns * n_sub * sc,) stripe-major -> (n_sub, ns * sc)."""
+    return payload.reshape(-1, n_sub, sc).transpose(1, 0, 2).reshape(
+        n_sub, -1)
+
+
+def _from_subchunk_rows(rows: np.ndarray, sc: int) -> np.ndarray:
+    """(n_sub, ns * sc) -> the stripe-major (ns * n_sub * sc,) payload."""
+    n_sub = rows.shape[0]
+    return np.ascontiguousarray(
+        rows.reshape(n_sub, -1, sc).transpose(1, 0, 2)).reshape(-1)
+
+
+async def _encode_subchunks_async(sinfo, ec_impl, arr, want, service):
+    k = ec_impl.get_data_chunk_count()
+    m = ec_impl.get_chunk_count() - k
+    cs = sinfo.chunk_size
+    alpha = ec_impl.get_sub_chunk_count()
+    sc = cs // alpha
+    data = arr.reshape(-1, k, cs).transpose(1, 0, 2)        # (k, ns, cs)
+    rows = np.ascontiguousarray(
+        data.reshape(k, -1, alpha, sc).transpose(0, 2, 1, 3)
+    ).reshape(k * alpha, -1)
+    parity = await service.apply(ec_impl.encode_matrix(), rows)
+    out = {ec_impl.chunk_index(i): np.ascontiguousarray(data[i]).reshape(-1)
+           for i in range(k)}
+    for j in range(m):
+        out[ec_impl.chunk_index(k + j)] = _from_subchunk_rows(
+            parity[j * alpha:(j + 1) * alpha], sc)
+    if want is not None:
+        out = {s: c for s, c in out.items() if s in want}
+    return out
+
+
+async def _repair_subchunks_async(
+    sinfo, ec_impl, to_decode, need, aggregator
+) -> dict[int, np.ndarray] | None:
+    """The regenerating repair of one chunk from its helpers' packed
+    ranged reads, as one matrix filed with the aggregator; None = the
+    caller takes the host path."""
+    if aggregator is None or len(need) != 1 \
+            or not _is_linear_vector_code(ec_impl):
+        return None
+    lost = next(iter(need))
+    R = ec_impl.repair_matrix(lost)
+    if R is None or set(to_decode) != set(ec_impl.repair_helpers(lost)):
+        return None
+    sc = sinfo.chunk_size // ec_impl.get_sub_chunk_count()
+    beta = R.shape[1] // len(to_decode)
+    rows = np.concatenate([
+        _to_subchunk_rows(np.asarray(to_decode[h]).reshape(-1), beta, sc)
+        for h in sorted(to_decode)])
+    out = await aggregator.apply(
+        R, rows, kind=ec_impl.REPAIR_KIND, lost_node=lost)
+    return {lost: _from_subchunk_rows(out, sc)}
 
 
 async def encode_async(
@@ -171,13 +273,18 @@ async def encode_async(
         if isinstance(data, np.ndarray)
         else np.frombuffer(data, dtype=np.uint8)    # bytes or a view
     )
-    if not _farm_ready(service, ec_impl, arr.nbytes):
+    vector = (_service_takes(service, arr.nbytes)
+              and _is_linear_vector_code(ec_impl))
+    if not vector and not _farm_ready(service, ec_impl, arr.nbytes):
         return encode(sinfo, ec_impl, arr, want)
     sw, cs = sinfo.stripe_width, sinfo.chunk_size
     if arr.nbytes % sw:
         raise ECError(errno.EINVAL, f"logical size {arr.nbytes} not stripe aligned")
     if arr.nbytes == 0:
         return {}
+    if vector:
+        return await _encode_subchunks_async(
+            sinfo, ec_impl, arr, want, service)
     k, m = ec_impl.get_data_chunk_count(), ec_impl.get_chunk_count() - ec_impl.get_data_chunk_count()
     ns = arr.nbytes // sw
     data_shards = np.ascontiguousarray(
@@ -226,13 +333,21 @@ async def decode_shards_async(
     aggregator=None,
 ) -> dict[int, np.ndarray]:
     """:func:`decode_shards` with batched reconstruction (recovery
-    path; falls back for sub-chunk/packed codes).
+    path).  A vector code's packed single-chunk repair is one matrix
+    like a scalar code's decode; its other decodes (several losses,
+    full-chunk reads, d < k+m-1) stay on the host loop.
 
     ``aggregator`` (a parallel.decode_batcher.DecodeAggregator) takes
     precedence over the encode farm: per-object recovery decodes that
     share an erasure signature coalesce into fixed-shape batched
     launches — the repair-pipelining discipline — instead of one farm
     matmul per object."""
+    if packed_repair and all(
+            np.asarray(v).size for v in to_decode.values()):
+        rec = await _repair_subchunks_async(
+            sinfo, ec_impl, to_decode, need, aggregator)
+        if rec is not None:
+            return rec
     if packed_repair or (
         not isinstance(ec_impl, MatrixErasureCode)
         or ec_impl.get_sub_chunk_count() != 1

@@ -1319,20 +1319,27 @@ class RecoveryMixin:
             lg.rollback_divergent(t, oid, v_star)
             await self._commit(t)
         need = {s for s, _ in targets}
+        # A shard its new holder lacks may still sit whole on the OSD
+        # that held it before (marking an OSD out can move a second
+        # position of the PG): that one is read there and passed on as
+        # it is, whatever the code (ErasureCode::_minimum_to_decode
+        # reads a wanted chunk that is available); only what no source
+        # has is ``lost`` and rebuilt
+        lost = need - set(sources)
         # single-shard repair of a regenerating code: thread
         # minimum_to_decode's (sub-chunk offset, count) runs down to
         # ranged shard reads so only sub_chunk_no/q of each helper
         # crosses the wire (reference ECCommon.cc:262-299 +
         # ErasureCodeClay::repair_one_lost_chunk) — CLAY's whole point
-        repair_extents: dict[int, list[tuple[int, int]]] | None = None
+        repair_extents: dict[int, list[tuple[int, int]]] = {}
         if (
-            len(need) == 1 and ec.get_sub_chunk_count() > 1
+            len(lost) == 1 and ec.get_sub_chunk_count() > 1
             and not rb_srcs
             and not getattr(self, "disable_subchunk_repair", False)
         ):
             try:
-                if ec.is_repair(need, set(sources)):
-                    minimum = ec.minimum_to_decode(need, set(sources))
+                if ec.is_repair(lost, set(sources)):
+                    minimum = ec.minimum_to_decode(lost, set(sources))
                     cs = sinfo.chunk_size
                     sub = cs // ec.get_sub_chunk_count()
                     size = int(src_attrs.get(SIZE_ATTR, b"0"))
@@ -1348,28 +1355,35 @@ class RecoveryMixin:
                         for s, runs in minimum.items()
                     }
             except Exception:
-                repair_extents = None  # fall back to full-chunk reads
+                log.exception(
+                    "osd.%d: %s/%s: no sub-chunk repair plan; reading "
+                    "whole chunks", self.id, pg, oid)
         # helper-shard reads and shard pushes both fan out concurrently
         # (the reference's ECSubRead/MOSDPGPush are fire-and-gather)
-        chunks: dict[int, np.ndarray] = {}
+        chunks: dict[int, np.ndarray] = {}    # what the decode is given
+        passed: dict[int, np.ndarray] = {}    # needed, and read whole
         used_packed = False
         read_sp = self.tracer.start_span("recovery_read", parent=obj_sp)
-        if repair_extents is not None and set(repair_extents) <= set(sources):
-            src_items = [(s, sources[s]) for s in sorted(repair_extents)]
+        if (repair_extents or not lost) and not rb_srcs:
+            # ranged reads of the helpers, whole reads of what only moved
+            reads = [(s, sources[s], ext)
+                     for s, ext in sorted(repair_extents.items())]
+            reads += [(s, sources[s], None) for s in sorted(need - lost)]
             payloads = await asyncio.gather(*(
-                self._read_shard_quiet(
-                    pool, pg, s, o, oid, extents=repair_extents[s]
-                )
-                for s, o in src_items
+                self._read_shard_quiet(pool, pg, s, o, oid, extents=ext)
+                for s, o, ext in reads
             ))
-            for (s, o), (payload, _a, _e) in zip(src_items, payloads):
+            for (s, _o, ext), (payload, _a, _e) in zip(reads, payloads):
                 if payload is not None:
-                    chunks[s] = np.frombuffer(payload, np.uint8)
-            if len(chunks) < len(repair_extents):
-                chunks = {}  # a helper vanished: retry with full reads
+                    (passed if ext is None else chunks)[s] = \
+                        np.frombuffer(payload, np.uint8)
+            if len(chunks) + len(passed) < len(reads):
+                chunks, passed = {}, {}  # a source vanished: full reads
             else:
-                used_packed = True
-        if not chunks:
+                used_packed = bool(chunks)
+                read_sp.tag(extents=sum(
+                    len(ext) if ext else 1 for _s, _o, ext in reads))
+        if not chunks and not passed:
             src_items = list(sources.items())
             payloads = await asyncio.gather(*(
                 self._read_shard_quiet(
@@ -1380,6 +1394,7 @@ class RecoveryMixin:
             for (s, o), (payload, _a, _e) in zip(src_items, payloads):
                 if payload is not None:
                     chunks[s] = np.frombuffer(payload, np.uint8)
+            read_sp.tag(extents=len(src_items))
             if len(chunks) < k:
                 self.tracer.finish_span(read_sp)
                 log.error(
@@ -1387,7 +1402,18 @@ class RecoveryMixin:
                     "succeeded", self.id, pg, oid, len(chunks), k,
                 )
                 return False
+            lost = need - set(chunks)
+            passed = {s: chunks[s] for s in need - lost}
+        helper_bytes = sum(v.nbytes for v in chunks.values())
+        read_sp.tag(subchunk=used_packed, helper_bytes=helper_bytes)
         self.tracer.finish_span(read_sp)
+        self.perf.inc("recovery_read_bytes", helper_bytes)
+        if ec.get_sub_chunk_count() > 1 and lost:
+            # a regenerating code's repair that read whole chunks (more
+            # than one shard lost, a source in its rollback sidecar,
+            # aloof nodes, a helper gone) is the fallback, and says so
+            self.perf.inc("recovery_subchunk_repairs" if used_packed
+                          else "recovery_fullchunk_repairs")
         # the timed decode stage (BASELINE.md #5; reference
         # ECBackend.cc:365-431 handle_recovery_read_complete): measured
         # IN the running daemon, not inferred from microbenches.  The
@@ -1398,12 +1424,23 @@ class RecoveryMixin:
             "recovery_decode", parent=obj_sp, stage="device",
         ) as dec_sp, tracing.scope(dec_sp):
             rebuilt = await ecutil.decode_shards_async(
-                sinfo, ec, chunks, need, packed_repair=used_packed,
+                sinfo, ec, chunks, lost, packed_repair=used_packed,
                 service=self.encode_service,
                 aggregator=self.decode_aggregator,
-            )
+            ) if lost else {}
+            rebuilt_bytes = sum(v.nbytes for v in rebuilt.values())
+            if used_packed:
+                dec_sp.tag(kind=getattr(ec, "REPAIR_KIND", "subchunk_repair"),
+                           lost_node=min(lost), objects=1,
+                           helper_bytes=helper_bytes,
+                           rebuilt_bytes=rebuilt_bytes)
+            rebuilt.update(passed)
         self.perf.inc("recovery_decode_seconds",
                       time.perf_counter() - _t0)
+        # recovery_decode_bytes is every byte handed to a target, the
+        # shards passed on as read too (as a scalar code's decode always
+        # passed them); recovery_rebuilt_bytes only what a decode made
+        self.perf.inc("recovery_rebuilt_bytes", rebuilt_bytes)
         self.perf.inc("recovery_decode_bytes",
                       sum(v.nbytes for v in rebuilt.values()))
         with self.tracer.span("recovery_push", parent=obj_sp):

@@ -132,9 +132,12 @@ device_matrices = DeviceMatrixCache()
 # -- the skeleton ------------------------------------------------------------
 
 class MatMul(NamedTuple):
-    """Encode's and decode's item: (out, k) bytes @ (k, S) over GF(2^8)."""
+    """Encode's and decode's item: (out, k) bytes @ (k, S) over GF(2^8).
+    ``tags`` say what the matrix is where the caller knows more than its
+    shape (``kind``, ``lost_node``): they go on the launch's span."""
     M: np.ndarray
     rows: np.ndarray
+    tags: dict | None = None
 
 
 class Request(NamedTuple):
@@ -149,7 +152,7 @@ def host_matmul_group(_key, group: list[Request]) -> list[np.ndarray]:
     """The host answer to a group of :class:`MatMul` requests."""
     from ceph_tpu.ops.gf256 import gf_matmul
 
-    return [gf_matmul(*req.item) for req in group]
+    return [gf_matmul(req.item.M, req.item.rows) for req in group]
 
 
 class LaunchBatcher:

@@ -45,13 +45,18 @@ class DecodeAggregator(LaunchBatcher):
         self.min_bucket = min_bucket
         self.tile_cap = tile_cap
 
-    async def apply(self, D: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    async def apply(self, D: np.ndarray, rows: np.ndarray,
+                    **tags) -> np.ndarray:
         """``D @ rows`` over GF(2^8), batched with concurrent callers
         that share D, the plugin's cached (out, k) decode matrix of one
-        erasure signature; rows is (k, S) uint8.  Returns (out, S)
-        uint8, bit-identical to ``gf_matmul(D, rows)``."""
+        erasure signature (or a vector code's repair matrix of one lost
+        node); rows is (k, S) uint8.  Returns (out, S) uint8,
+        bit-identical to ``gf_matmul(D, rows)``.  ``tags`` (``kind``,
+        ``lost_node``: the same for every caller of one D) go on the
+        launches' spans."""
         self.stats["requests"] += 1
-        return await self.submit(batcher.matrix_key(D), MatMul(D, rows))
+        return await self.submit(batcher.matrix_key(D),
+                                 MatMul(D, rows, tags or None))
 
     # -- the plan ------------------------------------------------------
 
@@ -76,6 +81,8 @@ class DecodeAggregator(LaunchBatcher):
         bits = self._bits(group[0].item.M)
         k = group[0].item.rows.shape[0]
         out_rows = bits.shape[0] // 8
+        tags = dict(group[0].item.tags or ())
+        kind = tags.pop("kind", "decode_batch")
         outs = [np.empty((out_rows, req.item.rows.shape[1]), np.uint8)
                 for req in group]
         waiting = set(range(len(group)))   # not yet served by a launch
@@ -87,16 +94,20 @@ class DecodeAggregator(LaunchBatcher):
                         group[gi].item.rows[:, off:off + width]
                 served = {gi for gi, _, _ in chunk} & waiting
                 waiting -= served
+                real = sum(width for _, _, width in chunk)
                 # one upload of the padded batch, one gather of the
                 # result (by design: rebuilt shards persist to the store)
                 with self._launching(
                     (bits.shape, b, k, w),
                     [group[gi] for gi in sorted(served)],
-                    kind="decode_batch", guard="decode_batch", w=w, b=b,
-                    b_real=len(chunk),
-                    real_bytes=k * sum(width for _, _, width in chunk),
+                    kind=kind, guard="decode_batch", w=w, b=b,
+                    b_real=len(chunk), real_bytes=k * real,
                     padded_bytes=b * k * w,
-                ):
+                ) as span:
+                    # the bytes the launch is for, pads left out
+                    span.tag(objects=len({gi for gi, _, _ in chunk}),
+                             helper_bytes=k * real,
+                             rebuilt_bytes=out_rows * real, **tags)
                     out = jax.device_get(jax.block_until_ready(
                         gf_bitmatmul(bits, jax.device_put(batch))))
                 self.stats["launches"] += 1
@@ -115,8 +126,9 @@ class DecodeAggregator(LaunchBatcher):
         aggregator can launch for ``ec_impl``'s code: the whole CLOSED
         ladder (``widths`` only hints at extra buckets) for each of
         ``erasure_counts`` (the decode matrix SHAPE depends only on the
-        count; one above the code's parity is skipped).  Blocking:
-        warmup only.  Returns the number of programs compiled."""
+        count; one above the code's parity is skipped), or for a vector
+        code the one shape of its repair matrices.  Blocking: warmup
+        only.  Returns the number of programs compiled."""
         import jax.numpy as jnp
 
         from ceph_tpu.ops.rs_kernels import gf_bitmatmul
@@ -125,10 +137,17 @@ class DecodeAggregator(LaunchBatcher):
         r = getattr(ec_impl, "rows_per_chunk", 1)
         buckets = batcher.bucket_ladder(
             self.min_bucket, self.tile_cap, widths)
+        if hasattr(ec_impl, "repair_matrix"):
+            # a vector code: the one shape of its single-chunk repair
+            # (its other decodes never come here)
+            R = ec_impl.repair_matrix(0)
+            mats = [] if R is None else [R.shape]
+        else:
+            mats = [(e * r, k * r) for e in erasure_counts
+                    if e <= ec_impl.get_chunk_count() - k]
         return self._prewarm(
-            [((8 * e * r, 8 * k * r), b, k * r, w)
-             for e in erasure_counts
-             if e <= ec_impl.get_chunk_count() - k
+            [((8 * out, 8 * rows), b, rows, w)
+             for out, rows in mats
              for w in buckets
              for b in batches or (1, self.max_batch)],
             lambda key: gf_bitmatmul(jnp.zeros(key[0], np.uint8),

@@ -15,8 +15,10 @@ from __future__ import annotations
 import asyncio
 import random
 
+import pytest
+
 from ceph_tpu.osd.daemon import OSDDaemon, object_to_pg
-from ceph_tpu.store import ghobject_t
+from ceph_tpu.store import coll_t, ghobject_t
 
 from .test_mini_cluster import Cluster, run
 
@@ -94,14 +96,29 @@ async def _run_repair(c: Cluster, disable_subchunk: bool) -> int:
     return delta, shard_len
 
 
+def _recovery_counters(c: Cluster) -> dict:
+    keys = ("recovery_subchunk_repairs", "recovery_fullchunk_repairs",
+            "recovery_read_bytes", "recovery_rebuilt_bytes",
+            "recovery_decode_bytes")
+    return {k: sum(o.perf.dump().get(k, 0) for o in c.osds
+                   if o is not None) for k in keys}
+
+
 class TestClaySubChunkRepair:
     def test_repair_reads_subchunk_fraction(self):
         async def go():
+            # counters of one process outlive a cluster: take growth
             async with Cluster(n_osds=K + M + 2) as c:
+                was = _recovery_counters(c)
                 full_delta, shard_len = await _run_repair(
                     c, disable_subchunk=True)
+                full = {k: v - was[k]
+                        for k, v in _recovery_counters(c).items()}
             async with Cluster(n_osds=K + M + 2) as c:
+                was = _recovery_counters(c)
                 sub_delta, _ = await _run_repair(c, disable_subchunk=False)
+                sub = {k: v - was[k]
+                       for k, v in _recovery_counters(c).items()}
             # regenerating read: d helpers x 1/q each = 2.5 chunks;
             # full reconstruction reads every consistent source (5).
             # The final client read adds the same k-chunk fan-out to
@@ -109,6 +126,20 @@ class TestClaySubChunkRepair:
             assert sub_delta < 0.75 * full_delta, (
                 sub_delta, full_delta, shard_len,
             )
+            # the fallback to whole chunks is never silent: each repair
+            # of a code with sub-chunks counts as one or the other
+            assert full["recovery_fullchunk_repairs"] >= 1, full
+            assert full["recovery_subchunk_repairs"] == 0, full
+            assert sub["recovery_subchunk_repairs"] >= 1, sub
+            assert sub["recovery_fullchunk_repairs"] == 0, sub
+            # d helpers x 1/q of a chunk each, local reads counted too
+            assert sub["recovery_read_bytes"] == \
+                D / (D - K + 1) * sub["recovery_rebuilt_bytes"], sub
+            assert full["recovery_read_bytes"] >= \
+                K * full["recovery_rebuilt_bytes"], full
+            # nothing moved here: every byte handed over was rebuilt
+            assert sub["recovery_decode_bytes"] == \
+                sub["recovery_rebuilt_bytes"], sub
 
         run(go())
 
@@ -130,3 +161,82 @@ class TestClaySubChunkRepair:
                     assert rep["inconsistencies"] == [], rep
 
         run(go())
+
+
+PROFILES = {
+    "scalar": {"plugin": "jax", "k": str(K), "m": str(M)},
+    "clay": {"plugin": "clay", "k": str(K), "m": str(M), "d": str(D),
+             "scalar_mds": "jax"},
+}
+
+
+@pytest.mark.parametrize("code", sorted(PROFILES))
+def test_a_shard_that_only_moved_is_passed_on_whatever_the_code(code):
+    """Marking an OSD out of k+m+1 hosts moves a second, live position
+    of some PGs.  One rule for every code: the shard its old holder
+    still has is read there and handed on; only the lost one is rebuilt
+    (CLAY: from sub-chunk reads, though the object has two targets)."""
+    async def go():
+        async with Cluster(n_osds=K + M + 1) as c:
+            await c.client.ec_profile_set("p", {
+                **PROFILES[code], "crush-failure-domain": "host"})
+            await c.client.pool_create(
+                "movepool", pg_num=8, pool_type="erasure",
+                erasure_code_profile="p")
+            io = c.client.ioctx("movepool")
+            rng = random.Random(5)
+            names = [f"m{i}" for i in range(16)]
+            payload = {n: rng.randbytes(OBJ_SIZE) for n in names}
+            for n in names:
+                await io.write_full(n, payload[n])
+            om = c.client.osdmap
+            pool = om.get_pg_pool(io.pool_id)
+            before = {n: om.pg_to_up_acting_osds(
+                object_to_pg(pool, n))[2] for n in names}
+            victim = K + M
+            epoch = om.epoch
+            await c.osds[victim].stop()
+            c.osds[victim] = None
+            # counters of one process outlive a cluster: take growth,
+            # over the OSDs that stay
+            was = _recovery_counters(c)
+            await c.client.command({"prefix": "osd down", "id": str(victim)})
+            await c.client.command({"prefix": "osd out", "id": str(victim)})
+            await c.wait_epoch(epoch + 2)
+            om = c.client.osdmap
+            lost = moved = 0
+            want = []
+            for n in names:
+                pg = object_to_pg(pool, n)
+                after = om.pg_to_up_acting_osds(pg)[2]
+                for s, (o0, o1) in enumerate(zip(before[n], after)):
+                    if o0 != o1:
+                        lost += o0 == victim
+                        moved += o0 != victim
+                        want.append((c.osds[o1].store, coll_t(
+                            pool.id, pool.raw_pg_to_pg(pg).ps, s),
+                            ghobject_t(n, shard=s)))
+            assert lost and moved, (lost, moved)
+            deadline = asyncio.get_running_loop().time() + 60
+            while not all(st.exists(cl, o) for st, cl, o in want):
+                assert asyncio.get_running_loop().time() < deadline, \
+                    "no recovery"
+                await asyncio.sleep(0.2)
+            await asyncio.sleep(0.5)  # the last push's counters
+            for n in names:
+                assert await io.read(n) == payload[n]
+            got = {k: v - was[k] for k, v in _recovery_counters(c).items()}
+            shard_len = want[0][0].stat(want[0][1], want[0][2])
+            assert got["recovery_rebuilt_bytes"] == lost * shard_len, got
+            assert got["recovery_decode_bytes"] == \
+                (lost + moved) * shard_len, got
+            if code == "clay":
+                assert got["recovery_fullchunk_repairs"] == 0, got
+                assert got["recovery_subchunk_repairs"] == lost, got
+                assert got["recovery_read_bytes"] == \
+                    D / (D - K + 1) * got["recovery_rebuilt_bytes"], got
+            else:
+                assert got["recovery_read_bytes"] >= \
+                    K * got["recovery_rebuilt_bytes"], got
+
+    run(go())

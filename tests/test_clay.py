@@ -197,34 +197,6 @@ def test_is_repair_predicate():
 # -- ECUtil integration (recovery flow with partial reads) -------------------
 
 
-def test_jit_repair_program_bit_exact():
-    """The single-dispatch traced repair (clay_jit) reproduces the host
-    repair byte-for-byte for every lost position."""
-    import numpy as np
-
-    from ceph_tpu.ec import registry
-    from ceph_tpu.ec.plugins.clay_jit import ClayRepairProgram
-
-    ec = registry.factory(
-        "clay", {"k": "4", "m": "2", "d": "5", "scalar_mds": "jax"}
-    )
-    cs = ec.get_chunk_size(4 * 65536)
-    rng = np.random.default_rng(5)
-    data = rng.integers(0, 256, 4 * cs, dtype=np.uint8)
-    enc = ec.encode(set(range(6)), data)
-    sub = cs // ec.get_sub_chunk_count()
-    for lost in range(6):
-        minimum = ec.minimum_to_decode({lost}, set(range(6)) - {lost})
-        helpers = {
-            c: np.concatenate([enc[c][o*sub:(o+n)*sub] for o, n in runs])
-            for c, runs in minimum.items()
-        }
-        lost_node = lost if lost < ec.k else lost + ec.nu
-        prog = ClayRepairProgram(ec, lost_node)
-        out = prog.repair(helpers)
-        assert np.array_equal(out, enc[lost]), lost
-
-
 def test_ecutil_decode_shards_with_subchunk_reads():
     ec = make(4, 2, 5)
     k = 4

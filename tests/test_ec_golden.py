@@ -66,6 +66,30 @@ def test_pinned_bytes(key):
             )
 
 
+MANUAL = os.path.join(os.path.dirname(__file__), "golden",
+                      "jerasure_manual.json")
+
+
+@pytest.mark.parametrize("col", range(7))
+def test_reed_sol_van_bytes_rest_on_the_jerasure_manuals_matrix(col):
+    """The pinned corpus above is the program's own output; this vector
+    is not: jerasure's manual prints the coding rows of ``reed_sol_01 7
+    7 8``.  Data chunk ``col`` all ones and the rest zero must store
+    coding chunk i as the constant M[i][col] (PR 34 put the last row
+    right; before, it stored 1 230 79 86 43 115 171 there)."""
+    with open(MANUAL) as f:
+        M = json.load(f)["reed_sol_01_7_7_8"]
+    ec = registry.factory("jerasure", {
+        "plugin": "jerasure", "technique": "reed_sol_van",
+        "k": "7", "m": "7", "w": "8"})
+    cs = ec.get_chunk_size(7 * 64)
+    data = np.zeros((7, cs), np.uint8)
+    data[col] = 1
+    enc = ec.encode(set(range(14)), data.tobytes())
+    for i in range(7):
+        assert set(enc[7 + i].tolist()) == {M[i][col]}, (i, col)
+
+
 def test_corpus_covers_every_shipped_plugin():
     plugins = {e["plugin"] for e in CORPUS.values()}
     assert {"jerasure", "isa", "jax", "shec", "lrc", "clay"} <= plugins

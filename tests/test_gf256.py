@@ -76,6 +76,22 @@ def test_bitmatrix_agrees_with_field_mul():
         assert prod == gf.gf_mul(c, x), (c, x)
 
 
+def test_matrix_bitmatrix_is_the_blockwise_expansion():
+    """The gather (PR 34) against the loop it replaced: block (i, j) is
+    the 8x8 matrix of entry (i, j)."""
+    rng = np.random.default_rng(34)
+    M = rng.integers(0, 256, (5, 9), dtype=np.uint8)
+    M[0, 0], M[4, 8] = 0, 1
+    B = gf.gf_matrix_to_bitmatrix(M)
+    assert B.shape == (40, 72) and B.dtype == np.uint8
+    for i in range(5):
+        for j in range(9):
+            assert np.array_equal(
+                B[8 * i:8 * i + 8, 8 * j:8 * j + 8],
+                gf.gf_const_to_bitmatrix(int(M[i, j]))), (i, j)
+    B[0, 0] ^= 1        # a result is the caller's own
+
+
 def test_matrix_bitmatrix_encode_equivalence():
     rng = np.random.default_rng(4)
     k, m, n = 4, 2, 16
