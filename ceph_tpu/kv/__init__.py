@@ -182,6 +182,22 @@ class MemDB:
                 keys = self._sorted[prefix] = sorted(cf)
             return Iterator(keys, dict(cf))
 
+    def get_prefix(self, prefix: str, base: str) -> dict[str, bytes]:
+        """The keys of the family that start with ``base``, each without
+        it, and their values: one object's attrs or omap.  It copies
+        what it returns, not the family (an iterator does)."""
+        with self._lock:
+            cf = self._cf.get(prefix, {})
+            keys = self._sorted.get(prefix)
+            if keys is None:
+                keys = self._sorted[prefix] = sorted(cf)
+            out, n = {}, len(base)
+            for i in range(bisect.bisect_left(keys, base), len(keys)):
+                if not keys[i].startswith(base):
+                    break
+                out[keys[i][n:]] = cf[keys[i]]
+            return out
+
     def prefixes(self) -> list[str]:
         with self._lock:
             return sorted(self._cf)

@@ -65,7 +65,6 @@ from ceph_tpu.osd.pgutil import (
     SIZE_ATTR,
     USER_XATTR_PREFIX,
     VERSION_ATTR,
-    _read_extents,
     _v_bytes,
     _v_parse,
 )
@@ -1041,16 +1040,11 @@ class ECBackendMixin:
             c = self._shard_coll(pool, pg, shard)
             o = (ghobject_t(oid, shard=shard) if snap == NOSNAP
                  else ghobject_t(oid, snap=snap, shard=shard))
-            if not self.store.exists(c, o):
-                return None, None, errno.ENOENT
             try:
-                if extents:
-                    data = _read_extents(self.store, c, o, extents)
-                else:
-                    data = self.store.read(
-                        c, o, off, None if length == 0 else length
-                    )
-                return data, self.store.getattrs(c, o), 0
+                data, attrs = self._store_read(
+                    c, o, off, None if length == 0 else length,
+                    extents=extents, parent=tracing.CURRENT_SPAN.get())
+                return data, attrs, 0
             except FileNotFoundError:
                 return None, None, errno.ENOENT
             except OSError as e:
@@ -1243,40 +1237,35 @@ class ECBackendMixin:
         c = self._shard_coll(pool, msg.pg, msg.shard)
         o = (ghobject_t(msg.oid, shard=msg.shard) if msg.snap == NOSNAP
              else ghobject_t(msg.oid, snap=msg.snap, shard=msg.shard))
-        if not self.store.exists(c, o):
+        try:
+            data, attrs = self._store_read(
+                c, o, msg.off, None if msg.length == 0 else msg.length,
+                extents=msg.extents, attrs=msg.want_attrs, ctx=msg.trace)
+            self.perf.inc("subop_read_bytes", len(data))
+            rep = MOSDECSubOpReadReply(
+                tid=msg.tid, pg=msg.pg, shard=msg.shard,
+                from_osd=self.id, result=0, data=data, attrs=attrs,
+                epoch=self.epoch,
+            )
+        except FileNotFoundError:
             rep = MOSDECSubOpReadReply(
                 tid=msg.tid, pg=msg.pg, shard=msg.shard, from_osd=self.id,
                 result=-errno.ENOENT, epoch=self.epoch,
             )
-        else:
-            try:
-                if msg.extents:
-                    data = _read_extents(self.store, c, o, msg.extents)
-                else:
-                    data = self.store.read(
-                        c, o, msg.off, None if msg.length == 0 else msg.length
-                    )
-                self.perf.inc("subop_read_bytes", len(data))
-                attrs = self.store.getattrs(c, o) if msg.want_attrs else {}
-                rep = MOSDECSubOpReadReply(
-                    tid=msg.tid, pg=msg.pg, shard=msg.shard,
-                    from_osd=self.id, result=0, data=data, attrs=attrs,
-                    epoch=self.epoch,
-                )
-            except OSError as e:
-                # e.g. a checksum-at-rest failure (BlockStore EIO): the
-                # primary excludes this shard and reconstructs from the
-                # others (the reference's shard-EIO path,
-                # ECBackend::handle_sub_read error handling).  Locally
-                # the error feeds the read-error ledger: quarantine +
-                # escalation run on the osd that OWNS the dying disk.
-                if (e.errno or errno.EIO) == errno.EIO:
-                    self._note_medium_error(
-                        pool, msg.pg, msg.shard, msg.oid, snap=msg.snap)
-                rep = MOSDECSubOpReadReply(
-                    tid=msg.tid, pg=msg.pg, shard=msg.shard,
-                    from_osd=self.id, result=-(e.errno or 5),
-                    epoch=self.epoch,
-                )
+        except OSError as e:
+            # e.g. a checksum-at-rest failure (BlockStore EIO): the
+            # primary excludes this shard and reconstructs from the
+            # others (the reference's shard-EIO path,
+            # ECBackend::handle_sub_read error handling).  Locally
+            # the error feeds the read-error ledger: quarantine +
+            # escalation run on the osd that OWNS the dying disk.
+            if (e.errno or errno.EIO) == errno.EIO:
+                self._note_medium_error(
+                    pool, msg.pg, msg.shard, msg.oid, snap=msg.snap)
+            rep = MOSDECSubOpReadReply(
+                tid=msg.tid, pg=msg.pg, shard=msg.shard,
+                from_osd=self.id, result=-(e.errno or 5),
+                epoch=self.epoch,
+            )
         rep.trace = msg.trace
         await msg.conn.send_message(rep)

@@ -37,16 +37,21 @@ RB_SNAP = 0x7FFFFFFFFFFFFF00
 ECConnErrors = (ConnectionError, asyncio.TimeoutError)
 
 
-def _read_extents(store, c, o, extents) -> bytes:
+def _read_extents(store, c, o, extents, *, attrs=True, marks=None):
     """Serve a multi-run ranged read from ONE covering store read:
     checksummed engines (BlockStore) verify each blob once instead of
-    once per run — CLAY sub-chunk repairs issue many runs per chunk."""
+    once per run — CLAY sub-chunk repairs issue many runs per chunk.
+    ``(data, attrs)`` and ``marks`` as ``ObjectStore.read_object``."""
     lo = min(eo for eo, _ln in extents)
     hi = max(eo + ln for eo, ln in extents)
-    span = bytes(store.read(c, o, lo, hi - lo))
+    span, xattrs = store.read_object(
+        c, o, lo, hi - lo, attrs=attrs, marks=marks)
+    if marks is not None and "copies" in marks:
+        marks["copies"] += 1    # the runs, laid end to end below
     # per-run slices clamp at the object size exactly like the
     # individual reads they replace (no padding)
-    return b"".join(span[eo - lo : eo - lo + ln] for eo, ln in extents)
+    span = memoryview(span)
+    return b"".join(span[eo - lo : eo - lo + ln] for eo, ln in extents), xattrs
 
 
 class ECFetchError(Exception):

@@ -962,6 +962,10 @@ class RecoveryMixin:
                 ok = await self._reconcile_object_locked(
                     pool, pg, pairs, oid, stray, prior_pairs)
             sp.tag(result="ok" if ok else "failed")
+            if not ok:
+                log.warning(
+                    "osd.%d: %s/%s not reconciled on this pass; a later "
+                    "one retries", self.id, pg, oid)
             return ok
 
     async def _reconcile_object_locked(
@@ -988,9 +992,12 @@ class RecoveryMixin:
         for s, o in pairs:
             try:
                 payload, attrs = await self._probe_shard(pool, pg, s, o, oid)
-            except (OSError, asyncio.TimeoutError, ConnectionError):
+            except (OSError, asyncio.TimeoutError, ConnectionError) as e:
                 # unreachable: not a source nor target now — but its
                 # unseen state VETOES destructive decisions below
+                log.warning(
+                    "osd.%d: %s/%s: no probe of shard %d on osd.%d: %r",
+                    self.id, pg, oid, s, o, e)
                 unprobed.append((s, o))
                 continue
             if payload is None:
@@ -1105,8 +1112,8 @@ class RecoveryMixin:
                     self._push(pool, pg, s, o, oid, payload, src_attrs)
                     for s, o in targets
                 ), return_exceptions=True)  # a dead target must not abort
-            return clone_ok and not unprobed and not any(
-                isinstance(r, BaseException) for r in results)
+            return clone_ok and not unprobed and self._all_pushed(
+                pg, oid, targets, results)
         ec = self._ec_for(pool)
         sinfo = self._sinfo(ec)
         k = ec.get_data_chunk_count()
@@ -1449,8 +1456,17 @@ class RecoveryMixin:
                            src_attrs, force=force_push)
                 for s, o in targets
             ), return_exceptions=True)  # dead targets retry next pass
-        return not unprobed and not any(
-            isinstance(r, BaseException) for r in results)
+        return not unprobed and self._all_pushed(pg, oid, targets, results)
+
+    def _all_pushed(self, pg, oid, targets, results) -> bool:
+        """Whether every push of one object reached its target; one that
+        did not is named in the log with its error."""
+        for (s, o), r in zip(targets, results):
+            if isinstance(r, BaseException):
+                log.warning(
+                    "osd.%d: %s/%s: push of shard %d to osd.%d failed: %r",
+                    self.id, pg, oid, s, o, r)
+        return not any(isinstance(r, BaseException) for r in results)
 
     #: reserved push-attr key carrying a clone's snap id (clone pushes
     #: reuse the MOSDPGPush frame; the receiver pops this and files the

@@ -347,6 +347,16 @@ class BlockStore(ObjectStore):
     # -- reads ---------------------------------------------------------
 
     def read(self, c, o, off=0, length=None):
+        return self.read_object(c, o, off, length, attrs=False)[0]
+
+    def read_object(self, c, o, off=0, length=None, *, attrs=True,
+                    marks=None):
+        """What a served read asks of an object, in one call and from
+        one load of its meta: ``(data, attrs)`` (``attrs`` False: no
+        xattrs are looked up, ``{}``), ``FileNotFoundError`` where
+        ``exists`` would say no.  ``marks``, like a transaction's, is
+        the caller's dict to stamp: ``copies``, the passes made over the
+        bytes after the ``pread`` (0: the verified blob itself)."""
         store_fault_check("read", self.fault_domain)
         if store_data_fault("read", self.fault_domain, peek=True):
             self._maybe_flip_bit(c, o)
@@ -360,9 +370,12 @@ class BlockStore(ObjectStore):
             if meta == last:
                 break
             try:
-                return self._read_with_meta(c, o, meta, off, length)
+                data = self._read_with_meta(c, o, meta, off, length, marks)
             except BlobError:
                 last = meta
+                continue
+            return data, (self.db.get_prefix("X", _okey(c, o) + SEP)
+                          if attrs else {})
         raise BlobError(5, f"checksum mismatch in {c}/{o}")
 
     def _maybe_flip_bit(self, c, o) -> None:
@@ -383,32 +396,49 @@ class BlockStore(ObjectStore):
         if byte:
             os.pwrite(self._fd, bytes([byte[0] ^ 0x40]), pos)
 
-    def _read_with_meta(self, c, o, meta, off=0, length=None):
+    def _read_with_meta(self, c, o, meta, off=0, length=None, marks=None):
         size = meta["size"]
         end = size if length is None else min(off + length, size)
         if off >= end:
             return b""
+        extents = meta.get("extents", [])
+        if marks is None:
+            marks = {}
+        # extents and inline pieces never overlap (_punch_hole clears a
+        # range before anything is written into it), so an extent that
+        # covers the whole range is all there is to it: every EC shard
+        # written by write_full is one blob.  The bytes object the crc
+        # was checked on is returned itself, or one slice of it.
+        for lo, blob, ln in extents:
+            if lo <= off and end <= lo + ln:
+                data = self._verified_blob(c, o, lo, blob, ln)
+                whole = end - off == ln
+                marks["copies"] = 0 if whole else 1
+                return data if whole else data[off - lo : end - lo]
+        # several extents, holes (zero-filled) or inline pieces: each
+        # byte is copied into the buffer and once more out of it
+        marks["copies"] = 2
         out = bytearray(end - off)
-        for lo, blob, ln in meta.get("extents", []):
-            hi = lo + ln
-            s, e = max(off, lo), min(end, hi)
-            if s >= e:
-                continue
-            try:
-                data = self._read_blob(blob, ln)
-            except BlobError:
-                # checksum-at-rest violation (or a benign stale-meta
-                # race the caller's retry loop disambiguates)
-                raise BlobError(5, f"checksum mismatch in {c}/{o} @ {lo}")
-            out[s - off : e - off] = data[s - lo : e - lo]
+        for lo, blob, ln in extents:
+            s, e = max(off, lo), min(end, lo + ln)
+            if s < e:
+                data = memoryview(self._verified_blob(c, o, lo, blob, ln))
+                out[s - off : e - off] = data[s - lo : e - lo]
         for hoff, hexdata in meta.get("inline", {}).items():
             lo = int(hoff)
-            data = bytes.fromhex(hexdata)
-            hi = lo + len(data)
-            s, e = max(off, lo), min(end, hi)
+            data = memoryview(bytes.fromhex(hexdata))
+            s, e = max(off, lo), min(end, lo + len(data))
             if s < e:
                 out[s - off : e - off] = data[s - lo : e - lo]
         return bytes(out)
+
+    def _verified_blob(self, c, o, lo, blob, ln) -> bytes:
+        try:
+            return self._read_blob(blob, ln)
+        except BlobError:
+            # checksum-at-rest violation (or a benign stale-meta race
+            # the caller's retry loop disambiguates)
+            raise BlobError(5, f"checksum mismatch in {c}/{o} @ {lo}")
 
     def stat(self, c, o):
         return self._require(c, o)["size"]
@@ -425,11 +455,11 @@ class BlockStore(ObjectStore):
 
     def getattrs(self, c, o):
         self._require(c, o)
-        return self._prefix_dict("X", _okey(c, o) + SEP)
+        return self.db.get_prefix("X", _okey(c, o) + SEP)
 
     def omap_get(self, c, o):
         self._require(c, o)
-        return self._prefix_dict("M", _okey(c, o) + SEP)
+        return self.db.get_prefix("M", _okey(c, o) + SEP)
 
     def omap_get_values(self, c, o, keys):
         self._require(c, o)
@@ -439,14 +469,6 @@ class BlockStore(ObjectStore):
             v = self.db.get("M", base + k)
             if v is not None:
                 out[k] = v
-        return out
-
-    def _prefix_dict(self, prefix: str, base: str) -> dict[str, bytes]:
-        it = self.db.get_iterator(prefix).lower_bound(base)
-        out = {}
-        while it.valid() and it.key().startswith(base):
-            out[it.key()[len(base):]] = it.value()
-            it.next()
         return out
 
     def list_collections(self):

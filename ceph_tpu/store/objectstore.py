@@ -196,6 +196,17 @@ class ObjectStore:
     def read(self, c: coll_t, o: ghobject_t, off: int = 0, length: int | None = None) -> bytes:
         raise NotImplementedError
 
+    def read_object(
+        self, c: coll_t, o: ghobject_t, off: int = 0,
+        length: int | None = None, *, attrs: bool = True,
+        marks: dict | None = None,
+    ) -> tuple[bytes, dict[str, bytes]]:
+        """``(read(...), getattrs(...))`` as one call: what serving a
+        read takes (``attrs`` False: ``{}``).  A store that can say how
+        it got the bytes stamps ``marks`` (BlockStore: ``copies``)."""
+        data = self.read(c, o, off, length)
+        return data, (self.getattrs(c, o) if attrs else {})
+
     def stat(self, c: coll_t, o: ghobject_t) -> int:
         """Returns object size; raises FileNotFoundError if the
         collection or object is missing (all read methods do)."""

@@ -222,6 +222,182 @@ class TestCompressionAtRest:
         assert zstore.read(C, O1) == bytes(want)
 
 
+def _count_preads(monkeypatch):
+    calls = []
+    real = os.pread
+
+    def pread(fd, n, off):
+        calls.append(n)
+        return real(fd, n, off)
+
+    monkeypatch.setattr(os, "pread", pread)
+    return calls
+
+
+def _keep_blobs(store, monkeypatch):
+    """The objects ``_read_blob`` hands back, in order."""
+    blobs = []
+    real = store._read_blob
+
+    def read_blob(blob, ln):
+        blobs.append(real(blob, ln))
+        return blobs[-1]
+
+    monkeypatch.setattr(store, "_read_blob", read_blob)
+    return blobs
+
+
+def _flip_stored_byte(store, c, o, at=10):
+    blob = store._require(c, o)["extents"][0][1]
+    unit = int(blob.split(":")[0])
+    with open(os.path.join(store.path, "block"), "r+b") as f:
+        f.seek(unit * MIN_ALLOC + at)
+        byte = f.read(1)
+        f.seek(unit * MIN_ALLOC + at)
+        f.write(bytes([byte[0] ^ 0xFF]))
+
+
+def _read_via(store, entry, c, o, *a):
+    """The same read through either entry point."""
+    if entry == "read":
+        return store.read(c, o, *a)
+    return store.read_object(c, o, *a)[0]
+
+
+class TestAReadTouchesItsBytesOnce:
+    """PR 35: a read is one ``pread`` and one crc, and where one blob
+    covers the range the bytes the crc was checked on are the answer;
+    only a range over several pieces assembles into a buffer."""
+
+    def test_whole_blob_read_is_one_pread_and_that_object(
+            self, store, monkeypatch):
+        data = os.urandom(8 * MIN_ALLOC)   # an EC shard: 512 KiB, one blob
+        store.queue_transaction(
+            Transaction().write(C, O1, 0, data).setattrs(C, O1, {"_v": b"3.7"}))
+        preads, blobs = _count_preads(monkeypatch), _keep_blobs(store, monkeypatch)
+        marks = {}
+        got, attrs = store.read_object(C, O1, marks=marks)
+        assert preads == [len(data)] and len(blobs) == 1
+        assert got is blobs[0] and type(got) is bytes and got == data
+        assert attrs == {"_v": b"3.7"} and marks == {"copies": 0}
+        # length given and equal to the blob's, and read(): the same object
+        assert store.read_object(C, O1, 0, len(data), attrs=False) == (
+            blobs[1], {})
+        assert store.read(C, O1) is blobs[2] and len(preads) == 3
+
+    @pytest.mark.parametrize("off,length", [
+        (0, 100), (MIN_ALLOC + 7, 3 * MIN_ALLOC), (8 * MIN_ALLOC - 5, None),
+        (8 * MIN_ALLOC - 5, 4096)])
+    def test_sub_range_of_one_blob_is_one_pread_and_one_slice(
+            self, store, monkeypatch, off, length):
+        data = os.urandom(8 * MIN_ALLOC)
+        store.queue_transaction(Transaction().write(C, O1, 0, data))
+        preads, marks = _count_preads(monkeypatch), {}
+        got, _ = store.read_object(C, O1, off, length, attrs=False, marks=marks)
+        end = len(data) if length is None else min(off + length, len(data))
+        assert got == data[off:end] and type(got) is bytes
+        assert preads == [len(data)] and marks == {"copies": 1}
+
+    @pytest.mark.parametrize("entry", ["read", "read_object"])
+    def test_extents_inline_and_hole_still_assemble(self, store, entry):
+        want = bytearray(5 * MIN_ALLOC)
+        a, b = os.urandom(2 * MIN_ALLOC), os.urandom(MIN_ALLOC + 11)
+        small = b"inline piece" * 8
+        t = Transaction()
+        t.write(C, O1, 0, a)                          # a blob
+        t.write(C, O1, 2 * MIN_ALLOC + 1000, small)   # inline, after a hole
+        t.write(C, O1, 3 * MIN_ALLOC, b)              # a second blob
+        t.truncate(C, O1, 5 * MIN_ALLOC)              # a hole at the end
+        store.queue_transaction(t)
+        want[: len(a)] = a
+        want[2 * MIN_ALLOC + 1000 : 2 * MIN_ALLOC + 1000 + len(small)] = small
+        want[3 * MIN_ALLOC : 3 * MIN_ALLOC + len(b)] = b
+        meta = store._require(C, O1)
+        assert len(meta["extents"]) == 2 and meta["inline"]
+        for off, length in [(0, None), (MIN_ALLOC, 3 * MIN_ALLOC),
+                            (2 * MIN_ALLOC - 1, 1002), (2 * MIN_ALLOC, 500),
+                            (3 * MIN_ALLOC - 1, 2), (4 * MIN_ALLOC, None),
+                            (5 * MIN_ALLOC, 10), (0, 10 * MIN_ALLOC)]:
+            end = len(want) if length is None else min(off + length, len(want))
+            args = (off,) if length is None else (off, length)
+            assert _read_via(store, entry, C, O1, *args) == bytes(
+                want[off:end]), (off, length)
+        marks = {}
+        store.read_object(C, O1, attrs=False, marks=marks)
+        assert marks == {"copies": 2}
+        # a range inside one of several blobs is still one slice of it
+        store.read_object(C, O1, 3 * MIN_ALLOC + 5, 100, attrs=False,
+                          marks=marks)
+        assert marks == {"copies": 1}
+
+    @pytest.mark.parametrize("entry", ["read", "read_object"])
+    def test_flipped_byte_on_disk_is_eio(self, store, entry):
+        store.queue_transaction(
+            Transaction().write(C, O1, 0, os.urandom(8 * MIN_ALLOC)))
+        _flip_stored_byte(store, C, O1, at=3 * MIN_ALLOC)
+        for args in [(), (0, 10), (5 * MIN_ALLOC, None)]:
+            with pytest.raises(OSError) as ei:   # the crc is over the blob
+                _read_via(store, entry, C, O1, *args)
+            assert ei.value.errno == 5
+
+    @pytest.mark.parametrize("entry", ["read", "read_object"])
+    def test_compressed_blob_round_trips(self, tmp_path, entry):
+        z = BlockStore(str(tmp_path / "bz"), compression="zlib")
+        z.mount()
+        z.queue_transaction(Transaction().create_collection(C))
+        data = bytes(range(256)) * (2 * MIN_ALLOC // 256)
+        z.queue_transaction(Transaction().write(C, O1, 0, data))
+        assert len(z._require(C, O1)["extents"][0][1].split(":")) == 5
+        assert _read_via(z, entry, C, O1) == data
+        assert _read_via(z, entry, C, O1, 300, 70000) == data[300:70300]
+        _flip_stored_byte(z, C, O1)
+        with pytest.raises(OSError):
+            _read_via(z, entry, C, O1)
+
+    @pytest.mark.parametrize("entry", ["read", "read_object"])
+    def test_stale_meta_is_retried_and_same_meta_is_rot(
+            self, store, monkeypatch, entry):
+        """A commit on a worker thread may free and reuse a blob's units
+        between the reader's meta load and its pread: a crc failure under
+        a CHANGED meta reloads and retries, under the same meta is EIO."""
+        from ceph_tpu.store.blockstore import BlobError
+
+        old, new = os.urandom(2 * MIN_ALLOC), os.urandom(2 * MIN_ALLOC)
+        store.queue_transaction(Transaction().write(C, O1, 0, old))
+        real, calls = store._read_blob, []
+
+        def racing_read_blob(blob, ln):
+            calls.append(blob)
+            if len(calls) == 1:     # the writer wins the race, once
+                store.queue_transaction(Transaction().write(C, O1, 0, new))
+                raise BlobError(5, "stale")
+            return real(blob, ln)
+
+        monkeypatch.setattr(store, "_read_blob", racing_read_blob)
+        assert _read_via(store, entry, C, O1) == new
+        assert len(calls) == 2 and calls[0] != calls[1]
+
+        def rotten(blob, ln):
+            calls.append(blob)
+            raise BlobError(5, "rot")
+
+        del calls[:]
+        monkeypatch.setattr(store, "_read_blob", rotten)
+        with pytest.raises(OSError) as ei:
+            _read_via(store, entry, C, O1)
+        assert ei.value.errno == 5 and len(calls) == 1   # same meta: no retry
+
+    def test_absent_object_is_enoent_and_an_inline_one_reads(self, store):
+        with pytest.raises(FileNotFoundError):
+            store.read_object(C, O1)
+        with pytest.raises(FileNotFoundError):
+            store.read_object(coll_t(9, 9, 9), O1)
+        store.queue_transaction(
+            Transaction().write(C, O1, 0, b"tiny").setattrs(C, O1, {"a": b"1"}))
+        assert store.read_object(C, O1) == (b"tiny", {"a": b"1"})
+        assert store.read_object(C, O1, attrs=False) == (b"tiny", {})
+
+
 class TestBitmapAllocator:
     @pytest.fixture
     def bstore(self, tmp_path):

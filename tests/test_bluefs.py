@@ -252,6 +252,35 @@ def test_fresh_iterator_is_ordered_and_complete_after(db, case):
     assert _drain(db.get_iterator("U").seek_to_first()) == []
 
 
+@pytest.mark.parametrize("case", sorted(_KEY_SET_CHANGES))
+def test_get_prefix_is_the_iterators_scan_without_the_copy(db, case, monkeypatch):
+    """PR 35: one object's attrs are a point read; what an iterator from
+    ``lower_bound(base)`` would collect, after any change of the key set,
+    and the family is not copied for it."""
+    change, _now = _KEY_SET_CHANGES[case]
+    b = WriteBatch()
+    for k in ("o1\x00_v", "o1\x00hinfo", "o1", "o10\x00_v", "o2\x00_v", "a"):
+        b.set("T", k, k.encode())
+    db.submit(b)
+    db.submit(change(WriteBatch()))
+
+    def by_iterator(base):
+        it, out = db.get_iterator("T").lower_bound(base), {}
+        while it.valid() and it.key().startswith(base):
+            out[it.key()[len(base):]] = it.value()
+            it.next()
+        return out
+
+    want = {base: by_iterator(base) for base in
+            ("o1\x00", "o10\x00", "o2\x00", "o3\x00", "", "z")}
+    monkeypatch.setattr(db, "get_iterator", None)     # no iterator is opened
+    for base, attrs in want.items():
+        assert db.get_prefix("T", base) == attrs
+    assert db.get_prefix("U", "o1\x00") == {}
+    if case not in ("rm_prefix", "rm_prefix_then_set"):
+        assert want["o1\x00"] == {"_v": b"o1\x00_v", "hinfo": b"o1\x00hinfo"}
+
+
 def test_iterators_share_the_key_list_while_the_key_set_stands(db):
     _seed(db)
     first = db.get_iterator("T")
