@@ -213,6 +213,9 @@ class FileDB(MemDB):
         self.checkpoint_bytes = checkpoint_bytes
         self._wal = None
         self._wal_size = 0
+        #: every byte written since the start, WAL records and
+        #: checkpoints (BlockStore reads the growth around a submit)
+        self.bytes_written = 0
         # serializes WAL append+fsync+checkpoint; the memtable lock
         # (self._lock) is held only for _apply so readers on the event
         # loop never wait out an fsync
@@ -265,6 +268,7 @@ class FileDB(MemDB):
             if sync:
                 os.fsync(self._wal.fileno())
             self._wal_size += len(rec)
+            self.bytes_written += len(rec)
             with self._lock:
                 self._apply(batch)
             if self._wal_size >= self.checkpoint_bytes:
@@ -286,7 +290,8 @@ class FileDB(MemDB):
         blob = b"".join(out)
         tmp = os.path.join(self.path, "checkpoint.tmp")
         with open(tmp, "wb") as f:
-            f.write(struct.pack("<I", crc32c(blob)) + blob)
+            self.bytes_written += f.write(
+                struct.pack("<I", crc32c(blob)) + blob)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, os.path.join(self.path, "checkpoint"))

@@ -8,6 +8,7 @@ fallbacks so the package works before/without a toolchain.
 
 Public API:
   crc32c(data, seed=-1)          -- reference ceph_crc32c semantics
+  crc32c_chunks(data, chunk)     -- every chunk's crc, packed, in one call
   crc_backend()                  -- which code computes it on this host
   crc32c_zeros(length, seed=-1)  -- crc of `length` zero bytes
   xor_region(dst, src)           -- dst ^= src in place (uint8 arrays)
@@ -83,6 +84,13 @@ def _load():
             fn.argtypes = [
                 ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t,
                 ctypes.c_int,
+            ]
+        lib.crc32c_chunks_gil_kept = ctypes.PyDLL(so).ceph_tpu_crc32c_chunks
+        for fn in (lib.ceph_tpu_crc32c_chunks, lib.crc32c_chunks_gil_kept):
+            fn.restype = None
+            fn.argtypes = [
+                ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t,
+                ctypes.c_size_t, ctypes.c_void_p, ctypes.c_int,
             ]
         lib.ceph_tpu_crc_backend.restype = ctypes.c_char_p
         lib.ceph_tpu_crc_backend.argtypes = []
@@ -192,6 +200,33 @@ def crc32c(data, seed: int = 0xFFFFFFFF, *, table: bool = False) -> int:
         else np.asarray(data, dtype=np.uint8).reshape(-1)
     )
     return _crc_fn(lib, arr.nbytes)(seed, arr.ctypes.data, arr.nbytes, table)
+
+
+def crc32c_chunks(data, chunk: int, seed: int = 0xFFFFFFFF, *,
+                  table: bool = False) -> bytes:
+    """The crc32c of every ``chunk`` bytes of ``data`` (``bytes`` or a
+    contiguous buffer; the last chunk may be short), each from ``seed``,
+    packed little-endian, 4 bytes a chunk: one call and one pass
+    however many chunks, with the GIL rule of :func:`crc32c`."""
+    view = memoryview(data).cast("B")
+    n = view.nbytes
+    count = -(-n // chunk)
+    lib = _load()
+    if lib is None:
+        return b"".join(
+            _py_crc32c(view[at:at + chunk], seed).to_bytes(4, "little")
+            for at in range(0, n, chunk))
+    if count == 0:
+        return b""
+    out = (ctypes.c_uint32 * count)()
+    fn = (lib.crc32c_chunks_gil_kept if n < _GIL_KEPT_BELOW
+          else lib.ceph_tpu_crc32c_chunks)
+    if isinstance(data, bytes):
+        fn(seed & 0xFFFFFFFF, data, n, chunk, out, table)
+    else:
+        arr = np.frombuffer(view, dtype=np.uint8)
+        fn(seed & 0xFFFFFFFF, arr.ctypes.data, n, chunk, out, table)
+    return bytes(out)
 
 
 def crc_backend() -> str:

@@ -133,6 +133,25 @@ CRC_HW_TARGET uint32_t crc_hw(uint32_t crc, const uint8_t* data, size_t len) {
   return static_cast<uint32_t>(c0);
 }
 
+// Whole chunks three at a time, one lane each; returns the bytes done.
+CRC_HW_TARGET size_t crc_hw_chunks3(uint32_t seed, const uint8_t* data,
+                                    size_t len, size_t chunk, uint32_t* out) {
+  size_t at = 0;
+  for (; at + 3 * chunk <= len; at += 3 * chunk) {
+    const uint8_t* p = data + at;
+    uint64_t c0 = seed, c1 = seed, c2 = seed;
+    for (size_t i = 0; i < chunk; i += 8) {
+      c0 = CRC_HW_U64(c0, load64(p + i));
+      c1 = CRC_HW_U64(c1, load64(p + chunk + i));
+      c2 = CRC_HW_U64(c2, load64(p + 2 * chunk + i));
+    }
+    *out++ = static_cast<uint32_t>(c0);
+    *out++ = static_cast<uint32_t>(c1);
+    *out++ = static_cast<uint32_t>(c2);
+  }
+  return at;
+}
+
 #if defined(__x86_64__)
 const bool kHaveHw = __builtin_cpu_supports("sse4.2");
 #else
@@ -154,6 +173,25 @@ uint32_t ceph_tpu_crc32c(uint32_t crc, const uint8_t* data, size_t len,
   if (data == nullptr) return crc_zeros(crc, len);
   return kHaveHw && !table_only ? crc_hw(crc, data, len)
                                 : crc_table(crc, data, len);
+}
+
+// The crc of every `chunk` bytes of data (the last chunk may be short),
+// each from `seed`, into out[0 .. ceil(len / chunk)): a store's checksum
+// per csum chunk, all of a buffer's in one pass and one call.  Three
+// whole chunks run side by side as crc_hw's three lanes do, and need no
+// advance: they are three separate sums.
+void ceph_tpu_crc32c_chunks(uint32_t seed, const uint8_t* data, size_t len,
+                            size_t chunk, uint32_t* out, int table_only) {
+  const bool hw = kHaveHw && !table_only;
+  size_t at = 0;
+#ifdef CRC_HW_U64
+  if (hw && chunk % 8 == 0) at = crc_hw_chunks3(seed, data, len, chunk, out);
+  out += at / (chunk ? chunk : 1);
+#endif
+  for (; at < len; at += chunk) {
+    const size_t n = len - at < chunk ? len - at : chunk;
+    *out++ = hw ? crc_hw(seed, data + at, n) : crc_table(seed, data + at, n);
+  }
 }
 
 // Which path ceph_tpu_crc32c takes on this CPU.

@@ -968,8 +968,13 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             # compiling ahead of time on an accelerator backend (where
             # a cold compile stalls the I/O path for ~30 s); on the CPU
             # backend (tests, dev) compiles are milliseconds and the
-            # eager virtual-mesh warmup would cost more than it saves
-            farm_warm = jax.default_backend() == "tpu"
+            # eager virtual-mesh warmup would cost more than it saves.
+            # A single-device service compiles its ladder everywhere:
+            # small overwrites launch whatever a window happens to
+            # gather, and a rehearsal on the CPU then launches (and
+            # counts cold) what the chip would
+            farm_warm = jax.default_backend() == "tpu" or (
+                svc is not None and svc.mesh is None)
             for name, prof in fresh:
                 try:
                     ec = ec_registry.factory(
@@ -1025,6 +1030,13 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         self._extent_cache.move_to_end((pool_id, oid))
         self.perf.inc("ec_extent_cache_hit")
         return arr[lo - elo : hi - elo]
+
+    def _extent_cache_may_hold(self, pool_id, oid, lo) -> bool:
+        """Whether an entry of the object starts at or before ``lo`` and
+        reaches past it, whatever its version: worth a probe and
+        :meth:`_extent_cache_get` before the shards are read."""
+        ent = self._extent_cache.get((pool_id, oid))
+        return ent is not None and ent[1] <= lo < ent[1] + len(ent[2])
 
     def _extent_cache_put(self, pool_id, oid, version, lo, arr) -> None:
         limit = self.conf["osd_ec_extent_cache_bytes"]
@@ -1212,6 +1224,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             return
         if parent_span is None or parent_span is tracing.INERT:
             await asyncio.to_thread(self.store.queue_transaction, t)
+            self._count_commit(t.marks)
             return
         submitted = time.monotonic()
 
@@ -1235,6 +1248,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                         "fsync_ms": 1e3 * (m["fsync"] - m["data"]),
                         "kv_ms": 1e3 * (m["kv"] - m["fsync"]),
                     }
+                if "kv_bytes" in m:     # the store says what it wrote
+                    phases.update(block_bytes=m["block_bytes"],
+                                  kv_bytes=m["kv_bytes"],
+                                  folded=m["folded"])
                 self.tracer.record(
                     "store_txn", parent=parent_span,
                     start_mono=started, end_mono=ended,
@@ -1242,6 +1259,16 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                               if op[0] == TxOp.WRITE), **phases)
 
         await asyncio.to_thread(run)
+        self._count_commit(t.marks)
+
+    def _count_commit(self, marks: dict) -> None:
+        """What a store that counts its writes (BlockStore) stamped
+        into the transaction's marks, into ``perf``: bytes to the block
+        file, bytes the kv engine wrote, folds."""
+        if "kv_bytes" in marks:
+            self.perf.inc("store_block_write_bytes", marks["block_bytes"])
+            self.perf.inc("store_kv_write_bytes", marks["kv_bytes"])
+            self.perf.inc("store_folds", marks["folded"])
 
     def _store_read(
         self, c: coll_t, o: ghobject_t, off: int = 0,
@@ -1256,8 +1283,9 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
 
         ``store_read`` (``stage="store"``) is filed under the reader's
         span (``parent``, or the wire context ``ctx``), tagged
-        ``read_ms`` (the store's call), ``bytes`` and ``copies`` (passes
-        over the bytes after the ``pread``)."""
+        ``read_ms`` (the store's call), ``bytes``, ``copies`` (passes
+        over the bytes after the ``pread``) and ``disk_bytes`` (what
+        was read from the block file and checksummed)."""
         started = time.monotonic()
         marks, out = {}, None
         try:
@@ -1269,6 +1297,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     c, o, off, length, attrs=attrs, marks=marks)
             self.perf.inc("store_read_ops")
             self.perf.inc("store_read_bytes", len(out[0]))
+            self.perf.inc("store_read_disk_bytes", marks.get("disk_bytes", 0))
             return out
         finally:
             if parent is not None or ctx is not None:

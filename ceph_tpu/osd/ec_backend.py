@@ -328,10 +328,37 @@ class ECBackendMixin:
         local_ss_raw = self._getattr_quiet(
             self._shard_coll(pool, pg, my_shard),
             ghobject_t(msg.oid, shard=my_shard), SS_ATTR)
+        # a vector of plain writes says which stripes it dirties before
+        # anything is read: unless the extent cache may hold them, the
+        # probe brings their old content along (``fetched``: the chunk
+        # range asked for, and what came)
+        fetched = None
         if ops[0].op != OP_WRITE_FULL or snapc.snaps or local_ss_raw:
+            span = None
+            if all(o.op == OP_WRITE and len(o.data) for o in ops):
+                span = (
+                    min(sinfo.logical_to_prev_stripe_offset(o.off)
+                        for o in ops),
+                    max(sinfo.logical_to_next_stripe_offset(
+                        o.off + len(o.data)) for o in ops))
+                if self._extent_cache_may_hold(pool.id, msg.oid, span[0]):
+                    span = None
             try:
-                exists, _wo, cur_size, cur_v, ss, _attrs = \
-                    await self._ec_head_state(pool, pg, acting, msg.oid)
+                if span is None:
+                    exists, _wo, cur_size, cur_v, ss, _attrs = \
+                        await self._ec_head_state(pool, pg, acting, msg.oid)
+                else:
+                    c_lo = sinfo.logical_to_prev_chunk_offset(span[0])
+                    c_len = sinfo.logical_to_prev_chunk_offset(span[1]) - c_lo
+                    with self._maybe_span(
+                        "ec_rmw_read", parent=tracing.CURRENT_SPAN.get(),
+                        stage="net", cache_hit=False, bytes=span[1] - span[0],
+                        stripes=(span[1] - span[0]) // sinfo.stripe_width,
+                    ) as rmw_sp, tracing.scope(rmw_sp):
+                        (exists, _wo, cur_size, cur_v, ss, _attrs), chunks = \
+                            await self._ec_head_state_and_chunks(
+                                pool, pg, acting, msg.oid, c_lo, c_len)
+                    fetched = (span, chunks)
             except ECFetchError as e:
                 return MOSDOpReply(
                     tid=msg.tid, result=-e.errno, epoch=self.epoch)
@@ -516,23 +543,44 @@ class ECBackendMixin:
         buf = np.zeros(d_hi - d_lo, np.uint8)
         read_hi = min(d_hi, old_end)
         if exists and d_lo < read_hi:
-            cached = self._extent_cache_get(
-                pool.id, msg.oid, cur_v, d_lo, read_hi)
+            # the old stripes: what the probe brought along, else the
+            # extent cache, else k ranged sub-reads (filed under this
+            # span); chunks are reassembled, a cache entry is the bytes
+            self.perf.inc("ec_rmw_ops")
+            chunks = cached = None
+            if fetched is not None and fetched[0] == (d_lo, d_hi):
+                chunks = fetched[1]
+            else:
+                with self._maybe_span(
+                    "ec_rmw_read", parent=tracing.CURRENT_SPAN.get(),
+                    stage="net", stripes=(read_hi - d_lo) // sw,
+                ) as rmw_sp, tracing.scope(rmw_sp):
+                    cached = self._extent_cache_get(
+                        pool.id, msg.oid, cur_v, d_lo, read_hi)
+                    if rmw_sp is not None:
+                        rmw_sp.tag(cache_hit=cached is not None,
+                                   bytes=0 if cached is not None
+                                   else read_hi - d_lo)
+                    if cached is None:
+                        c_lo = sinfo.logical_to_prev_chunk_offset(d_lo)
+                        c_len = sinfo.logical_to_prev_chunk_offset(
+                            read_hi) - c_lo
+                        try:
+                            _sz, _a, chunks = await self._ec_fetch(
+                                pool, pg, acting, msg.oid, ec,
+                                chunk_off=c_lo, chunk_len=c_len,
+                                fast_read=pool.fast_read,
+                            )
+                        except ECFetchError as e:
+                            return MOSDOpReply(
+                                tid=msg.tid, result=-e.errno,
+                                epoch=self.epoch)
             if cached is not None:
                 # hot stripe: the bytes we last wrote at cur_v ARE the
-                # on-disk content — skip the shard read entirely
+                # on-disk content — no shard was read
                 buf[: read_hi - d_lo] = cached
             else:
-                c_lo = sinfo.logical_to_prev_chunk_offset(d_lo)
-                c_len = sinfo.logical_to_prev_chunk_offset(read_hi) - c_lo
-                try:
-                    _sz, _a, chunks = await self._ec_fetch(
-                        pool, pg, acting, msg.oid, ec,
-                        chunk_off=c_lo, chunk_len=c_len,
-                        fast_read=pool.fast_read,
-                    )
-                except ECFetchError as e:
-                    return MOSDOpReply(tid=msg.tid, result=-e.errno, epoch=self.epoch)
+                self.perf.inc("ec_rmw_read_bytes", read_hi - d_lo)
                 old_logical = await self._ecu_decode_concat(sinfo, ec, chunks)
                 buf[: len(old_logical)] = old_logical
         for off, data in real_edits:
@@ -675,18 +723,33 @@ class ECBackendMixin:
         """Probe the EC head object: (exists, whiteout, size, version,
         SnapSet, attrs).  exists is False for a whiteout head (data-
         plane absent) but the SnapSet still anchors its clones."""
+        return (await self._ec_head_state_and_chunks(
+            pool, pg, acting, oid))[0]
+
+    async def _ec_head_state_and_chunks(
+        self, pool, pg, acting, oid, chunk_off: int = 0, chunk_len: int = 0,
+    ):
+        """:meth:`_ec_head_state`, and with a ``chunk_len`` the chunk
+        range [chunk_off, +chunk_len) of k shards from the SAME round of
+        sub-reads that brings the attrs: a partial overwrite knows the
+        stripes it lands on before it knows the object, so its probe and
+        its read of the old stripes are one fetch, not two in a row.
+        Returns ``(state, chunks)``; the chunks end where the shards do
+        and are ``{}`` for a probe or an absent object."""
         ec = self._ec_for(pool)
         try:
-            sz, attrs, _ = await self._ec_fetch(
-                pool, pg, acting, oid, ec, want_data=False)
+            sz, attrs, chunks = await self._ec_fetch(
+                pool, pg, acting, oid, ec, chunk_off=chunk_off,
+                chunk_len=chunk_len, want_data=chunk_len > 0,
+                fast_read=pool.fast_read)
         except ECFetchError as e:
             if e.errno != errno.ENOENT:
                 raise  # degraded, not absent: callers surface the errno
-            return False, False, 0, ZERO, SnapSet(), {}
+            return (False, False, 0, ZERO, SnapSet(), {}), {}
         ss = SnapSet.from_bytes(attrs.get(SS_ATTR))
         wo = attrs.get(WHITEOUT_ATTR) == b"1"
         v = _v_parse(attrs.get(VERSION_ATTR))
-        return (not wo), wo, (0 if wo else sz), v, ss, attrs
+        return ((not wo), wo, (0 if wo else sz), v, ss, attrs), chunks
 
     async def _ec_served_version(
         self, pool, pg, acting, oid, lg=None

@@ -175,17 +175,21 @@ def encode(
 # op is coalesced with concurrent ops into one sharded farm dispatch —
 # the production form of the ECSubWrite fan-out seam (reference
 # src/osd/ECCommon.cc:749, SURVEY.md §2.9).  Every gate failure falls
-# back to the sync single-device path, so behavior is identical.
+# back to the sync single-device path, so behavior is identical; a
+# flushed group too small for a launch is answered on the host by the
+# service itself.
 
 
-def _service_takes(service, nbytes: int) -> bool:
-    return (service is not None and service.active()
-            and nbytes >= service.min_bytes)
+def _service_takes(service) -> bool:
+    """An active service is filed every request, whatever its size:
+    whether a launch is worth its cost is decided per flushed group, by
+    what the group carries (parallel/batcher.py), not per caller."""
+    return service is not None and service.active()
 
 
-def _farm_ready(service, ec_impl, nbytes: int) -> bool:
+def _farm_ready(service, ec_impl) -> bool:
     return (
-        _service_takes(service, nbytes)
+        _service_takes(service)
         and isinstance(ec_impl, MatrixErasureCode)
         and ec_impl.rows_per_chunk == 1
     )
@@ -273,9 +277,8 @@ async def encode_async(
         if isinstance(data, np.ndarray)
         else np.frombuffer(data, dtype=np.uint8)    # bytes or a view
     )
-    vector = (_service_takes(service, arr.nbytes)
-              and _is_linear_vector_code(ec_impl))
-    if not vector and not _farm_ready(service, ec_impl, arr.nbytes):
+    vector = _service_takes(service) and _is_linear_vector_code(ec_impl)
+    if not vector and not _farm_ready(service, ec_impl):
         return encode(sinfo, ec_impl, arr, want)
     sw, cs = sinfo.stripe_width, sinfo.chunk_size
     if arr.nbytes % sw:
@@ -394,8 +397,7 @@ async def _decode_chunks_async(
     None = caller should take the sync path."""
     if not to_decode:
         return None
-    nbytes = sum(np.asarray(v).size for v in to_decode.values())
-    if not _farm_ready(service, ec_impl, nbytes):
+    if not _farm_ready(service, ec_impl):
         return None
     if not isinstance(ec_impl, MatrixErasureCode) or ec_impl.get_sub_chunk_count() != 1:
         return None

@@ -11,7 +11,11 @@ by ONE fixed-shape launch.  What the engines share is here, once:
 - *dispatch side*: the flush hands every group to a worker thread, where
   the engine's plan (``_run_group``) packs, launches and cuts back; a
   plan that raises is counted and every waiter is answered from the
-  engine's host path (``_host_group``), so no caller fails;
+  engine's host path (``_host_group``), so no caller fails.  Whether a
+  launch is worth its cost is the GROUP's question: one whose requests
+  together carry fewer than the engine's ``min_bytes`` is answered by
+  ``_host_group`` at the flush, inline (``host_groups`` /
+  ``host_requests``: a decision, not a fallback);
 - :data:`device_matrices`, the process's one LRU of device-resident
   operand matrices (``MatrixErasureCode._apply_device`` uses it too);
 - the warm set: ``_prewarm`` compiles a ladder of shape keys once
@@ -168,6 +172,10 @@ class LaunchBatcher:
     #: the ``stage="queue"`` child span filed under each traced waiter a
     #: launch serves (None: the engine's callers are not traced per op)
     wait_name: str | None = None
+    #: a flushed group whose requests together carry fewer real bytes
+    #: (``_group_bytes``) is answered on the host at the flush: a launch
+    #: costs more than so small a product does.  0: every group launches
+    min_bytes = 0
 
     def __init__(self, family: str, *, window_s: float, placement=None):
         self.window_s = window_s
@@ -206,7 +214,26 @@ class LaunchBatcher:
         pending, self._pending = self._pending, {}
         loop = asyncio.get_running_loop()
         for key, group in pending.items():
-            loop.create_task(self._dispatch(key, group))
+            if self.min_bytes and self._group_bytes(group) < self.min_bytes:
+                # here, inline: so small a product is a tenth of a
+                # millisecond of numpy, a hand-off to a worker thread
+                # waits ten times that for its thread (PERF.md, PR 35)
+                self.stats["host_groups"] += 1
+                self.stats["host_requests"] += len(group)
+                self._answer(group, self._host_group(key, group))
+            else:
+                loop.create_task(self._dispatch(key, group))
+
+    def _group_bytes(self, group: list[Request]) -> int:
+        """The real bytes a launch for ``group`` would carry (engines
+        with a ``min_bytes`` say how to count theirs)."""
+        raise NotImplementedError
+
+    @staticmethod
+    def _answer(group: list[Request], outs) -> None:
+        for req, out in zip(group, outs):
+            if not req.fut.done():
+                req.fut.set_result(out)
 
     async def _dispatch(self, key, group: list[Request]) -> None:
         try:
@@ -216,9 +243,7 @@ class LaunchBatcher:
             # (always correct), don't fail client ops
             self.stats[self.fallback_stat] += 1
             outs = await asyncio.to_thread(self._host_group, key, group)
-        for req, out in zip(group, outs):
-            if not req.fut.done():
-                req.fut.set_result(out)
+        self._answer(group, outs)
 
     def _matrix(self, key, build):
         """``build()`` resident where this engine's launches want it."""
@@ -243,7 +268,8 @@ class LaunchBatcher:
         inside the ``guard`` transfer-guard window (an implicit transfer
         between the explicit upload and gather is a counted violation
         and a host fallback), and counts the launch per (w[, b][,
-        labels]) bucket once it is back.  Yields the span."""
+        labels]) bucket once it is back.  The span says what the launch
+        carried (``real_bytes``).  Yields the span."""
         from ceph_tpu.common.transfer_guard import no_implicit_transfers
 
         labels["w"] = w
@@ -259,7 +285,8 @@ class LaunchBatcher:
             self.metrics.inc("cold_launches", **labels)
         with tracing.launch_span(
             self.wait_name, [(r.span, r.arrived) for r in waiters],
-            kind=kind, w=w, b_real=b_real, cold=cold, **tags,
+            kind=kind, w=w, b_real=b_real, real_bytes=real_bytes,
+            cold=cold, **tags,
         ) as span, no_implicit_transfers(guard):
             yield span
         self.metrics.inc("launches", **labels)

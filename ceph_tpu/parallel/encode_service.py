@@ -16,8 +16,12 @@ generate_transactions -> ECTransaction.cc:37 encode_and_write;
 src/osd/OSDMapMapping.h:18 ParallelPGMapper for the batch pattern):
 independent per-PG ops become one batched TPU computation.
 
-In a cpu-only single-device process the service is inactive; there, and
-for payloads under ``min_bytes``, callers keep their host/1-chip path.
+In a cpu-only single-device process the service is inactive and callers
+keep their host/1-chip path.  An active service is filed every request;
+a flushed group that carries fewer than ``min_bytes`` in all is answered
+on the host at the flush (parallel/batcher.py): the decision is the
+group's, so 4 KiB overwrites that arrive together share a launch that
+none of them would be worth alone.
 """
 
 from __future__ import annotations
@@ -29,8 +33,9 @@ import numpy as np
 from ceph_tpu.parallel import batcher, encode_farm
 from ceph_tpu.parallel.batcher import LaunchBatcher, MatMul, Request
 
-#: payloads smaller than this stay on the caller's local path — TPU/mesh
-#: dispatch overhead dwarfs the math (SURVEY.md §7 hard part 3)
+#: a flushed group that carries fewer bytes than this is answered on the
+#: host — TPU/mesh dispatch overhead dwarfs the math (SURVEY.md §7 hard
+#: part 3)
 DEFAULT_MIN_BYTES = 32768
 
 
@@ -111,6 +116,9 @@ class EncodeService(LaunchBatcher):
                 for lo, hi in zip(offs, offs[1:])]
 
     _host_group = staticmethod(batcher.host_matmul_group)
+
+    def _group_bytes(self, group: list[Request]) -> int:
+        return sum(req.item.rows.size for req in group)
 
     def _bucket(self, total: int) -> int:
         """The fixed launch width holding ``total`` real columns."""

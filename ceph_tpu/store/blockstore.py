@@ -17,16 +17,25 @@ Mapping of BlueStore's moving parts:
   units, rebuilt at mount from the live blob set (the FreelistManager
   role); torn writes can only leak space, never corrupt — leaked blobs
   are reclaimed by the mount-time sweep (fsck-lite);
-- deferred small writes: payloads under ``inline_max`` are stored
-  INLINE in the kv (committed by the kv WAL — one durable write instead
-  of block write + fsync + kv commit), the same latency trade
-  BlueStore's deferred-write policy makes for small I/O;
+- deferred small writes: payloads up to ``INLINE_MAX`` are stored in
+  the kv (committed by the kv WAL — one durable write instead of block
+  write + fsync + kv commit), the same latency trade BlueStore's
+  deferred-write policy makes for small I/O: each is a kv value of its
+  own (a *piece*, family D), which an extent names like a blob;
 - big writes are COW: fresh extents are allocated, written and fsync'd
   BEFORE the kv batch commits the new extent map, so a crash leaves
   either the old object or the new one, never a tear;
+- an extent points INTO a blob (``blob_off``): overwriting part of a
+  blob edits the extent map and neither reads nor rewrites the edges
+  that survive; a blob's refcount counts the extents that name it and
+  its units return to the allocator when the last one goes;
 - clone: extent maps are copied and blob refcounts bumped (the
   SharedBlob role) — snapshots share unmodified data at rest;
-- checksums: one crc32c per blob, checked on read and by fsck.
+- checksums: one crc32c per ``CSUM_CHUNK`` (4 KiB: BlueStore's
+  csum_chunk_order 12) of a blob, kept under the blob's id in family K;
+  a read verifies the chunks it touches, fsck every extent.  When an
+  object's pieces pass ``PIECES_MAX`` they are folded, with the blobs
+  under them, into one new blob: the one write path that reads.
 
 Write ordering invariant: block-file data is durable before the kv
 batch that references it commits; the kv batch is the commit point.
@@ -34,11 +43,13 @@ batch that references it commits; the kv batch is the commit point.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import struct
 import threading
 import time
+from typing import NamedTuple
 
 from ceph_tpu.common.fault_injector import (
     InjectedError,
@@ -46,7 +57,7 @@ from ceph_tpu.common.fault_injector import (
     store_fault_check,
 )
 from ceph_tpu.kv import FileDB, MemDB, WriteBatch
-from ceph_tpu.native import crc32c
+from ceph_tpu.native import crc32c, crc32c_chunks
 from ceph_tpu.store.kstore import (
     _TxnView,
     _ckey,
@@ -65,6 +76,8 @@ from ceph_tpu.store.objectstore import (
 SEP = "\x01"
 MIN_ALLOC = 65536        # min_alloc_size: block allocation unit
 INLINE_MAX = 4096        # small writes stay in kv (deferred-write role)
+CSUM_CHUNK = 4096        # checksum granularity of a raw blob
+PIECES_MAX = 64          # kv pieces an object may hold before the fold
 
 
 class BlobError(OSError):
@@ -189,16 +202,60 @@ class _BitmapAllocator:
             1 for u in range(self.end_units) if not self._used(u))
 
 
+class _Blob(NamedTuple):
+    """A blob id, parsed.  ``kind``: ``"chunked"`` (raw, a crc per
+    CSUM_CHUNK in family K, ``length`` stored bytes), ``"kv"`` (a piece:
+    the bytes are the value under the id in family D, one crc),
+    ``"whole"`` (one crc over the stored bytes: compressed at rest, or
+    a raw blob an older store wrote)."""
+    unit: int
+    units: int
+    crc: int
+    alg: str
+    length: int      # stored bytes; 0 = the extent map knows (old raw)
+    kind: str
+
+
+class _Txn:
+    """What one transaction has done so far: the kv batch and its view,
+    every object meta it touched (written once, when it settles), the
+    blobs' refcount deltas (an overwrite removes the rollback clone and
+    makes the next: all but a few of those +1/-1 cancel), the blobs it
+    wrote, what to free after the commit, and the bytes it cost."""
+
+    def __init__(self, db):
+        self.batch = WriteBatch()
+        self.view = _TxnView(db, self.batch)
+        self.metas: dict[str, dict | None] = {}    # okey -> meta | removed
+        self.dirty: set[str] = set()               # the metas to write
+        self.refs: dict[str, int] = {}
+        self.created: set[str] = set()
+        self.freed: list[str] = []
+        self.block_bytes = 0
+        self.folds = 0
+
+    def ref(self, blob: str, by: int) -> None:
+        self.refs[blob] = self.refs.get(blob, 0) + by
+
+
 class BlockStore(ObjectStore):
     """ObjectStore over raw block space + a KeyValueDB (BlueStore role).
 
     kv column families: C collections, O object meta (size + extent
-    map), X xattrs, M omap, R blob refcounts.  Object meta value is
-    json: ``{"size": N, "extents": [[logical_off, blob_id, length], ...],
-    "inline": {"off": hex-bytes, ...}}``; blob id "unit:units:crc" or,
-    compressed at rest, "unit:units:crc:alg:stored_len" (crc over the
-    STORED bytes — verify before decompress, like BlueStore's
-    csum-then-decompress order).
+    map), X xattrs, M omap, R blob refcounts (one per extent that names
+    the blob), K a raw blob's chunk crcs (4 bytes a CSUM_CHUNK, packed),
+    D the small pieces.  Object meta value is json: ``{"size": N,
+    "extents": [[logical_off, blob_id, length, blob_off], ...]}``: the
+    extent is ``length`` bytes of the blob from ``blob_off`` on.  Blob
+    ids: "unit:units:fp:length" (raw; fp the crc of its K value, so that
+    units freed and written again never pass for the blob a stale reader
+    still names), "k<seq>:crc" (a piece in D), compressed at rest
+    "unit:units:crc:alg:stored_len" (crc over the STORED bytes — verify
+    before decompress, like BlueStore's csum-then-decompress order).
+    Read, and edited where a write lands on them, but no longer
+    written: three-field extents (``blob_off`` 0), "unit:units:crc" raw
+    blobs with one crc (an extent cut out of one carries the blob's
+    length as a fifth field), and ``"inline": {"off": hex-bytes}``.
 
     ``compression``: a compressor plugin name ("zlib", ...) enables
     transparent at-rest compression of non-inline blobs; a blob is
@@ -206,6 +263,13 @@ class BlockStore(ObjectStore):
     ``compression_required_ratio`` of the raw size (BlueStore's
     bluestore_compression_required_ratio gate).  ``allocator`` selects
     "first-fit" (extent list, Avl role) or "bitmap".
+
+    ``stats`` are running totals the store keeps itself:
+    ``block_write_bytes`` (blob data written to the block file),
+    ``kv_write_bytes`` (what the kv engine wrote for the commits: WAL
+    records, and its checkpoints and superblocks when they fall due),
+    ``read_disk_bytes`` (blob bytes read and checksummed for reads),
+    ``folds``.
     """
 
     def __init__(self, path: str, db=None, compression: str = "none",
@@ -243,6 +307,10 @@ class BlockStore(ObjectStore):
         self._alloc = (
             _BitmapAllocator() if allocator == "bitmap" else _Allocator())
         self._txn_lock = threading.Lock()
+        self._piece_seq = 0     # the last piece id handed out
+        self.stats = dict.fromkeys(
+            ("block_write_bytes", "kv_write_bytes", "read_disk_bytes",
+             "folds"), 0)
         self._compressor = None
         if compression and compression != "none":
             from ceph_tpu import compressor as _comp
@@ -286,16 +354,22 @@ class BlockStore(ObjectStore):
         it = self.db.get_iterator("O").seek_to_first()
         while it.valid():
             meta = json.loads(it.value())
-            for _lo, blob, _ln in meta.get("extents", []):
-                unit, units = _parse_blob(blob)[:2]
-                used.update(range(unit, unit + units))
-                end = max(end, unit + units)
+            for ext in meta.get("extents", []):
+                b = _parse_blob(ext[1])
+                if b.kind != "kv":
+                    used.update(range(b.unit, b.unit + b.units))
+                    end = max(end, b.unit + b.units)
             it.next()
         if bluefs:
             kv_units = self.db.used_units()
             used |= kv_units
             end = max(end, max(kv_units) + 1)
         self._alloc.init_from_used(used, end)
+        it = self.db.get_iterator("D").seek_to_first()
+        while it.valid():
+            self._piece_seq = max(
+                self._piece_seq, int(it.key()[1:].split(":")[0]))
+            it.next()
         if bluefs:
             # allocator live: the WAL may now grow and checkpoints run
             self.db.activate(self._alloc)
@@ -310,9 +384,9 @@ class BlockStore(ObjectStore):
             self._fd = None
 
     def fsck(self) -> list[dict]:
-        """Verify every blob's checksum at rest (BlueStore fsck role),
-        plus the co-located KV's own metadata (superblock generations +
-        WAL frames) when BlueFS hosts it."""
+        """Verify every extent's checksums at rest (BlueStore fsck
+        role), plus the co-located KV's own metadata (superblock
+        generations + WAL frames) when BlueFS hosts it."""
         bad: list[dict] = []
         db_fsck = getattr(self.db, "fsck", None)
         if callable(db_fsck):
@@ -320,21 +394,30 @@ class BlockStore(ObjectStore):
         it = self.db.get_iterator("O").seek_to_first()
         while it.valid():
             meta = json.loads(it.value())
-            for lo, blob, ln in meta.get("extents", []):
+            for ext in meta.get("extents", []):
                 try:
-                    self._read_blob(blob, ln)
+                    self._read_extent(ext, 0, ext[2], self.db.get, {})
                 except BlobError:
-                    bad.append({"okey": it.key(), "logical_off": lo,
-                                "blob": blob})
+                    bad.append({"okey": it.key(), "logical_off": ext[0],
+                                "blob": ext[1]})
             it.next()
         return bad
 
     # -- object meta ---------------------------------------------------
 
-    def _meta(self, c: coll_t, o: ghobject_t, view=None) -> dict | None:
-        get = view.get if view is not None else self.db.get
-        raw = get("O", _okey(c, o))
-        return None if raw is None else json.loads(raw)
+    def _meta(self, c: coll_t, o: ghobject_t, tx: _Txn | None = None
+              ) -> dict | None:
+        """The object's meta; with ``tx``, as that transaction has left
+        it so far (the dict is the transaction's own: edits to it are
+        written when it settles)."""
+        key = _okey(c, o)
+        if tx is not None and key in tx.metas:
+            return tx.metas[key]
+        raw = self.db.get("O", key)
+        meta = None if raw is None else json.loads(raw)
+        if tx is not None and meta is not None:
+            tx.metas[key] = meta
+        return meta
 
     def _require(self, c: coll_t, o: ghobject_t) -> dict:
         if not self.collection_exists(c):
@@ -356,42 +439,55 @@ class BlockStore(ObjectStore):
         xattrs are looked up, ``{}``), ``FileNotFoundError`` where
         ``exists`` would say no.  ``marks``, like a transaction's, is
         the caller's dict to stamp: ``copies``, the passes made over the
-        bytes after the ``pread`` (0: the verified blob itself)."""
+        bytes after the ``pread`` (0: the verified bytes themselves),
+        and ``disk_bytes``, what was read from the block file and
+        checksummed."""
         store_fault_check("read", self.fault_domain)
         if store_data_fault("read", self.fault_domain, peek=True):
             self._maybe_flip_bit(c, o)
+        if marks is None:
+            marks = {}
         # writers commit on a worker thread and may free+reuse a blob's
         # units between our meta load and the pread; a checksum failure
         # with a CHANGED meta is that benign race — reload and retry.
         # A failure with the SAME committed meta is genuine bit rot.
         last = None
-        for _ in range(3):
-            meta = self._require(c, o)
-            if meta == last:
-                break
-            try:
-                data = self._read_with_meta(c, o, meta, off, length, marks)
-            except BlobError:
-                last = meta
-                continue
-            return data, (self.db.get_prefix("X", _okey(c, o) + SEP)
-                          if attrs else {})
-        raise BlobError(5, f"checksum mismatch in {c}/{o}")
+        try:
+            for _ in range(3):
+                meta = self._require(c, o)
+                if meta == last:
+                    break
+                marks["disk_bytes"] = 0
+                try:
+                    data = self._read_with_meta(
+                        c, o, meta, off, length, marks)
+                except BlobError:
+                    last = meta
+                    continue
+                return data, (self.db.get_prefix("X", _okey(c, o) + SEP)
+                              if attrs else {})
+            raise BlobError(5, f"checksum mismatch in {c}/{o}")
+        finally:
+            self.stats["read_disk_bytes"] += marks.get("disk_bytes", 0)
 
     def _maybe_flip_bit(self, c, o) -> None:
         """Armed bitflip data fault: corrupt one stored byte of this
-        object's first blob AT REST, so the normal read path's
-        checksum-at-rest verification surfaces it as EIO (the
-        BlueStore bit-rot model).  Objects with no blob (inline-only,
-        absent) leave the fault armed for the next eligible read."""
+        object's first extent in the block file AT REST, so the normal
+        read path's checksum-at-rest verification surfaces it as EIO
+        (the BlueStore bit-rot model).  Objects with no blob (pieces
+        only, absent) leave the fault armed for the next eligible
+        read."""
         meta = self._meta(c, o)
-        if not meta or not meta.get("extents"):
+        at = next(((_parse_blob(e[1]), e[3] if len(e) > 3 else 0)
+                   for e in (meta or {}).get("extents", [])
+                   if e[1][0] != "k"), None)
+        if at is None:
             return
         spec = store_data_fault("read", self.fault_domain)
         if spec is None or not spec.get("bitflip"):
             return
-        unit = _parse_blob(meta["extents"][0][1])[0]
-        pos = unit * MIN_ALLOC
+        b, boff = at
+        pos = b.unit * MIN_ALLOC + (0 if b.alg else boff)
         byte = os.pread(self._fd, 1, pos)
         if byte:
             os.pwrite(self._fd, bytes([byte[0] ^ 0x40]), pos)
@@ -404,41 +500,41 @@ class BlockStore(ObjectStore):
         extents = meta.get("extents", [])
         if marks is None:
             marks = {}
-        # extents and inline pieces never overlap (_punch_hole clears a
-        # range before anything is written into it), so an extent that
-        # covers the whole range is all there is to it: every EC shard
-        # written by write_full is one blob.  The bytes object the crc
-        # was checked on is returned itself, or one slice of it.
-        for lo, blob, ln in extents:
-            if lo <= off and end <= lo + ln:
-                data = self._verified_blob(c, o, lo, blob, ln)
-                whole = end - off == ln
-                marks["copies"] = 0 if whole else 1
-                return data if whole else data[off - lo : end - lo]
-        # several extents, holes (zero-filled) or inline pieces: each
-        # byte is copied into the buffer and once more out of it
-        marks["copies"] = 2
-        out = bytearray(end - off)
-        for lo, blob, ln in extents:
-            s, e = max(off, lo), min(end, lo + ln)
-            if s < e:
-                data = memoryview(self._verified_blob(c, o, lo, blob, ln))
-                out[s - off : e - off] = data[s - lo : e - lo]
-        for hoff, hexdata in meta.get("inline", {}).items():
-            lo = int(hoff)
-            data = memoryview(bytes.fromhex(hexdata))
-            s, e = max(off, lo), min(end, lo + len(data))
-            if s < e:
-                out[s - off : e - off] = data[s - lo : e - lo]
-        return bytes(out)
-
-    def _verified_blob(self, c, o, lo, blob, ln) -> bytes:
+        get = self.db.get
         try:
-            return self._read_blob(blob, ln)
+            # extents never overlap (_punch_hole clears a range before
+            # anything is written into it), so an extent that covers
+            # the whole range is all there is to it: every EC shard
+            # written by write_full is one blob, and a sub-read of one
+            # stripe unit lies in one extent.  The bytes object the
+            # crcs were checked on is returned itself, or one slice.
+            for ext in extents:
+                lo = ext[0]
+                if lo <= off and end <= lo + ext[2]:
+                    return self._read_extent(
+                        ext, off - lo, end - lo, get, marks)
+            # several extents, holes (zero-filled) or old inline
+            # pieces: each byte is copied into the buffer and once
+            # more out of it
+            out = bytearray(end - off)
+            for ext in extents:
+                lo = ext[0]
+                s, e = max(off, lo), min(end, lo + ext[2])
+                if s < e:
+                    out[s - off : e - off] = self._read_extent(
+                        ext, s - lo, e - lo, get, marks)
+            for hoff, hexdata in meta.get("inline", {}).items():
+                lo = int(hoff)
+                data = memoryview(bytes.fromhex(hexdata))
+                s, e = max(off, lo), min(end, lo + len(data))
+                if s < e:
+                    out[s - off : e - off] = data[s - lo : e - lo]
+            marks["copies"] = 2
+            return bytes(out)
         except BlobError:
             # checksum-at-rest violation (or a benign stale-meta race
             # the caller's retry loop disambiguates)
-            raise BlobError(5, f"checksum mismatch in {c}/{o} @ {lo}")
+            raise BlobError(5, f"checksum mismatch in {c}/{o}")
 
     def stat(self, c, o):
         return self._require(c, o)["size"]
@@ -504,14 +600,12 @@ class BlockStore(ObjectStore):
             marks["locked"] = time.monotonic()
             validate_transaction(self, txn)
             marks["validated"] = time.monotonic()
-            batch = WriteBatch()
-            view = _TxnView(self.db, batch)
-            freed: list[str] = []     # blobs to free AFTER commit
-            wrote_block = False
+            tx = _Txn(self.db)
             for op in txn.ops:
-                wrote_block |= self._translate(op, view, freed)
+                self._translate(op, tx)
+            self._settle(tx)
             marks["data"] = time.monotonic()    # pwrite + crc done
-            if wrote_block:
+            if tx.block_bytes:
                 # ordering invariant: blob data durable BEFORE the kv
                 # commit that references it
                 os.fsync(self._fd)
@@ -526,18 +620,63 @@ class BlockStore(ObjectStore):
                 raise InjectedError(
                     5, "injected torn write (kv commit dropped)")
             store_fault_check("commit", self.fault_domain)
-            self.db.submit(batch)
+            kv_before = getattr(self.db, "bytes_written", 0)
+            self.db.submit(tx.batch)
             marks["kv"] = time.monotonic()
-            for blob in freed:
-                self._deref_blob(blob)
+            # what the commit cost the medium, for the submitter's span
+            # and counters and for the store's own totals
+            marks["block_bytes"] = tx.block_bytes
+            marks["kv_bytes"] = getattr(
+                self.db, "bytes_written", 0) - kv_before
+            marks["folded"] = tx.folds
+            self.stats["block_write_bytes"] += marks["block_bytes"]
+            self.stats["kv_write_bytes"] += marks["kv_bytes"]
+            self.stats["folds"] += tx.folds
+            for blob in tx.freed:
+                b = _parse_blob(blob)
+                self._alloc.free(b.unit, b.units)
         for cb in txn.on_applied:
             cb()
         for cb in txn.on_commit:
             cb()
 
+    def _settle(self, tx: _Txn) -> None:
+        """Every meta the transaction touched goes into the batch once,
+        and every blob whose count of extents changed gets its new
+        refcount; a blob no extent names any more loses its kv entries
+        and (after the commit) its units."""
+        for key in tx.dirty:
+            meta = tx.metas[key]
+            if meta is None:
+                tx.view.rmkey("O", key)
+            else:
+                tx.view.set("O", key, json.dumps(
+                    meta, separators=(",", ":")).encode())
+        for blob, by in tx.refs.items():
+            new = blob in tx.created
+            if not by and not new:
+                continue
+            raw = tx.view.get("R", blob)
+            refs = (struct.unpack("<I", raw)[0] if raw
+                    else 0 if new else 1) + by
+            if refs > 0:
+                tx.view.set("R", blob, struct.pack("<I", refs))
+                continue
+            kind = _parse_blob(blob).kind
+            if raw:
+                tx.view.rmkey("R", blob)
+            if kind == "kv":
+                tx.view.rmkey("D", blob)
+            else:
+                if kind == "chunked":
+                    tx.view.rmkey("K", blob)
+                tx.freed.append(blob)
+
     # blob helpers ------------------------------------------------------
 
-    def _write_blob(self, data: bytes) -> str:
+    def _write_blob(self, tx: _Txn, data) -> str:
+        """``data`` as one new blob in the block file; its id.  The
+        caller adds the extent, and the reference, that name it."""
         stored = data
         tag = ""
         if self._compressor is not None and len(data) > INLINE_MAX:
@@ -548,258 +687,252 @@ class BlockStore(ObjectStore):
         units = max(1, -(-len(stored) // MIN_ALLOC))
         unit = self._alloc.alloc(units)
         os.pwrite(self._fd, stored, unit * MIN_ALLOC)
-        return f"{unit}:{units}:{crc32c(stored)}{tag}"
-
-    def _read_blob(self, blob: str, ln: int) -> bytes:
-        """pread + crc-verify (+ decompress) one blob; ``ln`` is the
-        logical (uncompressed) length the extent map records."""
-        unit, _units, crc, alg, stored_len = _parse_blob(blob)
-        data = os.pread(self._fd, stored_len if alg else ln,
-                        unit * MIN_ALLOC)
-        if crc32c(data) != crc:
-            raise BlobError(5, f"checksum mismatch in blob {blob}")
-        if alg:
-            if self._compressor is not None and alg == self._comp_alg:
-                data = self._compressor.decompress(data)
-            else:  # legacy blob from a differently-configured mount
-                from ceph_tpu import compressor as _comp
-
-                data = _comp.create(alg).decompress(data)
-        return data
-
-    def _bump_blob(self, view: _TxnView, blob: str, by: int = 1) -> None:
-        raw = view.get("R", blob)
-        refs = (struct.unpack("<I", raw)[0] if raw else 0) + by
-        view.set("R", blob, struct.pack("<I", refs))
-
-    def _deref_blob_in_view(self, view: _TxnView, blob: str,
-                            freed: list[str]) -> None:
-        raw = view.get("R", blob)
-        refs = struct.unpack("<I", raw)[0] if raw else 1
-        if refs <= 1:
-            view.rmkey("R", blob)
-            freed.append(blob)
+        tx.block_bytes += len(stored)
+        if tag:
+            blob = f"{unit}:{units}:{crc32c(stored)}{tag}"
         else:
-            view.set("R", blob, struct.pack("<I", refs - 1))
+            crcs = crc32c_chunks(stored, CSUM_CHUNK)
+            blob = f"{unit}:{units}:{crc32c(crcs)}:{len(stored)}"
+            tx.view.set("K", blob, crcs)
+        tx.created.add(blob)
+        return blob
 
-    def _deref_blob(self, blob: str) -> None:
-        unit, units = _parse_blob(blob)[:2]
-        self._alloc.free(unit, units)
+    def _write_piece(self, tx: _Txn, data) -> str:
+        """``data`` (at most INLINE_MAX bytes) as a kv value of its
+        own; its id."""
+        self._piece_seq += 1
+        blob = f"k{self._piece_seq}:{crc32c(data)}"
+        tx.view.set("D", blob, data)
+        tx.created.add(blob)
+        return blob
+
+    def _read_extent(self, ext, s: int, e: int, get, marks: dict):
+        """Bytes [s, e) of the extent, verified: the chunks of a raw
+        blob that the range touches and no others (a piece, a compressed
+        blob and an old one-crc blob are verified whole).  ``get`` reads
+        the kv (the db's, or a transaction's view)."""
+        blob = ext[1]
+        b = _parse_blob(blob)
+        boff = ext[3] if len(ext) > 3 else 0
+        a, z = boff + s, boff + e       # within the blob's content
+        if b.kind == "chunked":
+            ca = a - a % CSUM_CHUNK
+            cz = min(b.length, z + -z % CSUM_CHUNK)
+            crcs = get("K", blob)
+            data = os.pread(self._fd, cz - ca, b.unit * MIN_ALLOC + ca)
+            marks["disk_bytes"] = marks.get("disk_bytes", 0) + len(data)
+            if crcs is None or len(data) != cz - ca or crc32c_chunks(
+                    data, CSUM_CHUNK) != crcs[
+                        ca // CSUM_CHUNK * 4 : -(-cz // CSUM_CHUNK) * 4]:
+                raise BlobError(5, f"checksum mismatch in blob {blob}")
+            a, z = a - ca, z - ca
+        elif b.kind == "kv":
+            data = get("D", blob)
+            if data is None or crc32c(data) != b.crc:
+                raise BlobError(5, f"checksum mismatch in piece {blob}")
+        else:
+            stored = b.length or (ext[4] if len(ext) > 4 else ext[2])
+            data = os.pread(self._fd, stored, b.unit * MIN_ALLOC)
+            marks["disk_bytes"] = marks.get("disk_bytes", 0) + len(data)
+            if crc32c(data) != b.crc:
+                raise BlobError(5, f"checksum mismatch in blob {blob}")
+            if b.alg:
+                if self._compressor is not None and b.alg == self._comp_alg:
+                    data = self._compressor.decompress(data)
+                else:  # legacy blob from a differently-configured mount
+                    from ceph_tpu import compressor as _comp
+
+                    data = _comp.create(b.alg).decompress(data)
+        whole = a == 0 and z == len(data)
+        marks["copies"] = 0 if whole else 1
+        return data if whole else data[a:z]
 
     # translation -------------------------------------------------------
 
-    def _translate(self, op, view: _TxnView, freed: list[str]) -> bool:
-        """Apply one TxOp into the view; returns True when block data
-        was written (the caller fsyncs once before commit)."""
+    def _translate(self, op, tx: _Txn) -> None:
+        """Apply one TxOp into the transaction."""
         kind = op[0]
-        wrote = False
         if kind == TxOp.MKCOLL:
-            view.set("C", _ckey(op[1]), b"1")
+            tx.view.set("C", _ckey(op[1]), b"1")
         elif kind == TxOp.RMCOLL:
-            view.rmkey("C", _ckey(op[1]))
+            tx.view.rmkey("C", _ckey(op[1]))
         elif kind == TxOp.TOUCH:
             _, c, o = op
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, _new_meta())
+            self._touch(tx, c, o)
         elif kind == TxOp.WRITE:
             _, c, o, off, data = op
-            meta = self._meta(c, o, view) or _new_meta()
-            wrote = self._write_range(view, c, o, meta, off, data, freed)
+            self._write_range(tx, self._touch(tx, c, o, True), off, data)
         elif kind == TxOp.ZERO:
             # zeros need no storage: punch the range out of the extent
             # map — read() zero-fills gaps (BlueStore punch-hole zeroing)
             _, c, o, off, length = op
-            meta = self._meta(c, o, view) or _new_meta()
-            wrote = self._punch_hole(view, meta, off, off + length, freed)
+            meta = self._touch(tx, c, o, True)
+            self._punch_hole(tx, meta, off, off + length)
             meta["size"] = max(meta.get("size", 0), off + length)
-            self._put_meta(view, c, o, meta)
         elif kind == TxOp.TRUNCATE:
             _, c, o, size = op
-            meta = self._meta(c, o, view) or _new_meta()
-            wrote = self._truncate(view, c, o, meta, size, freed)
+            meta = self._touch(tx, c, o, True)
+            if size < meta.get("size", 0):
+                self._punch_hole(tx, meta, size, meta["size"])
+            meta["size"] = size
         elif kind == TxOp.REMOVE:
             _, c, o = op
-            self._rm_object(view, c, o, freed)
+            self._rm_object(tx, c, o)
         elif kind == TxOp.SETATTRS:
             _, c, o, attrs = op
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, _new_meta())
+            self._touch(tx, c, o)
             for k, v in attrs.items():
-                view.set("X", _okey(c, o) + SEP + k, v)
+                tx.view.set("X", _okey(c, o) + SEP + k, v)
         elif kind == TxOp.RMATTR:
             _, c, o, name = op
-            view.rmkey("X", _okey(c, o) + SEP + name)
+            tx.view.rmkey("X", _okey(c, o) + SEP + name)
         elif kind == TxOp.OMAP_SETKEYS:
             _, c, o, kv = op
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, _new_meta())
+            self._touch(tx, c, o)
             for k, v in kv.items():
-                view.set("M", _okey(c, o) + SEP + k, v)
+                tx.view.set("M", _okey(c, o) + SEP + k, v)
         elif kind == TxOp.OMAP_RMKEYS:
             _, c, o, keys = op
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, _new_meta())
+            self._touch(tx, c, o)
             for k in keys:
-                view.rmkey("M", _okey(c, o) + SEP + k)
+                tx.view.rmkey("M", _okey(c, o) + SEP + k)
         elif kind == TxOp.OMAP_CLEAR:
             _, c, o = op
             base = _okey(c, o) + SEP
-            view.rm_range("M", base, _prefix_end(base))
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, _new_meta())
+            tx.view.rm_range("M", base, _prefix_end(base))
+            self._touch(tx, c, o)
         elif kind == TxOp.CLONE:
             _, c, src, dst = op
-            wrote = self._clone(view, c, src, c, dst)
+            self._clone(tx, c, src, c, dst)
         elif kind == TxOp.COLL_MOVE_RENAME:
             _, src_c, src_o, dst_c, dst_o = op
-            wrote = self._clone(view, src_c, src_o, dst_c, dst_o)
-            self._rm_object(view, src_c, src_o, freed)
+            self._clone(tx, src_c, src_o, dst_c, dst_o)
+            self._rm_object(tx, src_c, src_o)
         else:  # pragma: no cover
             raise ValueError(f"unknown op {kind}")
-        return wrote
 
-    def _put_meta(self, view, c, o, meta: dict) -> None:
-        view.set("O", _okey(c, o), json.dumps(meta).encode())
+    def _touch(self, tx: _Txn, c, o, dirty: bool = False) -> dict:
+        """The object's meta in ``tx``, made if the object is new;
+        ``dirty``: the caller is about to edit it."""
+        meta = self._meta(c, o, tx)
+        if meta is None:
+            meta = tx.metas[_okey(c, o)] = _new_meta()
+            dirty = True
+        if dirty:
+            tx.dirty.add(_okey(c, o))
+        return meta
 
-    def _write_range(self, view, c, o, meta, off, data, freed) -> bool:
-        """COW write: large payloads get fresh blobs; small ones stay
-        inline in kv (the deferred-write/small-blob policy)."""
+    def _write_range(self, tx: _Txn, meta, off, data) -> None:
+        """COW write: large payloads get fresh blobs; small ones are
+        pieces in the kv (the deferred-write/small-blob policy)."""
         if not data:
-            if self._meta(c, o, view) is None:
-                self._put_meta(view, c, o, meta)
-            return False
+            return
         end = off + len(data)
-        # drop the overwritten range from existing state (edge blobs
-        # written there count as block writes for the fsync ordering)
-        wrote = self._punch_hole(view, meta, off, end, freed)
-        if len(data) <= INLINE_MAX:
-            meta.setdefault("inline", {})[str(off)] = data.hex()
-            if len(meta["inline"]) > 64:
-                # deferred-write flush: many small writes consolidate
-                # into one blob so the meta value stays bounded
-                wrote |= self._compact(view, meta, freed)
-        else:
-            blob = self._write_blob(data)
-            self._bump_blob(view, blob)
-            meta.setdefault("extents", []).append([off, blob, len(data)])
-            meta["extents"].sort()
-            wrote = True
+        self._punch_hole(tx, meta, off, end)
+        small = len(data) <= INLINE_MAX
+        blob = self._write_piece(tx, data) if small \
+            else self._write_blob(tx, data)
+        tx.ref(blob, +1)
+        extents = meta["extents"]
+        extents.append([off, blob, len(data), 0])
+        extents.sort()
         meta["size"] = max(meta.get("size", 0), end)
-        self._put_meta(view, c, o, meta)
-        return wrote
+        if small and sum(e[1][0] == "k" for e in extents) > PIECES_MAX:
+            # deferred-write flush: many small writes consolidate into
+            # one blob, so the extent map stays bounded
+            self._fold(tx, meta)
 
-    def _punch_hole(self, view, meta, lo, hi, freed) -> bool:
-        """Remove [lo, hi) from the extent map and inline set, keeping
-        non-overlapped blob sub-ranges; returns True when edge blobs
-        were written to the block file (caller must fsync before the
-        kv commit — the durability-ordering invariant)."""
-        wrote = False
-        new_extents = []
-        for elo, blob, ln in meta.get("extents", []):
+    def _punch_hole(self, tx: _Txn, meta, lo, hi) -> None:
+        """Remove [lo, hi) from the extent map.  What survives of an
+        extent the range cuts keeps pointing into the same blob: the
+        map alone is edited, nothing is read and nothing rewritten (so
+        overwriting, e.g. pg repair force-pushing a reconstructed
+        object, can replace a blob whose checksum no longer
+        verifies)."""
+        self._adopt_inline(tx, meta)
+        kept = []
+        for ext in meta.get("extents", []):
+            elo, blob, ln = ext[:3]
             ehi = elo + ln
             if ehi <= lo or elo >= hi:
-                new_extents.append([elo, blob, ln])
+                kept.append(ext)
                 continue
-            # overlapped: re-read SURVIVING edges into inline/new blobs;
-            # a fully-covered blob is never read, so overwriting (e.g.
-            # pg repair force-pushing a reconstructed object) can
-            # replace a blob whose checksum no longer verifies
-            edges = [
-                (s, e) for s, e in ((elo, min(lo, ehi)), (max(hi, elo), ehi))
-                if s < e
-            ]
-            if edges:
-                data = self._read_blob(blob, ln)
-                for s, e in edges:
-                    part = data[s - elo : e - elo]
-                    if len(part) <= INLINE_MAX:
-                        meta.setdefault("inline", {})[str(s)] = part.hex()
-                    else:
-                        nb = self._write_blob(part)
-                        wrote = True
-                        self._bump_blob(view, nb)
-                        new_extents.append([s, nb, len(part)])
-            self._deref_blob_in_view(view, blob, freed)
-        new_extents.sort()
-        meta["extents"] = new_extents
-        inline = meta.get("inline", {})
-        new_inline = {}
-        for hoff, hexdata in inline.items():
-            s = int(hoff)
-            part = bytes.fromhex(hexdata)
-            e = s + len(part)
-            if e <= lo or s >= hi:
-                new_inline[hoff] = hexdata
-                continue
-            if s < lo:
-                new_inline[str(s)] = part[: lo - s].hex()
-            if e > hi:
-                new_inline[str(hi)] = part[hi - s:].hex()
-        meta["inline"] = new_inline
-        return wrote
+            boff = ext[3] if len(ext) > 3 else 0
+            # an old raw blob's one crc covers its whole length, which
+            # only its first, uncut extent says: hand it on
+            b = _parse_blob(blob)
+            blen = ext[4:] or (
+                [ln] if b.kind == "whole" and not b.length else [])
+            if elo < lo:
+                kept.append([elo, blob, lo - elo, boff, *blen])
+                tx.ref(blob, +1)
+            if ehi > hi:
+                kept.append([hi, blob, ehi - hi, boff + hi - elo, *blen])
+                tx.ref(blob, +1)
+            tx.ref(blob, -1)
+        kept.sort()
+        meta["extents"] = kept
 
-    def _compact(self, view, meta, freed) -> bool:
+    def _adopt_inline(self, tx: _Txn, meta) -> None:
+        """An older store's inline pieces (hex in the meta value)
+        become pieces of their own the first time the object changes."""
+        for hoff, hexdata in meta.pop("inline", {}).items():
+            data = bytes.fromhex(hexdata)
+            blob = self._write_piece(tx, data)
+            tx.ref(blob, +1)
+            meta["extents"].append([int(hoff), blob, len(data), 0])
+        meta["extents"].sort()
+
+    def _fold(self, tx: _Txn, meta) -> None:
         """Rewrite the object's content as one blob (the deferred
-        small-write flush).  Caller holds the txn lock."""
-        # the span covers everything recorded so far — the caller may
-        # not have folded the current write into meta["size"] yet
-        size = meta.get("size", 0)
-        for lo, _blob, ln in meta.get("extents", []):
-            size = max(size, lo + ln)
-        for hoff, hexdata in meta.get("inline", {}).items():
-            size = max(size, int(hoff) + len(hexdata) // 2)
+        small-write flush): the one write that reads what it replaces.
+        Caller holds the txn lock."""
+        self._adopt_inline(tx, meta)
+        size = max([meta.get("size", 0)]
+                   + [e[0] + e[2] for e in meta["extents"]])
         if size == 0:
-            return False
+            return
         buf = bytearray(size)
-        for lo, blob, ln in meta.get("extents", []):
-            data = self._read_blob(blob, ln)
-            buf[lo : lo + ln] = data
-            self._deref_blob_in_view(view, blob, freed)
-        for hoff, hexdata in meta.get("inline", {}).items():
-            part = bytes.fromhex(hexdata)
-            lo = int(hoff)
-            buf[lo : lo + len(part)] = part
-        nb = self._write_blob(bytes(buf))
-        self._bump_blob(view, nb)
-        meta["extents"] = [[0, nb, size]]
-        meta["inline"] = {}
-        return True
+        for ext in meta["extents"]:
+            buf[ext[0] : ext[0] + ext[2]] = self._read_extent(
+                ext, 0, ext[2], tx.view.get, {})
+            tx.ref(ext[1], -1)
+        nb = self._write_blob(tx, bytes(buf))
+        tx.ref(nb, +1)
+        meta["extents"] = [[0, nb, size, 0]]
+        tx.folds += 1
 
-    def _truncate(self, view, c, o, meta, size, freed) -> bool:
-        cur = meta.get("size", 0)
-        wrote = False
-        if size < cur:
-            wrote = self._punch_hole(view, meta, size, cur, freed)
-        meta["size"] = size
-        self._put_meta(view, c, o, meta)
-        return wrote
-
-    def _rm_object(self, view, c, o, freed) -> None:
-        meta = self._meta(c, o, view)
+    def _rm_object(self, tx: _Txn, c, o) -> None:
+        meta = self._meta(c, o, tx)
         if meta:
-            for _lo, blob, _ln in meta.get("extents", []):
-                self._deref_blob_in_view(view, blob, freed)
-        view.rmkey("O", _okey(c, o))
+            for ext in meta.get("extents", []):
+                tx.ref(ext[1], -1)
+        tx.metas[_okey(c, o)] = None
+        tx.dirty.add(_okey(c, o))
         base = _okey(c, o) + SEP
         for prefix in ("X", "M"):
-            view.rm_range(prefix, base, _prefix_end(base))
+            tx.view.rm_range(prefix, base, _prefix_end(base))
 
-    def _clone(self, view, src_c, src_o, dst_c, dst_o) -> bool:
+    def _clone(self, tx: _Txn, src_c, src_o, dst_c, dst_o) -> None:
         """Share blobs with the destination (the SharedBlob role):
         refcounts bump, no data moves."""
-        meta = self._meta(src_c, src_o, view)
+        meta = self._meta(src_c, src_o, tx)
         if meta is None:
             meta = _new_meta()
+        old = self._meta(dst_c, dst_o, tx)
+        if old:     # an overwritten destination lets go of its blobs
+            for ext in old.get("extents", []):
+                tx.ref(ext[1], -1)
         dst = json.loads(json.dumps(meta))  # deep copy
-        for _lo, blob, _ln in dst.get("extents", []):
-            self._bump_blob(view, blob)
-        self._put_meta(view, dst_c, dst_o, dst)
+        for ext in dst.get("extents", []):
+            tx.ref(ext[1], +1)
+        tx.metas[_okey(dst_c, dst_o)] = dst
+        tx.dirty.add(_okey(dst_c, dst_o))
         sbase = _okey(src_c, src_o) + SEP
         dbase = _okey(dst_c, dst_o) + SEP
         for prefix in ("X", "M"):
-            for key, val in view.items(prefix, sbase):
-                view.set(prefix, dbase + key[len(sbase):], val)
-        return False
+            for key, val in tx.view.items(prefix, sbase):
+                tx.view.set(prefix, dbase + key[len(sbase):], val)
 
 
 def validate_transaction(store: ObjectStore, txn: Transaction) -> None:
@@ -881,15 +1014,18 @@ def validate_transaction(store: ObjectStore, txn: Transaction) -> None:
 
 
 def _new_meta() -> dict:
-    return {"size": 0, "extents": [], "inline": {}}
+    return {"size": 0, "extents": []}
 
 
-def _parse_blob(blob: str) -> tuple[int, int, int, str, int]:
-    """(unit, units, crc, alg, stored_len); alg == "" for raw blobs
-    (3-field legacy ids stay readable — stored_len falls back to the
-    extent's logical length at the read site)."""
+@functools.lru_cache(maxsize=4096)
+def _parse_blob(blob: str) -> _Blob:
+    """A blob id's fields (the ids: :class:`BlockStore`)."""
     parts = blob.split(":")
+    if blob[0] == "k":
+        return _Blob(0, 0, int(parts[1]), "", 0, "kv")
     unit, units, crc = int(parts[0]), int(parts[1]), int(parts[2])
+    if len(parts) == 4:
+        return _Blob(unit, units, crc, "", int(parts[3]), "chunked")
     if len(parts) == 5:
-        return unit, units, crc, parts[3], int(parts[4])
-    return unit, units, crc, "", 0
+        return _Blob(unit, units, crc, parts[3], int(parts[4]), "whole")
+    return _Blob(unit, units, crc, "", 0, "whole")

@@ -66,6 +66,10 @@ class BlueFSLite(MemDB):
         self.wal_seq = 1            # seq of the wal chain's FIRST record
         self._next_seq = 1
         self._wal_pos = 0           # append offset within the chain
+        #: every byte written to the device since the start: WAL
+        #: records, checkpoints, superblocks (BlockStore reads the
+        #: growth around a submit)
+        self.bytes_written = 0
 
     # -- wiring (called by BlockStore) ---------------------------------
 
@@ -99,7 +103,9 @@ class BlueFSLite(MemDB):
         rec = struct.pack("<II", crc32c(blob), len(blob)) + blob
         assert len(rec) <= MIN_ALLOC, "superblock overflow"
         slot = SUPER_UNITS[self.gen % 2]
-        os.pwrite(self._fd, rec.ljust(MIN_ALLOC, b"\0"), slot * MIN_ALLOC)
+        # the record alone: its length and crc say where it ends, so the
+        # slot's other 60-odd KiB need not be written on every flip
+        self.bytes_written += os.pwrite(self._fd, rec, slot * MIN_ALLOC)
         os.fsync(self._fd)
 
     def _read_super(self) -> dict | None:
@@ -133,8 +139,9 @@ class BlueFSLite(MemDB):
             lo = max(pos, off)
             hi = min(pos + len(data), off + span)
             if lo < hi:
-                os.pwrite(self._fd, data[lo - pos:hi - pos],
-                          unit * MIN_ALLOC + (lo - off))
+                self.bytes_written += os.pwrite(
+                    self._fd, data[lo - pos:hi - pos],
+                    unit * MIN_ALLOC + (lo - off))
             off += span
         if pos + len(data) > off:
             raise IOError("write past extent chain")

@@ -295,6 +295,11 @@ class SmokeCluster:
                 await asyncio.wait_for(
                     asyncio.gather(*tasks),
                     max(deadline - time.monotonic(), 0.001))
+                # a gather of tasks that are all done completes without
+                # a yield, while the done callbacks that take them out
+                # of _warm_tasks are still queued: let those run, or
+                # this loop never leaves the event loop's one step
+                await asyncio.sleep(0)
             elif all(POOL in o._warmed_profiles for o in self.live_osds()):
                 break
             else:

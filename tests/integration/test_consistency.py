@@ -359,6 +359,16 @@ class TestKillBackfillerMidTransfer:
                 # per-run counter baseline: the registry is
                 # process-global and survives daemon restarts
                 pcs = get_perf_counters(f"osd.{primary}")
+                # the down-map may have started a pass of its own (a
+                # prior interval takes the backfill path too, and paces
+                # itself by osd_recovery_sleep): a baseline taken while
+                # it is in flight would hide the pass the revival starts
+                for _ in range(300):
+                    d = pcs.dump()
+                    if d.get("backfill_started", 0.0) == \
+                            d.get("backfill_completed", 0.0):
+                        break
+                    await asyncio.sleep(0.02)
                 base_s = pcs.dump().get("backfill_started", 0.0)
                 base_c = pcs.dump().get("backfill_completed", 0.0)
                 await revive(c, victim, vstore)

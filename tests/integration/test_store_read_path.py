@@ -21,7 +21,7 @@ from .test_disk_faults import _blockstore_factory
 from .test_mini_cluster import Cluster, run
 
 BIG = bytes(range(256)) * 128      # 32 KiB: shards of 16 KiB, one blob each
-TINY = b"lives in its kv meta"     # a shard of one 4 KiB stripe unit: inline
+TINY = b"lives in the kv"     # a shard of one 4 KiB stripe unit: a piece
 STORES = ["blockstore", "memstore"]
 
 
@@ -79,7 +79,7 @@ class TestAServedRead:
                 await io.write_full("big", BIG)
                 await io.write_full("tiny", TINY)
                 loop_thread = threading.get_ident()
-                blobs = _record_threads(monkeypatch, BlockStore, "_read_blob")
+                blobs = _record_threads(monkeypatch, BlockStore, "_read_extent")
                 before = _counts(c)
                 assert await io.read("big") == BIG
                 # k = 2 shards of 16 KiB, each one pread, on the loop
@@ -127,11 +127,21 @@ class TestAServedRead:
                         and s["trace_id"] == sp.trace_id]
                 assert [s["tags"]["copies"] for s in runs] == [2]
 
-                # an inline-only object: no pread at all
-                del blobs[:]
-                before = _counts(c)
+                # an object of pieces alone: kv values, no pread at all
+                # (the stores' own totals; the perf collections, which
+                # outlive a test's daemons, grow by the same)
+                def disk():
+                    return (sum(o.store.stats["read_disk_bytes"]
+                                for o in c.osds),
+                            sum(o.perf.dump().get("store_read_disk_bytes", 0)
+                                for o in c.osds))
+
+                before, disk_before = _counts(c), disk()
                 assert await io.read("tiny") == TINY
-                assert blobs == [] and _grew(c, before)[0] == 2
+                assert _grew(c, before)[0] == 2 and disk() == disk_before
+                assert await io.read("big") == BIG
+                grew = [now - was for now, was in zip(disk(), disk_before)]
+                assert grew == [len(BIG)] * 2
 
         run(go())
 
@@ -143,7 +153,7 @@ class TestAServedRead:
                 await c.client.pool_create("rp", pg_num=4, size=2)
                 io = c.client.ioctx("rp")
                 await io.write_full("big", BIG)
-                blobs = _record_threads(monkeypatch, BlockStore, "_read_blob")
+                blobs = _record_threads(monkeypatch, BlockStore, "_read_extent")
                 before = _counts(c)
                 assert await io.read("big") == BIG
                 assert await io.read("big", 100, 1000) == BIG[100:1100]

@@ -67,8 +67,11 @@ def test_cell_resolves_to_configuration_traffic_reference_and_readers(
                            entry["traffic"] + ".json")) as f:
         traffic = json.load(f)
     assert traffic["object_bytes"] > 0 and traffic["in_flight"] > 0
-    assert (traffic["loop"] or {"op": "write_full"})["op"] in (
-        "write_full", "read")
+    loop = traffic["loop"] or {"op": "write_full"}
+    assert loop["op"] in ("write_full", "read", "write")
+    if loop["op"] == "write":   # a block device's writes: whole blocks
+        assert traffic["prefill_objects"] > 0 and loop["offsets"] == "uniform"
+        assert traffic["object_bytes"] % loop["io_bytes"] == 0
     if traffic.get("counter"):      # the cell's end-to-end metric
         assert traffic["counter_metric"] in {
             m["name"] for m in _metrics_of(cell, "end_to_end")}

@@ -80,8 +80,12 @@ class TestBlockStoreSpecifics:
     def test_small_writes_stay_inline(self, store):
         store.queue_transaction(Transaction().write(C, O1, 0, b"tiny"))
         meta = json.loads(store.db.get("O", _okey_of(store, C, O1)))
-        assert meta["extents"] == []
-        assert meta["inline"]
+        # a piece: a kv value of its own that the extent names, not a
+        # blob in the block file and not bytes in the meta value
+        [[off, piece, ln, boff]] = meta["extents"]
+        assert (off, ln, boff) == (0, 4, 0) and piece.startswith("k")
+        assert store.db.get("D", piece) == b"tiny" and "inline" not in meta
+        assert store.stats["block_write_bytes"] == 0
         assert store.read(C, O1) == b"tiny"
 
     def test_allocator_reuses_freed_space(self, store):
@@ -155,7 +159,9 @@ class TestDurabilityOrdering:
             store.queue_transaction(
                 Transaction().write(C, O1, i * 1000, bytes([i]) * 1000))
         meta = json.loads(store.db.get("O", _okey_of(store, C, O1)))
-        assert len(meta["inline"]) <= 65, "inline set unbounded"
+        pieces = [e for e in meta["extents"] if e[1].startswith("k")]
+        assert len(pieces) <= 64, "pieces unbounded"
+        assert store.stats["folds"] == 1
         want = b"".join(bytes([i]) * 1000 for i in range(100))
         assert store.read(C, O1) == want
         assert store.fsck() == []
@@ -196,7 +202,7 @@ class TestCompressionAtRest:
         zstore.queue_transaction(Transaction().write(C, O1, 0, data))
         meta = zstore._require(C, O1)
         blob = meta["extents"][0][1]
-        assert len(blob.split(":")) == 3  # ratio gate kept it raw
+        assert len(blob.split(":")) == 4  # ratio gate kept it raw
         assert zstore.read(C, O1) == data
 
     def test_bit_rot_in_compressed_blob_is_detected(self, zstore):
@@ -235,15 +241,15 @@ def _count_preads(monkeypatch):
 
 
 def _keep_blobs(store, monkeypatch):
-    """The objects ``_read_blob`` hands back, in order."""
+    """The objects ``_read_extent`` hands back, in order."""
     blobs = []
-    real = store._read_blob
+    real = store._read_extent
 
-    def read_blob(blob, ln):
-        blobs.append(real(blob, ln))
+    def read_extent(*a):
+        blobs.append(real(*a))
         return blobs[-1]
 
-    monkeypatch.setattr(store, "_read_blob", read_blob)
+    monkeypatch.setattr(store, "_read_extent", read_extent)
     return blobs
 
 
@@ -279,7 +285,8 @@ class TestAReadTouchesItsBytesOnce:
         got, attrs = store.read_object(C, O1, marks=marks)
         assert preads == [len(data)] and len(blobs) == 1
         assert got is blobs[0] and type(got) is bytes and got == data
-        assert attrs == {"_v": b"3.7"} and marks == {"copies": 0}
+        assert attrs == {"_v": b"3.7"}
+        assert marks == {"copies": 0, "disk_bytes": len(data)}
         # length given and equal to the blob's, and read(): the same object
         assert store.read_object(C, O1, 0, len(data), attrs=False) == (
             blobs[1], {})
@@ -290,13 +297,17 @@ class TestAReadTouchesItsBytesOnce:
         (8 * MIN_ALLOC - 5, 4096)])
     def test_sub_range_of_one_blob_is_one_pread_and_one_slice(
             self, store, monkeypatch, off, length):
+        """PR 37: the pread and the crcs cover the 4 KiB csum chunks the
+        range touches, not the blob."""
         data = os.urandom(8 * MIN_ALLOC)
         store.queue_transaction(Transaction().write(C, O1, 0, data))
         preads, marks = _count_preads(monkeypatch), {}
         got, _ = store.read_object(C, O1, off, length, attrs=False, marks=marks)
         end = len(data) if length is None else min(off + length, len(data))
         assert got == data[off:end] and type(got) is bytes
-        assert preads == [len(data)] and marks == {"copies": 1}
+        chunks = -(-end // 4096) * 4096 - off // 4096 * 4096
+        assert preads == [chunks] and chunks < len(got) + 8192
+        assert marks == {"copies": 1, "disk_bytes": chunks}
 
     @pytest.mark.parametrize("entry", ["read", "read_object"])
     def test_extents_inline_and_hole_still_assemble(self, store, entry):
@@ -313,7 +324,8 @@ class TestAReadTouchesItsBytesOnce:
         want[2 * MIN_ALLOC + 1000 : 2 * MIN_ALLOC + 1000 + len(small)] = small
         want[3 * MIN_ALLOC : 3 * MIN_ALLOC + len(b)] = b
         meta = store._require(C, O1)
-        assert len(meta["extents"]) == 2 and meta["inline"]
+        assert [e[1].startswith("k") for e in meta["extents"]] == [
+            False, True, False]
         for off, length in [(0, None), (MIN_ALLOC, 3 * MIN_ALLOC),
                             (2 * MIN_ALLOC - 1, 1002), (2 * MIN_ALLOC, 500),
                             (3 * MIN_ALLOC - 1, 2), (4 * MIN_ALLOC, None),
@@ -324,21 +336,27 @@ class TestAReadTouchesItsBytesOnce:
                 want[off:end]), (off, length)
         marks = {}
         store.read_object(C, O1, attrs=False, marks=marks)
-        assert marks == {"copies": 2}
+        assert marks == {"copies": 2,
+                         "disk_bytes": len(a) + len(b)}
         # a range inside one of several blobs is still one slice of it
         store.read_object(C, O1, 3 * MIN_ALLOC + 5, 100, attrs=False,
                           marks=marks)
-        assert marks == {"copies": 1}
+        assert marks == {"copies": 1, "disk_bytes": 4096}
 
     @pytest.mark.parametrize("entry", ["read", "read_object"])
     def test_flipped_byte_on_disk_is_eio(self, store, entry):
         store.queue_transaction(
             Transaction().write(C, O1, 0, os.urandom(8 * MIN_ALLOC)))
         _flip_stored_byte(store, C, O1, at=3 * MIN_ALLOC)
-        for args in [(), (0, 10), (5 * MIN_ALLOC, None)]:
-            with pytest.raises(OSError) as ei:   # the crc is over the blob
+        # a crc a 4 KiB chunk: every read that covers the chunk, none other
+        for args in [(), (3 * MIN_ALLOC, 1), (3 * MIN_ALLOC - 5, 10),
+                     (MIN_ALLOC, None), (3 * MIN_ALLOC + 4095, 4096)]:
+            with pytest.raises(OSError) as ei:
                 _read_via(store, entry, C, O1, *args)
             assert ei.value.errno == 5
+        for args in [(0, 10), (0, 3 * MIN_ALLOC), (3 * MIN_ALLOC + 4096, None)]:
+            assert len(_read_via(store, entry, C, O1, *args)) > 0
+        assert len(store.fsck()) == 1
 
     @pytest.mark.parametrize("entry", ["read", "read_object"])
     def test_compressed_blob_round_trips(self, tmp_path, entry):
@@ -364,25 +382,25 @@ class TestAReadTouchesItsBytesOnce:
 
         old, new = os.urandom(2 * MIN_ALLOC), os.urandom(2 * MIN_ALLOC)
         store.queue_transaction(Transaction().write(C, O1, 0, old))
-        real, calls = store._read_blob, []
+        real, calls = store._read_extent, []
 
-        def racing_read_blob(blob, ln):
-            calls.append(blob)
+        def racing_read_extent(ext, *a):
+            calls.append(ext[1])
             if len(calls) == 1:     # the writer wins the race, once
                 store.queue_transaction(Transaction().write(C, O1, 0, new))
                 raise BlobError(5, "stale")
-            return real(blob, ln)
+            return real(ext, *a)
 
-        monkeypatch.setattr(store, "_read_blob", racing_read_blob)
+        monkeypatch.setattr(store, "_read_extent", racing_read_extent)
         assert _read_via(store, entry, C, O1) == new
         assert len(calls) == 2 and calls[0] != calls[1]
 
-        def rotten(blob, ln):
-            calls.append(blob)
+        def rotten(ext, *a):
+            calls.append(ext[1])
             raise BlobError(5, "rot")
 
         del calls[:]
-        monkeypatch.setattr(store, "_read_blob", rotten)
+        monkeypatch.setattr(store, "_read_extent", rotten)
         with pytest.raises(OSError) as ei:
             _read_via(store, entry, C, O1)
         assert ei.value.errno == 5 and len(calls) == 1   # same meta: no retry
@@ -492,3 +510,236 @@ class TestLegacyLayoutGuard:
 
         s = BlockStore(str(tmp_path / "new"))
         assert isinstance(s.db, BlueFSLite)
+
+
+# -- PR 37: a small overwrite costs its own bytes ----------------------------
+
+SHARD = 1 << 20     # an EC(4,2) shard of a 4 MiB object
+RB = ghobject_t("obj1", snap=7, shard=2)
+
+
+def _count_pwrites(monkeypatch):
+    calls = []
+    real = os.pwrite
+
+    def pwrite(fd, data, off):
+        calls.append(len(data))
+        return real(fd, data, off)
+
+    monkeypatch.setattr(os, "pwrite", pwrite)
+    return calls
+
+
+def _overwrite(store, off, data, rollback=True):
+    """One EC sub-write as ``_shard_write_txn`` builds it: the rollback
+    clone of the last one goes, the next is made, then the ranged
+    write."""
+    t = Transaction()
+    if rollback:
+        if store.exists(C, RB):
+            t.remove(C, RB)
+        t.clone(C, O1, RB)
+    t.write(C, O1, off, data).truncate(C, O1, SHARD)
+    t.setattrs(C, O1, {"_version": b"1.%d" % off})
+    store.queue_transaction(t)
+    return t
+
+
+def _seeded_overwrites(seed, n):
+    rng = __import__("numpy").random.default_rng(seed)
+    for _ in range(n):
+        yield (int(rng.integers(0, SHARD // 4096)) * 4096,
+               rng.integers(0, 256, 4096, dtype="uint8").tobytes())
+
+
+class TestASmallOverwriteCostsItsOwnBytes:
+    """PR 37: extents point into blobs, a crc a 4 KiB chunk, pieces are
+    kv values of their own, the fold is bounded."""
+
+    def test_1024_overwrites_replay_remount_and_clone(self, store, tmp_path):
+        base = os.urandom(SHARD)
+        store.queue_transaction(Transaction().write(C, O1, 0, base))
+        want = bytearray(base)
+        snap, snap_want = ghobject_t("obj1", snap=3, shard=2), None
+        for i, (off, data) in enumerate(_seeded_overwrites(37, 1024), 1):
+            _overwrite(store, off, data)
+            want[off : off + 4096] = data
+            if i == 512:    # a clone taken mid-way keeps what it saw
+                store.queue_transaction(Transaction().clone(C, O1, snap))
+                snap_want = bytes(want)
+            if i % 64 == 0:
+                assert store.read(C, O1) == bytes(want), i
+                assert store.read(C, O1, off, 4096) == data
+        assert store.read(C, snap) == snap_want
+        assert 8 <= store.stats["folds"] <= 1024 // 65
+        meta = store._require(C, O1)
+        assert len(json.dumps(meta)) < 16384 and len(meta["extents"]) <= 130
+        assert store.fsck() == []
+        store.umount()
+        s2 = BlockStore(store.path)
+        s2.mount()
+        assert s2.read(C, O1) == bytes(want)
+        assert s2.read(C, snap) == snap_want
+        assert s2.read(C, RB) != s2.read(C, O1)     # the last rollback clone
+        assert s2.fsck() == []
+        # pieces made after the remount do not reuse a live piece's id
+        _overwrite(s2, 8192, b"\x5a" * 4096)
+        want[8192:12288] = b"\x5a" * 4096
+        assert s2.read(C, O1) == bytes(want) and s2.read(C, snap) == snap_want
+        # every blob and piece goes with the last extent that names it
+        t = Transaction()
+        for o in (O1, RB, snap):
+            t.remove(C, o)
+        s2.queue_transaction(t)
+        for family in ("R", "K", "D", "O"):
+            assert not s2.db.get_iterator(family).seek_to_first().valid()
+        assert s2._alloc.free_units() + len(s2.db.used_units()) \
+            == s2._alloc.end_units
+
+    def test_counters_agree_with_the_medium_and_stay_under_48_kib(
+            self, store, monkeypatch):
+        store.queue_transaction(
+            Transaction().write(C, O1, 0, os.urandom(SHARD)))
+        pwrites, preads = _count_pwrites(monkeypatch), _count_preads(monkeypatch)
+        before, n = dict(store.stats), 512
+        marks = {"block_bytes": 0, "kv_bytes": 0, "folded": 0}
+        for off, data in _seeded_overwrites(41, n):
+            read_before = len(preads)
+            t = _overwrite(store, off, data)
+            for k in marks:
+                marks[k] += t.marks[k]
+            if not t.marks["folded"]:   # only the fold reads
+                assert len(preads) == read_before
+        grew = {k: store.stats[k] - before[k] for k in before}
+        assert grew["block_write_bytes"] + grew["kv_write_bytes"] \
+            == sum(pwrites)
+        assert (grew["block_write_bytes"], grew["kv_write_bytes"],
+                grew["folds"]) == (
+            marks["block_bytes"], marks["kv_bytes"], marks["folded"])
+        assert 4 <= grew["folds"] <= n // 65 and grew["read_disk_bytes"] == 0
+        assert grew["block_write_bytes"] == grew["folds"] * SHARD
+        assert sum(pwrites) / n <= 48 * 1024, sum(pwrites) / n
+        print("bytes a 4 KiB overwrite:", sum(pwrites) / n, grew)
+
+    def test_a_4k_ranged_read_of_a_1mib_blob_preads_at_most_8k(
+            self, store, monkeypatch):
+        data = os.urandom(SHARD)
+        store.queue_transaction(Transaction().write(C, O1, 0, data))
+        preads, marks = _count_preads(monkeypatch), {}
+        got, _ = store.read_object(C, O1, 40960, 4096, attrs=False, marks=marks)
+        assert got == data[40960:45056]
+        assert preads == [4096] and marks == {"copies": 0, "disk_bytes": 4096}
+        assert store.read(C, O1, 40961, 4096) == data[40961:45057]
+        assert preads == [4096, 8192]
+        # ... and of an overwritten block: a piece, no pread at all
+        _overwrite(store, 40960, b"\x11" * 4096)
+        assert store.read(C, O1, 40960, 4096) == b"\x11" * 4096
+        assert len(preads) == 2
+
+    def test_a_flipped_bit_is_eio_for_the_reads_that_cover_its_chunk(
+            self, store):
+        data = os.urandom(SHARD)
+        store.queue_transaction(Transaction().write(C, O1, 0, data))
+        _overwrite(store, 8 * 4096, b"\x22" * 4096, rollback=False)
+        for chunk in (0, 7, 9, 100, 255):
+            _flip_stored_byte(store, C, O1, at=chunk * 4096 + 17)
+            for c2 in (0, 7, 8, 9, 100, 255):
+                if c2 != chunk:
+                    store.read(C, O1, c2 * 4096, 4096)
+            with pytest.raises(OSError) as ei:
+                store.read(C, O1, chunk * 4096 + 100, 8)
+            assert ei.value.errno == 5
+            assert len(store.fsck()) == 1
+            _flip_stored_byte(store, C, O1, at=chunk * 4096 + 17)  # back
+        assert store.fsck() == []
+
+    def test_a_torn_overwrite_leaves_the_old_object(self, store):
+        from ceph_tpu.common.fault_injector import FAULTS, InjectedError
+
+        data = os.urandom(SHARD)
+        store.queue_transaction(Transaction().write(C, O1, 0, data))
+        _overwrite(store, 4096, b"\x33" * 4096)
+        want = store.read(C, O1)
+        FAULTS.inject("store.write", torn=True)
+        try:
+            with pytest.raises(InjectedError):
+                _overwrite(store, 4096, b"\x44" * 4096)
+            with pytest.raises(InjectedError):    # a big one writes a blob
+                FAULTS.inject("store.write", torn=True)
+                _overwrite(store, 65536, os.urandom(65536))
+        finally:
+            FAULTS.clear()
+        assert store.read(C, O1) == want
+        store.umount()
+        s2 = BlockStore(store.path)
+        s2.mount()      # the sweep takes the orphaned blob's units back
+        assert s2.read(C, O1) == want and s2.fsck() == []
+
+    def test_a_store_in_the_old_form_mounts_reads_and_is_written(
+            self, store):
+        """Three-field extents, a raw blob under one crc, hex pieces in
+        the meta value: as the parent of PR 37 wrote them."""
+        import struct
+
+        from ceph_tpu.kv import WriteBatch
+        from ceph_tpu.native import crc32c
+        from ceph_tpu.store.blockstore import _okey
+
+        a, b = os.urandom(2 * MIN_ALLOC), os.urandom(MIN_ALLOC + 11)
+        unit = store._alloc.alloc(2)
+        unit_b = store._alloc.alloc(2)
+        os.pwrite(store._fd, a, unit * MIN_ALLOC)
+        os.pwrite(store._fd, b, unit_b * MIN_ALLOC)
+        blob_a, blob_b = (f"{unit}:2:{crc32c(a)}", f"{unit_b}:2:{crc32c(b)}")
+        batch = WriteBatch()
+        batch.set("O", _okey(C, O1), json.dumps({
+            "size": 5 * MIN_ALLOC,
+            "extents": [[0, blob_a, len(a)], [3 * MIN_ALLOC, blob_b, len(b)]],
+            "inline": {str(2 * MIN_ALLOC + 100): b"old piece".hex()},
+        }).encode())
+        for blob in (blob_a, blob_b):
+            batch.set("R", blob, struct.pack("<I", 1))
+        store.db.submit(batch)
+        store.umount()
+        s2 = BlockStore(store.path)
+        s2.mount()
+        want = bytearray(5 * MIN_ALLOC)
+        want[: len(a)] = a
+        want[2 * MIN_ALLOC + 100 : 2 * MIN_ALLOC + 109] = b"old piece"
+        want[3 * MIN_ALLOC : 3 * MIN_ALLOC + len(b)] = b
+        assert s2.read(C, O1) == bytes(want) and s2.fsck() == []
+        assert s2.read(C, O1, 100, 50) == bytes(want[100:150])
+        # a new blob does not land on the old ones
+        O2 = ghobject_t("obj2", shard=2)
+        s2.queue_transaction(Transaction().write(C, O2, 0, os.urandom(MIN_ALLOC * 3)))
+        assert s2.read(C, O1) == bytes(want)
+        # overwrites cut the old blobs without reading them
+        s2.queue_transaction(Transaction().clone(C, O1, RB))
+        for off, data in [(4096, b"\x55" * 4096), (2 * MIN_ALLOC + 104, b"XY"),
+                          (3 * MIN_ALLOC + 8192, os.urandom(8192))]:
+            s2.queue_transaction(Transaction().write(C, O1, off, data))
+            want[off : off + len(data)] = data
+            assert s2.read(C, O1) == bytes(want)
+            assert s2.read(C, O1, off, len(data)) == data
+        assert "inline" not in s2._require(C, O1) and s2.fsck() == []
+        s2.queue_transaction(Transaction().remove(C, O1))
+        free = s2._alloc.free_units()
+        s2.queue_transaction(Transaction().remove(C, RB))   # the last names
+        assert s2._alloc.free_units() >= free + 4
+        assert s2.fsck() == []
+
+
+def test_crc32c_chunks_is_every_chunks_crc_in_one_call():
+    from ceph_tpu import native
+
+    for n in (0, 1, 4095, 4096, 4097, 3 * 4096, 7 * 4096 + 5, SHARD):
+        data = os.urandom(n)
+        want = b"".join(native.crc32c(data[at : at + 4096]).to_bytes(
+            4, "little") for at in range(0, n, 4096))
+        for buf in (data, bytearray(data), memoryview(data)):
+            assert native.crc32c_chunks(buf, 4096) == want
+        assert native.crc32c_chunks(data, 4096, table=True) == want
+    small = os.urandom(9000)
+    assert native.crc32c_chunks(small, 1000) == b"".join(
+        native._py_crc32c(small[at : at + 1000], 0xFFFFFFFF).to_bytes(
+            4, "little") for at in range(0, 9000, 1000))

@@ -340,3 +340,117 @@ def test_a_build_that_raises_leaves_nothing_and_raises_again():
         with pytest.raises(RuntimeError):
             batcher.shared("broken", build)
     assert "broken" not in batcher._shared
+
+
+# -- PR 37: the launch decision is the group's --------------------------------
+
+def _service(window_s: float = 0.02):
+    return encode_service.EncodeService(
+        device=jax.devices()[0], window_s=window_s)
+
+
+@pytest.mark.parametrize("widths,launches,host", [
+    ([4096], 0, (1, 1)),                # a lone 16 KiB stripe: the host
+    ([4096, 4096, 4096], 1, (0, 0)),    # three in one window: ONE launch
+    ([1 << 20], 1, (0, 0)),             # a lone 4 MiB object: a launch
+    ([1024, 2048, 2048], 0, (1, 3)),    # 20 KiB in all: one host group
+], ids=["lone_16k", "three_16k", "lone_4m", "group_under_32k"])
+def test_a_flushed_group_launches_by_what_it_carries(widths, launches, host):
+    """``min_bytes`` (32 KiB, no option) is asked of the flushed group's
+    summed bytes, never of one request, and a group under it is answered
+    on the host at the flush: counted, never as a fallback."""
+    svc = _service()
+    assert svc.min_bytes == encode_service.DEFAULT_MIN_BYTES == 32768
+    M = np.asarray(_ec(2).coding_matrix, np.uint8)
+    reqs = [_rows(70 + i, w) for i, w in enumerate(widths)]
+
+    async def go():
+        threads = []
+        real = svc._host_group
+
+        def host_group(key, group):
+            threads.append(threading.get_ident())
+            return real(key, group)
+
+        svc._host_group = host_group
+        outs = await asyncio.gather(*(svc.apply(M, r) for r in reqs))
+        assert threads == [threading.get_ident()] * host[0]   # inline
+        return outs
+
+    for r, out in zip(reqs, asyncio.run(go())):
+        assert np.array_equal(out, gf_matmul(M, r))
+    assert svc.stats["single_dispatches"] == launches
+    assert svc.stats["coalesced"] == (len(reqs) if launches else 0)
+    assert (svc.stats["host_groups"], svc.stats["host_requests"]) == host
+    assert svc.stats["fallbacks"] == 0
+
+
+def test_two_matrices_in_one_window_are_two_decisions():
+    """Groups are per matrix: 48 KiB under one launches while 16 KiB
+    under another, flushed in the same window, goes to the host."""
+    svc = _service()
+    A = np.asarray(_ec(2).coding_matrix, np.uint8)
+    B = np.asarray(_ec(3).coding_matrix, np.uint8)
+
+    async def go():
+        return await asyncio.gather(
+            *(svc.apply(A, _rows(80 + i)) for i in range(3)),
+            svc.apply(B, _rows(90)))
+
+    outs = asyncio.run(go())
+    assert np.array_equal(outs[3], gf_matmul(B, _rows(90)))
+    assert np.array_equal(outs[0], gf_matmul(A, _rows(80)))
+    assert (svc.stats["single_dispatches"], svc.stats["coalesced"],
+            svc.stats["host_groups"], svc.stats["host_requests"],
+            svc.stats["fallbacks"]) == (1, 3, 1, 1, 0)
+
+
+def test_an_encode_launchs_span_says_what_it_carried():
+    svc = _service()
+    M = np.asarray(_ec(2).coding_matrix, np.uint8)
+    tracer = tracing.device_tracer()
+    tracer.set_ring_max(1 << 12)
+
+    async def go():
+        with tracing.get_tracer("t37").span("op") as sp, tracing.scope(sp):
+            await asyncio.gather(*(svc.apply(M, _rows(i)) for i in range(3)))
+        return sp.trace_id, sp.span_id
+
+    _trace, parent = asyncio.run(go())
+    launch = [s for s in tracer.dump(limit=1 << 12)
+              if s["name"] == "xla_launch"
+              and parent in s["tags"].get("parents", ())]
+    assert len(launch) == 1
+    assert launch[0]["tags"]["real_bytes"] == 3 * K * WIDTH
+    assert launch[0]["tags"]["kind"] == "encode_single"
+
+
+def test_ecutil_files_every_request_with_an_active_service():
+    """``_service_takes`` asks no size: a 16 KiB read-modify-write
+    stripe reaches the service (and is answered there on the host)."""
+    ec = registry.factory("jerasure", {
+        "technique": "reed_sol_van", "k": "4", "m": "2"})
+    sinfo = ecutil.StripeInfo(4, ec.get_chunk_size(4096 * 4) * 4)
+    svc = _service(0.002)
+    data = np.random.default_rng(3).integers(0, 256, 16384, dtype=np.uint8)
+
+    async def go():
+        return await ecutil.encode_async(sinfo, ec, data, service=svc)
+
+    got = asyncio.run(go())
+    want = ecutil.encode(sinfo, ec, data)
+    assert all(np.array_equal(got[s], want[s]) for s in want)
+    assert svc.stats["host_requests"] == 1 and svc.stats["fallbacks"] == 0
+    assert "nbytes" not in ecutil._service_takes.__code__.co_varnames
+
+
+def test_the_daemons_ladder_covers_what_32_overwrites_can_gather():
+    """``_warm_ec_profiles`` compiles widths cs/4, cs, 4 cs at 1..16
+    requests: every power-of-two bucket that 2..32 stripes of a (4, 2)
+    pool's 4 KiB chunks land in is among them, so no read-modify-write
+    launch is cold."""
+    svc = _service()
+    cs = 4096
+    warmed = {svc._bucket(w << f) for w in (cs >> 2, cs, cs << 2)
+              for f in range((16).bit_length())}
+    assert {svc._bucket(cs * n) for n in range(2, 33)} <= warmed
