@@ -140,9 +140,11 @@ declare(
            min=0.0),
     Option("osd_backfill_retry_interval", float, 1.0, LEVEL_ADVANCED,
            "seconds before retrying a PG whose remote backfill "
-           "reservation was rejected (reference "
-           "osd_backfill_retry_interval, default 30s there — shorter "
-           "here to match mini-cluster timescales)", min=0.0),
+           "reservation a backfillfull peer rejected, and the first "
+           "backoff before re-running a recovery pass that left PGs "
+           "unclean; a busy peer queues the reservation instead "
+           "(reference osd_backfill_retry_interval, default 30s there "
+           "— shorter here to match mini-cluster timescales)", min=0.0),
     Option("osd_backfill_grant_timeout", float, 60.0, LEVEL_ADVANCED,
            "seconds a remote backfill GRANT may sit unreleased before "
            "the reserver-death sweep reclaims the slot (0 disables the "

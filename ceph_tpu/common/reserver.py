@@ -107,24 +107,6 @@ class AsyncReserver:
         self._issued[item] = res
         return res
 
-    def try_request(self, item, priority: int = 0) -> Reservation | None:
-        """Non-blocking acquire: a slot now or None (the remote-
-        reservation REJECT_TOOFULL path — replicas answer immediately
-        rather than parking the primary on the wire)."""
-        existing = self._granted.get(item)
-        if existing is not None and not existing._released:
-            return existing
-        if len(self._granted) >= self.max_allowed or self._queue:
-            return None
-        pending = self._issued.get(item)
-        if pending is not None and not pending._released:
-            res = pending
-        else:
-            res = Reservation(self, item, priority)
-            self._issued[item] = res
-        self._grant(res)
-        return res
-
     def cancel(self, item) -> None:
         """Drop a queued or granted reservation for ``item``
         (AsyncReserver::cancel_reservation)."""

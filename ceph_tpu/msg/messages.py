@@ -1171,9 +1171,11 @@ class MBackfillReserve(Message):
     """Backfill-reservation handshake between a recovering primary and
     its acting-set replicas (src/messages/MBackfillReserve.h): REQUEST
     asks the replica for one of its osd_max_backfills remote slots;
-    the replica answers GRANT or REJECT_TOOFULL (non-blocking — the
-    primary retries after osd_backfill_retry_interval); RELEASE frees
-    the slot when the PG goes clean."""
+    the replica answers GRANT — at once, or when a busy slot frees,
+    under the REQUEST's tid — or REJECT_TOOFULL when it is backfillfull
+    (the primary retries after osd_backfill_retry_interval); RELEASE
+    frees the slot when the PG goes clean, or withdraws a queued
+    REQUEST."""
 
     TYPE = 99  # MSG_OSD_BACKFILL_RESERVE (src/include/msgr.h)
 

@@ -339,6 +339,13 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         self.local_reserver = AsyncReserver(max_allowed=_mb)
         self.remote_reserver = AsyncReserver(max_allowed=_mb)
         self._remote_grants: dict[tuple[int, int, int], object] = {}
+        # replica side: (pool, ps, primary) -> the REQUESTs (by tid)
+        # still waiting for a remote slot, which its GRANT answers
+        self._remote_asks: dict[
+            tuple[int, int, int], dict[int, MBackfillReserve]] = {}
+        # primary side: reply futures of reservation waits that a new
+        # map ends (_wake_reserve_waits)
+        self._reserve_waits: set[asyncio.Future] = set()
         # in-flight object-reconciliation budget within granted PGs
         # (osd_recovery_max_active role)
         self._recovery_budget = asyncio.Semaphore(
@@ -1692,6 +1699,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         new_map, gap = apply_map_message(self.osdmap, msg.maps, msg.incs)
         if new_map is not None:
             self.osdmap = new_map
+            self._wake_reserve_waits()
             self._maybe_snap_trim(old_map, new_map)
             self._track_intervals(old_map, new_map)
             self._maybe_split_pgs(old_map, new_map)

@@ -63,10 +63,11 @@ class RecoveryMixin:
         PGs run concurrently, but admission is reservation-gated
         (backfill_reservation.rst): each PG takes one of OUR
         osd_max_backfills local slots, then one remote slot on every
-        acting peer (MBackfillReserve REQUEST/GRANT); a REJECT_TOOFULL
-        releases everything and retries after
-        osd_backfill_retry_interval, so cluster-wide concurrent
-        backfill load per OSD stays bounded.
+        acting peer in ascending OSD id (MBackfillReserve REQUEST/GRANT;
+        a busy peer queues the REQUEST and GRANTs when its slot frees),
+        so cluster-wide concurrent backfill load per OSD stays bounded;
+        only a backfillfull peer's REJECT_TOOFULL releases everything
+        and retries after osd_backfill_retry_interval.
 
         A pass that leaves PGs unclean (a peer mid-restart, a dropped
         connection) re-runs even if no new map arrives — the
@@ -177,8 +178,8 @@ class RecoveryMixin:
         })
         retry = self.conf["osd_backfill_retry_interval"]
         # one trace per PG pass: recover_pg -> pg_reserve (queueing for
-        # the local slot and a slot on every peer, retry sleeps
-        # included), pg_scan, then each object's admit wait and
+        # the local slot and a slot on every peer, backfillfull retry
+        # sleeps included), pg_scan, then each object's admit wait and
         # recover_object — in scope, so the object legs parent there
         with self.tracer.span("recover_pg", pg=str(pg)) as pg_sp, \
                 tracing.scope(pg_sp):
@@ -195,13 +196,16 @@ class RecoveryMixin:
                         while not self.stopping \
                                 and self.epoch == pass_epoch:
                             rounds += 1
-                            if await self._reserve_remotes(
-                                    pg, peers, granted):
+                            held = await self._reserve_remotes(
+                                pg, peers, granted, pass_epoch)
+                            if held:
                                 break
-                            # partial holds across the retry sleep invite
-                            # cluster-wide deadlock (two primaries each
-                            # camped on one of the other's replicas): drop
-                            # everything
+                            if held is None:
+                                continue  # the map moved on: ends below
+                            # a backfillfull (or unreachable) peer may stay
+                            # so for long: partial holds across the retry
+                            # sleep would park every PG queued behind
+                            # them — drop everything
                             rejects += 1
                             self.recovery_stats["reservation_rejects"] += 1
                             await self._release_remotes(pg, granted)
@@ -215,12 +219,17 @@ class RecoveryMixin:
                             # are never reservation- or fullness-gated)
                             await self._recover_pg_deletes(pool, pg, acting)
                             await asyncio.sleep(retry)
-                        else:
-                            pg_sp.tag(result="superseded")
-                            return
                     finally:
                         reserve_sp.tag(rounds=rounds, rejects=rejects)
                         self.tracer.finish_span(reserve_sp)
+                    # checked after the LAST grant, which a queue can
+                    # hand out seconds after the first ask: a pass that
+                    # reserved under an old map would probe the old
+                    # acting set (a stopped OSD's closed port) and fail
+                    # its objects; the next pass runs with the new one
+                    if self.stopping or self.epoch != pass_epoch:
+                        pg_sp.tag(result="superseded")
+                        return
                     self._recovering_pgs.add(key)
                     try:
                         ok = await self._recover_pg(pool, pg, acting)
@@ -245,36 +254,83 @@ class RecoveryMixin:
 
     async def _reserve_remotes(
         self, pg: pg_t, peers: list[int], granted: list[int],
-    ) -> bool:
-        """GRANT from every acting peer, or False on REJECT_TOOFULL.
+        pass_epoch: int,
+    ) -> bool | None:
+        """A slot on every acting peer, asked of one peer at a time in
+        ``peers``' ascending OSD order; what is held stays held while a
+        later peer is waited on.  Every PG takes its peers in that one
+        order, so no pass can hold what another holds and waits for,
+        and a busy peer's queue grants each in turn.
 
-        A peer the MAP says is down is skipped — it can take no
-        recovery load and no pushes will reach it.  A peer that is up
-        but unreachable counts as a REJECT: it may come back mid-
-        recovery and start absorbing pushes, so proceeding without its
-        slot would unbound its inbound backfill load; the retry loop
-        re-asks (either it answers, or it gets marked down — a new
-        epoch — and the pass restarts without it).  Either way a
-        best-effort RELEASE covers the race where the peer GRANTed but
-        the reply missed our timeout — without it the replica's slot
-        leaks until we restart."""
+        True once every peer granted.  False on REJECT_TOOFULL (the peer
+        is backfillfull), or when a peer that is up cannot be reached:
+        it may come back mid-recovery and start absorbing pushes, so
+        proceeding without its slot would unbound its inbound backfill
+        load; the retry loop re-asks (either it answers, or it gets
+        marked down — a new epoch — and the pass restarts without it).
+        None when the map moved past ``pass_epoch`` or this OSD stops.
+        A peer the MAP says is down is skipped — it can take no recovery
+        load and no pushes will reach it.  Every way out but a GRANT
+        sends the peer asked a best-effort RELEASE: it may hold the ask
+        in its queue, or have GRANTed it unheard — without the RELEASE
+        the replica's slot leaks until we restart."""
         for o in peers:
-            tid = next(self._tids)
             try:
-                rep = await self._sub_op(o, MBackfillReserve(
-                    tid=tid, op=MBackfillReserve.REQUEST, pool=pg.pool,
-                    ps=pg.ps, from_osd=self.id, priority=1,
-                ), tid)
+                op = await self._ask_remote_slot(pg, o, pass_epoch)
             except (OSError, asyncio.TimeoutError, ConnectionError):
                 if not self.osdmap.is_up(o):
                     continue
                 await self._release_remotes(pg, [o])
                 return False
-            if rep.op == MBackfillReserve.GRANT:
+            if op == MBackfillReserve.GRANT:
                 granted.append(o)
-            else:
-                return False
+                continue
+            await self._release_remotes(pg, [o])
+            return None if op is None else False
         return True
+
+    async def _ask_remote_slot(
+        self, pg: pg_t, o: int, pass_epoch: int,
+    ) -> int | None:
+        """REQUEST a backfill slot of peer ``o`` and wait for its
+        answer: GRANT, or REJECT_TOOFULL.  None when the map moves past
+        ``pass_epoch`` or this OSD stops first.  A busy peer queues the
+        ask, so the wait is not timed out: a peer silent for
+        SUBOP_TIMEOUT is asked again under the same tid (a REQUEST or
+        GRANT may be lost; the peer answers every ask by its tid).  Raises
+        what connecting or sending raises, and ConnectionError when the
+        link to ``o`` resets meanwhile."""
+        loop = asyncio.get_running_loop()
+        tid = next(self._tids)
+        try:
+            while not self.stopping and self.epoch == pass_epoch:
+                fut = loop.create_future()
+                fut.peer = ("osd", o)  # _on_reset fails it
+                self._waiters[tid] = fut
+                conn = await self._osd_conn(o)
+                await conn.send_message(MBackfillReserve(
+                    tid=tid, op=MBackfillReserve.REQUEST,
+                    pool=pg.pool, ps=pg.ps, from_osd=self.id, priority=1,
+                ))
+                self._reserve_waits.add(fut)
+                try:
+                    rep = await asyncio.wait_for(fut, SUBOP_TIMEOUT)
+                except asyncio.TimeoutError:
+                    continue
+                finally:
+                    self._reserve_waits.discard(fut)
+                if rep is not None:
+                    return rep.op
+            return None
+        finally:
+            self._waiters.pop(tid, None)
+
+    def _wake_reserve_waits(self) -> None:
+        """A new map ends every reservation wait: each checks its
+        pass's epoch again (_ask_remote_slot)."""
+        for fut in self._reserve_waits:
+            if not fut.done():
+                fut.set_result(None)
 
     async def _release_remotes(self, pg: pg_t, granted: list[int]) -> None:
         for o in granted:
@@ -312,6 +368,17 @@ class RecoveryMixin:
                     "osd.%d: swept backfill grant pg=%d.%d from osd.%d "
                     "(%s)", self.id, key[0], key[1], key[2],
                     "requester down" if down else "grant timed out")
+        # a queued ask of a dead primary would be granted a slot that
+        # nobody releases until the grant times out
+        if self.osdmap is None:
+            return
+        for key in [k for k in self._remote_asks
+                    if not self.osdmap.is_up(k[2])]:
+            del self._remote_asks[key]
+            self.remote_reserver.cancel(key)
+            log.info(
+                "osd.%d: dropped queued backfill ask pg=%d.%d from osd.%d "
+                "(requester down)", self.id, key[0], key[1], key[2])
 
     async def _grant_sweep(self) -> None:
         """Periodic reserver-death sweep — independent of this osd's
@@ -333,6 +400,7 @@ class RecoveryMixin:
     async def _handle_backfill_reserve(self, msg: MBackfillReserve) -> None:
         if msg.op == MBackfillReserve.REQUEST:
             key = (msg.pool, msg.ps, msg.from_osd)
+            self.perf.inc("backfill_reserve_requests")
             if (self._full_ratio()
                     >= self.conf["mon_osd_backfillfull_ratio"]):
                 # backfillfull: absorbing a backfill would push this
@@ -357,30 +425,75 @@ class RecoveryMixin:
                 # kill-backfiller-mid-transfer deadlock (a revived
                 # primary could never re-reserve its own leaked slot).
                 self._remote_grants[key] = (held[0], time.monotonic())
-                op = MBackfillReserve.GRANT
+                await msg.conn.send_message(MBackfillReserve(
+                    tid=msg.tid, op=MBackfillReserve.GRANT, pool=msg.pool,
+                    ps=msg.ps, from_osd=self.id,
+                ))
+                return
+            # the GRANT, whenever it comes, answers every ask of the key
+            # by its tid: a second pass of the primary's for this PG
+            # (_queue_pg_pass beside _recover_all) would otherwise wait
+            # out SUBOP_TIMEOUT for an answer sent to the other
+            asks = self._remote_asks.get(key)
+            if asks is not None:
+                asks[msg.tid] = msg  # asked again while queued
+                return
+            self._remote_asks[key] = {msg.tid: msg}
+            rr = self.remote_reserver
+            if rr.in_use < rr.max_allowed:
+                await self._grant_remote(key, msg.priority)
             else:
-                res = self.remote_reserver.try_request(key, msg.priority)
-                if res is not None:
-                    self._remote_grants[key] = (res, time.monotonic())
-                    self.recovery_stats["peak_remote"] = max(
-                        self.recovery_stats["peak_remote"],
-                        self.remote_reserver.in_use)
-                    op = MBackfillReserve.GRANT
-                else:
-                    op = MBackfillReserve.REJECT_TOOFULL
-            await msg.conn.send_message(MBackfillReserve(
-                tid=msg.tid, op=op, pool=msg.pool, ps=msg.ps,
-                from_osd=self.id,
-            ))
+                # busy: queue at the ask's priority, FIFO within it
+                # (backfill_reservation.rst) — off this connection's
+                # dispatch, whose next message may be the RELEASE that
+                # frees the slot
+                self.perf.inc("backfill_reserve_queued")
+                self._spawn_repair_task(
+                    self._grant_remote(key, msg.priority))
         elif msg.op == MBackfillReserve.RELEASE:
-            held = self._remote_grants.pop(
-                (msg.pool, msg.ps, msg.from_osd), None)
+            key = (msg.pool, msg.ps, msg.from_osd)
+            held = self._remote_grants.pop(key, None)
             if held is not None:
                 held[0].release()
+            elif self._remote_asks.pop(key, None) is not None:
+                self.remote_reserver.cancel(key)  # withdrawn while queued
         else:  # GRANT / REJECT_TOOFULL reply to our REQUEST
             fut = self._waiters.get(msg.tid)
             if fut and not fut.done():
                 fut.set_result(msg)
+
+    async def _grant_remote(self, key: tuple[int, int, int],
+                            priority: int) -> None:
+        """Take a remote slot for ``key`` — at once where one is free,
+        else when one frees — and GRANT it to each of the key's asks.  A
+        RELEASE or the sweep that withdraws a queued ask cancels the
+        wait (AsyncReserver.cancel)."""
+        res = self.remote_reserver.request(key, priority)
+        await res.wait()
+        asks = self._remote_asks.pop(key, None)
+        if asks is None:
+            # withdrawn before this woke; but the reserver hands one
+            # reservation to every request of a key, so a task of an
+            # ask withdrawn before it first ran may hold the slot of the
+            # key's next ask, granted meanwhile: that one stays
+            held = self._remote_grants.get(key)
+            if held is None or held[0] is not res:
+                res.release()
+            return
+        self._remote_grants[key] = (res, time.monotonic())
+        self.recovery_stats["peak_remote"] = max(
+            self.recovery_stats["peak_remote"], self.remote_reserver.in_use)
+        for ask in asks.values():
+            try:
+                await ask.conn.send_message(MBackfillReserve(
+                    tid=ask.tid, op=MBackfillReserve.GRANT, pool=key[0],
+                    ps=key[1], from_osd=self.id,
+                ))
+            except (OSError, ConnectionError):
+                # the primary asks again (re-GRANT) or RELEASEs; else
+                # the sweep reclaims the slot
+                log.info("osd.%d: GRANT pg=%d.%d to osd.%d not sent",
+                         self.id, key[0], key[1], key[2])
 
     def _load_backfill_cursor(self, myc, acting) -> str | None:
         """Last-backfill cursor persisted by an interrupted pass —

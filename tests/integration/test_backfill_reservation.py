@@ -32,6 +32,11 @@ async def _total_remap(c, io, n_osds: int) -> None:
     await c.wait_epoch(epoch0 + 1)
 
 
+def _queued(c) -> float:
+    return sum(o.perf.dump().get("backfill_reserve_queued", 0)
+               for o in c.osds)
+
+
 class TestBackfillReservation:
     def test_concurrent_backfills_bounded(self):
         async def go():
@@ -50,6 +55,8 @@ class TestBackfillReservation:
                         np.random.default_rng(i).integers(
                             0, 256, 8192, dtype=np.uint8).tobytes())
                 await c.client.wait_clean(timeout=30)
+                # perf collections outlive a test's daemons: growth
+                queued0 = _queued(c)
                 await _total_remap(c, io, 6)
                 await c.client.wait_clean(timeout=90)
                 peaks_l = [o.recovery_stats["peak_local"] for o in c.osds]
@@ -61,11 +68,13 @@ class TestBackfillReservation:
                 assert max(peaks_r) <= 1, peaks_r
                 assert recovered >= 8, recovered
                 # 8 PGs re-homing through 1-slot reservers MUST have
-                # produced contention somewhere (REJECT_TOOFULL path)
+                # contended somewhere: a busy peer queued the ask, and
+                # no store is backfillfull, so nothing was rejected
+                assert _queued(c) > queued0
                 rejects = sum(
                     o.recovery_stats["reservation_rejects"]
                     for o in c.osds)
-                assert rejects > 0
+                assert rejects == 0
                 for i in range(24):
                     data = np.random.default_rng(i).integers(
                         0, 256, 8192, dtype=np.uint8).tobytes()
