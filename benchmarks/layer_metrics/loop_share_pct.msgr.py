@@ -1,0 +1,20 @@
+"""The share of the daemons' loop time that went to ``msgr``, the
+messengers' own work: reading and decoding frames, handshakes, dials (what
+a handler does is its message's role).  The growth of
+``msgr.loop_s_msgr`` over that of every role's, summed.  Nothing to read
+where no callback was timed.
+"""
+
+from harness.loopclock import owned_s
+
+LAYER = "loop"
+UNIT = "%"
+MOVES = "throughput_MiB_s"
+SOURCE = "program_counter"
+
+
+def compute(spans, counters, trace, run):
+    s = owned_s(counters)
+    if s is None or not sum(s.values()):
+        return None
+    return 100.0 * s["msgr"] / sum(s.values())

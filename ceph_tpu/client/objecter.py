@@ -40,6 +40,7 @@ import logging
 from collections import deque
 
 from ceph_tpu.common.metrics import get_perf_counters
+from ceph_tpu.common.tracing import loop_role
 from ceph_tpu.msg.messages import MOSDOp, MOSDOpReply
 from ceph_tpu.osd.daemon import object_to_pg
 
@@ -188,29 +189,32 @@ class Objecter:
         to its own driver.  Returns the Completion immediately."""
         from ceph_tpu.client.rados import RadosError
 
-        if self._stopping:
-            raise RadosError(errno.ESHUTDOWN, "client shutting down")
-        client = self.client
-        loop = asyncio.get_running_loop()
-        if op.is_write() and not op.reqid:
-            # stable across resends (osd_reqid_t): the OSD dedups a
-            # retried non-idempotent op by this id
-            op.reqid = f"client.{client.id}:{next(client._tids)}"
-        cost = self._op_cost(op)
-        await self._admit(cost, loop)
-        comp = Completion(loop, op.oid)
-        span = client.tracer.start_span(
-            "client_op", oid=op.oid, pool=pool_id,
-            write=op.is_write(), reqid=op.reqid or "aio",
-        )
-        op.trace = client.tracer.ctx_for(span)
-        rec = _OpRec(op, pool_id, comp,
-                     loop.time() + client.op_timeout, cost, span)
-        self.perf.inc("ops_submitted")
-        task = asyncio.ensure_future(self._drive(rec))
-        self._drivers.add(task)
-        task.add_done_callback(self._drivers.discard)
-        return comp
+        # the admission, the op's driver and the writers it starts are
+        # the client's loop time, whoever submits
+        with loop_role("client", self.client.messenger):
+            if self._stopping:
+                raise RadosError(errno.ESHUTDOWN, "client shutting down")
+            client = self.client
+            loop = asyncio.get_running_loop()
+            if op.is_write() and not op.reqid:
+                # stable across resends (osd_reqid_t): the OSD dedups a
+                # retried non-idempotent op by this id
+                op.reqid = f"client.{client.id}:{next(client._tids)}"
+            cost = self._op_cost(op)
+            await self._admit(cost, loop)
+            comp = Completion(loop, op.oid)
+            span = client.tracer.start_span(
+                "client_op", oid=op.oid, pool=pool_id,
+                write=op.is_write(), reqid=op.reqid or "aio",
+            )
+            op.trace = client.tracer.ctx_for(span)
+            rec = _OpRec(op, pool_id, comp,
+                         loop.time() + client.op_timeout, cost, span)
+            self.perf.inc("ops_submitted")
+            task = asyncio.ensure_future(self._drive(rec))
+            self._drivers.add(task)
+            task.add_done_callback(self._drivers.discard)
+            return comp
 
     # -- the per-op driver (op_submit/_calc_target/resend loop) --------
 

@@ -25,13 +25,21 @@ This module provides the same capability TPU-side, now **cluster-wide**:
   ``dump_traces`` admin command, and sampled/slow spans additionally
   queue in an export buffer the daemon's MgrClient drains into
   MMgrReport — the mgr's TraceCollector (mgr/tracer.py) assembles the
-  cluster-wide trees.
+  cluster-wide trees.  A tracer held by MgrClients queues only while
+  one of them has an active mgr to send to.
 
 - an interval that is only known once it has ended (a wait, a phase a
   worker thread ran) is filed afterwards with :meth:`Tracer.record`;
 - with head sampling at 0 and tail capture off the hot path builds no
   :class:`Span` at all: every constructor hands out the one shared
-  :data:`INERT` span, whose children and wire context are inert too.
+  :data:`INERT` span, whose children and wire context are inert too;
+- **the event loop's own clock** (the reference AsyncMessenger workers'
+  ``msgr_running_*_time``, src/msg/async/Stack.h): every callback the
+  loop runs is charged to the daemon and role in :data:`LOOP_OWNER`
+  (``msgr``, ``op``, ``recovery``, ``background``, ``client``) in that
+  daemon's ``Messenger.stats``; a callback that holds the loop for
+  :data:`LONG_TURN_S` or more is a ``loop_turn`` span; periodic loops
+  sleep through :func:`tick`, which counts how late the loop woke them.
 
 Usage::
 
@@ -47,6 +55,7 @@ Usage::
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import itertools
 import random
@@ -194,6 +203,180 @@ class scope:
         return False
 
 
+# -- the event loop's own clock ------------------------------------------------
+
+#: whom the loop time of the running task or callback is charged to:
+#: ``(messenger, role)`` of a daemon, whose ``Messenger.stats`` keeps the
+#: seconds and whose ``tracer`` files the long turns.  Tasks inherit it
+#: at creation, like :data:`CURRENT_SPAN`; None outside every daemon (a
+#: harness's own tasks, the handle a worker thread schedules to complete
+#: its future)
+LOOP_OWNER: contextvars.ContextVar = contextvars.ContextVar(
+    "ceph_tpu_loop_owner", default=None)
+
+#: what a daemon's loop time is spent on
+LOOP_ROLES = ("msgr", "op", "recovery", "background", "client")
+
+#: a callback that holds the loop this long or longer is a ``loop_turn``
+LONG_TURN_S = 1e-3
+
+#: the counters every messenger's ``stats`` starts with beside the wire's
+LOOP_STATS = (*(f"loop_s_{r}" for r in LOOP_ROLES), "loop_callbacks",
+              "loop_timer_fires", "loop_timer_late_s",
+              "loop_timer_late_over_1s")
+
+_ROLE_KEY = {r: f"loop_s_{r}" for r in LOOP_ROLES}
+_HANDLE_RUN = asyncio.events.Handle._run
+#: the process's timed loop seconds: all of them, and those of no owner
+_LOOP_S = {"total": 0.0, "unowned": 0.0}
+_clock = time.monotonic         # the spans' clock
+
+
+class _Turn:
+    """The callback one thread's loop is running: the owner its time is
+    charged to since ``t`` (None: no callback is being timed)."""
+
+    __slots__ = ("owner", "t")
+
+    def __init__(self):
+        self.owner = self.t = None
+
+
+class _Turns(threading.local):
+    def __init__(self):
+        self.turn = _Turn()
+
+
+_TURNS = _Turns()
+
+
+def _charge(owner, seconds: float) -> None:
+    if owner is None:
+        _LOOP_S["unowned"] += seconds
+    else:
+        owner[0].stats[_ROLE_KEY[owner[1]]] += seconds
+
+
+def _switch(owner) -> None:
+    """The running task's owner changes inside a callback: the piece so
+    far is the old owner's."""
+    turn = _TURNS.turn
+    if turn.t is not None:
+        now = _clock()
+        _charge(turn.owner, now - turn.t)
+        turn.owner, turn.t = owner, now
+
+
+def _timed_run(handle) -> None:
+    """``asyncio.events.Handle._run`` with the loop's clock on: the
+    callback's wall time (a wait for the GIL a worker thread holds
+    included) goes to its owner's ``loop_s_<role>``, split where a
+    :class:`loop_role` scope changes the owner.  A long turn's span is
+    filed inside the timed interval, so that filing it is charged too."""
+    turn = _TURNS.turn
+    owner = turn.owner = handle._context.get(LOOP_OWNER)
+    t0 = turn.t = _clock()
+    _HANDLE_RUN(handle)
+    t1 = _clock()
+    last = turn.owner
+    if t1 - t0 >= LONG_TURN_S:
+        _long_turn(handle, last, t0, t1)
+        t1 = _clock()
+    piece = t1 - turn.t
+    turn.t = None
+    _LOOP_S["total"] += t1 - t0
+    if last is None:            # _charge, inline: this runs every callback
+        _LOOP_S["unowned"] += piece
+    else:
+        last[0].stats[_ROLE_KEY[last[1]]] += piece
+    if owner is not None:
+        owner[0].stats["loop_callbacks"] += 1
+
+
+def _long_turn(handle, owner, t0: float, t1: float) -> None:
+    """A turn that held the loop ``LONG_TURN_S`` or more: a root
+    ``loop_turn`` span in the tracer of the owner it ended under, with
+    no stage, so no op's tree and no stage sum sees it; ``idle_gaps`` of
+    a device trace names the device's idle time under it
+    ``loop_turn:<role>``.  No head-sampling draw: a turn is a sampled
+    trace where its tracer samples every trace, else tail capture's."""
+    tracer = None if owner is None else owner[0].tracer
+    if tracer is None or not tracer.on:
+        return
+    cb = handle._callback
+    task = getattr(cb, "__self__", None)
+    if isinstance(task, asyncio.Task):
+        cb = task.get_coro()
+    tracer.record(
+        "loop_turn", start_mono=t0, end_mono=t1,
+        sampled=tracer.sample_rate >= 1.0, kind=owner[1],
+        callback=getattr(cb, "__qualname__", type(cb).__name__),
+        ms=1e3 * (t1 - t0))
+
+
+def start_loop_clock(tracer) -> None:
+    """Time every callback the loop runs from now on, if ``tracer`` (that
+    of a messenger that starts serving) keeps spans: installed once a
+    process, and never where every tracer is off."""
+    if tracer is not None and tracer.on \
+            and asyncio.events.Handle._run is not _timed_run:
+        asyncio.events.Handle._run = _timed_run
+
+
+def loop_role_now() -> str | None:
+    """The role the running task's loop time is charged to, if any."""
+    owner = LOOP_OWNER.get()
+    return None if owner is None else owner[1]
+
+
+def loop_unowned() -> float:
+    """Timed loop seconds of this process that no daemon owned."""
+    return _LOOP_S["unowned"]
+
+
+class loop_role:
+    """``with loop_role(role):`` charges the running task's loop time,
+    and that of the tasks it creates, to ``role`` of its owner (of
+    ``messenger``, where given) until the block ends."""
+
+    __slots__ = ("owner", "token")
+
+    def __init__(self, role: str, messenger=None):
+        if messenger is None:
+            messenger = (LOOP_OWNER.get() or (None,))[0]
+        self.owner = None if messenger is None else (messenger, role)
+
+    def __enter__(self):
+        _switch(self.owner)
+        self.token = LOOP_OWNER.set(self.owner)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            LOOP_OWNER.reset(self.token)
+        except ValueError:
+            pass        # a foreign Context at loop teardown, as scope's
+        _switch(LOOP_OWNER.get())
+        return False
+
+
+async def tick(interval: float) -> None:
+    """``asyncio.sleep(interval)`` for a loop that wakes on a period,
+    counting into its owner's stats how late the loop woke it:
+    ``loop_timer_fires``, ``loop_timer_late_s`` and
+    ``loop_timer_late_over_1s``, kept whether tracing is on or off."""
+    t0 = time.monotonic()
+    await asyncio.sleep(interval)
+    owner = LOOP_OWNER.get()
+    if owner is not None:
+        late = max(time.monotonic() - t0 - interval, 0.0)
+        stats = owner[0].stats
+        stats["loop_timer_fires"] += 1
+        stats["loop_timer_late_s"] += late
+        if late > 1.0:
+            stats["loop_timer_late_over_1s"] += 1
+
+
 class _OpenSpan:
     """``with tracer.span(...) as sp``: finishes the span on exit."""
 
@@ -231,6 +414,10 @@ class Tracer:
             maxlen=ring_max if ring_max else DEFAULT_RING_MAX)
         self._export: deque[Span] = deque()
         self._export_max = DEFAULT_EXPORT_MAX
+        #: who drains the export buffer: None for a tracer no MgrClient
+        #: holds (a client's; its owner drains it), else the MgrClients
+        #: holding it that have an active mgr — none, and nothing queues
+        self.drainers: set | None = None
         self._lock = threading.Lock()
         self._rng = random.Random()
         #: the tracing plane's own telemetry (exported by the
@@ -253,6 +440,11 @@ class Tracer:
         self.counters["sampler_accept" if ok else "sampler_reject"] += 1
         return ok
 
+    @property
+    def on(self) -> bool:
+        """Whether this tracer keeps any span at all."""
+        return self.sample_rate > 0 or self.tail_slow_s is not None
+
     def wants(self, sampled: bool) -> bool:
         """Whether a span of a trace with this head verdict is built at
         all: an unsampled one only for tail capture to look at."""
@@ -260,7 +452,8 @@ class Tracer:
 
     def _make_span(self, name: str, parent: Span | None,
                    ctx: TraceContext | None, tags: dict,
-                   start_mono: float | None = None) -> Span:
+                   start_mono: float | None = None,
+                   sampled: bool | None = None) -> Span:
         if parent is not None:
             if parent is INERT:
                 return INERT
@@ -273,7 +466,8 @@ class Tracer:
                 tags["reqid"] = ctx.reqid
         else:
             trace_id, parent_id = _next_id(), None
-            sampled = self._head_sample()
+            if sampled is None:
+                sampled = self._head_sample()
         if not self.wants(sampled):
             return INERT
         now = time.monotonic()
@@ -308,11 +502,13 @@ class Tracer:
 
     def record(self, name: str, *, parent: Span | None = None,
                ctx: TraceContext | None = None, start_mono: float,
-               end_mono: float, **tags) -> Span:
+               end_mono: float, sampled: bool | None = None,
+               **tags) -> Span:
         """File a finished span whose interval is already known: a wait
         (only known once it has ended) or a phase a worker thread ran
-        (it cannot hold a context manager across the loop)."""
-        sp = self._make_span(name, parent, ctx, tags, start_mono)
+        (it cannot hold a context manager across the loop).  A root
+        given ``sampled`` takes that verdict and draws none."""
+        sp = self._make_span(name, parent, ctx, tags, start_mono, sampled)
         if sp is not INERT:
             sp.end_mono = end_mono
             sp.duration = max(end_mono - start_mono, 0.0)
@@ -345,7 +541,8 @@ class Tracer:
                 self.counters["spans_dropped"] += 1
             self._ring.append(sp)
             self.counters["spans_recorded"] += 1
-            if sp.sampled or slow:
+            if (sp.sampled or slow) and (self.drainers is None
+                                         or self.drainers):
                 if len(self._export) >= self._export_max:
                     self._export.popleft()
                     self.counters["export_dropped"] += 1

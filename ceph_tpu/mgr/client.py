@@ -28,6 +28,7 @@ import json
 import logging
 import time
 
+from ceph_tpu.common import tracing
 from ceph_tpu.msg.messages import MMgrOpen, MMgrReport
 
 log = logging.getLogger("ceph_tpu.mgr")
@@ -58,8 +59,12 @@ class MgrClient:
         # tracers whose export buffers this client drains into each
         # report (the daemon's own + shared rings like the device-
         # launch profiler); drained spans ride MMgrReport.spans to the
-        # mgr's TraceCollector
+        # mgr's TraceCollector; they queue spans for export only while
+        # one of their MgrClients has an active mgr to send them to
         self.tracers = tuple(tracers)
+        for t in self.tracers:
+            if t.drainers is None:
+                t.drainers = set()
         # set by MMgrConfigure from the active mgr: outlier detection
         # flagged this daemon slow — its scrub scheduler defers
         # background scrubs while the flag holds
@@ -83,6 +88,7 @@ class MgrClient:
 
     async def stop(self) -> None:
         self._stopping = True
+        self._set_exporting(False)
         if self._task is not None:
             self._task.cancel()
             self._task = None
@@ -104,6 +110,15 @@ class MgrClient:
         old_gid = ((old or {}).get("active") or {}).get("gid")
         if new_gid != old_gid:
             self._conn = None  # lazily re-dialed by the next tick
+        self._set_exporting(
+            not self._stopping and self._active_addr() is not None)
+
+    def _set_exporting(self, on: bool) -> None:
+        for t in self.tracers:
+            if on:
+                t.drainers.add(self)
+            else:
+                t.drainers.discard(self)
 
     def handle_configure(self, msg) -> None:
         """MMgrConfigure from the active mgr: report-period tuning +
@@ -123,7 +138,7 @@ class MgrClient:
     async def _report_loop(self) -> None:
         interval = self.conf["mgr_report_interval"]
         while not self._stopping:
-            await asyncio.sleep(interval)
+            await tracing.tick(interval)
             try:
                 await self._report_once()
             except asyncio.CancelledError:

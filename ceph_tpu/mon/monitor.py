@@ -31,6 +31,7 @@ import itertools
 import logging
 import time
 
+from ceph_tpu.common.tracing import loop_role
 from ceph_tpu.crush.types import CrushMap
 from ceph_tpu.msg.messages import (
     MConfig,
@@ -208,67 +209,69 @@ class Monitor(OSDMonitorMixin, StatsServiceMixin, MgrServiceMixin,
     # -- lifecycle -----------------------------------------------------
 
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self.addr = await self.messenger.bind(host, port)
-        sock_path = self.conf["admin_socket"]
-        if sock_path:
-            from ceph_tpu.common import AdminSocket
+        # all of a mon's own work is background loop time
+        with loop_role("background", self.messenger):
+            self.addr = await self.messenger.bind(host, port)
+            sock_path = self.conf["admin_socket"]
+            if sock_path:
+                from ceph_tpu.common import AdminSocket
 
-            self._admin = AdminSocket(
-                sock_path.replace("$id", f"mon{self.rank}")
-            )
-            self._admin.register(
-                "config show", "effective configuration",
-                lambda cmd: self.conf.show(),
-            )
-            self._admin.register(
-                "quorum_status", "election/quorum state",
-                lambda cmd: {
-                    "rank": self.rank,
-                    "leader": self.paxos.leader,
-                    "election_epoch": self.paxos.election_epoch,
-                    "quorum": sorted(self.paxos.quorum),
-                    "last_committed": self.paxos.last_committed,
-                },
-            )
-            self._admin.register(
-                "status", "cluster status",
-                lambda cmd: {
-                    "epoch": self.osdmap.epoch,
-                    "num_pools": len(self.osdmap.pools),
-                },
-            )
-            self._admin.register(
-                "dump_chaos", "chaos-engine event counters + recent "
-                "event spans (process-wide, ceph_tpu/chaos)",
-                lambda cmd: __import__(
-                    "ceph_tpu.chaos", fromlist=["dump_chaos"]
-                ).dump_chaos(),
-            )
-            self._admin.register(
-                "dump_traces", "recent spans (blkin/otel role)",
-                lambda cmd: self.tracer.dump(),
-            )
-            self._admin.register(
-                "dump_log", "cluster-log/health-history service state "
-                "(ring sizes, mute book, per-entity seqs)",
-                lambda cmd: self.dump_log_service(),
-            )
-            self._admin.register(
-                "perf dump", "dump perf counters",
-                lambda cmd: {**self.perf.dump(),
-                             **self.messenger.perf_dump()},
-            )
-            await self._admin.start()
-        await self._replay()
-        self._start_mgr_tick()
-        self._start_health_tick()
-        self.mgr_client.start()
-        if self.beacon_grace > 0:
-            self._tick_task = asyncio.ensure_future(self._tick())
-        if self.conf["mon_pg_autoscale_interval"] > 0:
-            self._autoscale_task = asyncio.ensure_future(
-                self._autoscale_tick())
-        return self.addr
+                self._admin = AdminSocket(
+                    sock_path.replace("$id", f"mon{self.rank}")
+                )
+                self._admin.register(
+                    "config show", "effective configuration",
+                    lambda cmd: self.conf.show(),
+                )
+                self._admin.register(
+                    "quorum_status", "election/quorum state",
+                    lambda cmd: {
+                        "rank": self.rank,
+                        "leader": self.paxos.leader,
+                        "election_epoch": self.paxos.election_epoch,
+                        "quorum": sorted(self.paxos.quorum),
+                        "last_committed": self.paxos.last_committed,
+                    },
+                )
+                self._admin.register(
+                    "status", "cluster status",
+                    lambda cmd: {
+                        "epoch": self.osdmap.epoch,
+                        "num_pools": len(self.osdmap.pools),
+                    },
+                )
+                self._admin.register(
+                    "dump_chaos", "chaos-engine event counters + recent "
+                    "event spans (process-wide, ceph_tpu/chaos)",
+                    lambda cmd: __import__(
+                        "ceph_tpu.chaos", fromlist=["dump_chaos"]
+                    ).dump_chaos(),
+                )
+                self._admin.register(
+                    "dump_traces", "recent spans (blkin/otel role)",
+                    lambda cmd: self.tracer.dump(),
+                )
+                self._admin.register(
+                    "dump_log", "cluster-log/health-history service state "
+                    "(ring sizes, mute book, per-entity seqs)",
+                    lambda cmd: self.dump_log_service(),
+                )
+                self._admin.register(
+                    "perf dump", "dump perf counters",
+                    lambda cmd: {**self.perf.dump(),
+                                 **self.messenger.perf_dump()},
+                )
+                await self._admin.start()
+            await self._replay()
+            self._start_mgr_tick()
+            self._start_health_tick()
+            self.mgr_client.start()
+            if self.beacon_grace > 0:
+                self._tick_task = asyncio.ensure_future(self._tick())
+            if self.conf["mon_pg_autoscale_interval"] > 0:
+                self._autoscale_task = asyncio.ensure_future(
+                    self._autoscale_tick())
+            return self.addr
 
     async def _replay(self) -> None:
         """Restart recovery: install the persisted snapshot (if any),
@@ -551,74 +554,75 @@ class Monitor(OSDMonitorMixin, StatsServiceMixin, MgrServiceMixin,
     async def _dispatch(self, msg: Message) -> None:
         from ceph_tpu.mon.paxos import MMonElection, MMonPaxos
 
-        if isinstance(msg, MMonElection):
-            await self.paxos.handle_election(msg, msg.src[1])
-        elif isinstance(msg, MMonPaxos):
-            await self.paxos.handle_paxos(msg, msg.src[1])
-        elif isinstance(msg, MOSDBoot):
-            await self._handle_boot(msg)
-        elif isinstance(msg, MOSDBeacon):
-            if self.is_leader:
-                self._last_beacon[msg.osd] = time.monotonic()
-                if msg.pg_stats:
-                    self._ingest_pg_stats(msg.osd, msg.epoch, msg.pg_stats)
-                if msg.statfs:
-                    await self._ingest_statfs(msg.osd, msg.statfs)
-                om = self.osdmap
-                if (0 <= msg.osd < om.max_osd and om.exists(msg.osd)
-                        and (not om.is_up(msg.osd)
-                             or msg.epoch < om.epoch)):
-                    # a beacon from an OSD the map says is DOWN, or one
-                    # whose epoch lags the current map: it is alive but
-                    # never saw the newer epochs (publish raced its
-                    # reboot, a false failure report landed while its
-                    # subscription was being re-established, or a netem
-                    # fault on the mon link made a publish fail and
-                    # popped it from _subscribers).  Hand it the
-                    # catch-up payload so the "map says I'm down;
-                    # re-booting" defense can fire / the stale daemon
-                    # converges — without this the daemon beacons into
-                    # the void forever and its PGs wedge in peering or
-                    # report clean at a dead epoch (soak-chaos-found;
-                    # stale-epoch arm chaos-fuzz-found, control-net).
-                    if msg.src == ("osd", msg.osd):
-                        # the beacon proves this path is healthy again:
-                        # re-register the subscription a failed publish
-                        # dropped (peon-forwarded beacons carry the
-                        # peon's conn — don't register those)
-                        self._subscribers[msg.src] = msg.conn
-                    try:
-                        await msg.conn.send_message(
-                            self._maps_since(msg.epoch))
-                    except (ConnectionError, OSError):
-                        pass
-            else:
-                await self._forward_to_leader(msg)
-        elif isinstance(msg, MOSDFailure):
-            await self._handle_failure(msg)
-        elif isinstance(msg, MLog):
-            await self._handle_log(msg)
-        elif isinstance(msg, MMgrBeacon):
-            await self._handle_mgr_beacon(msg)
-        elif isinstance(msg, MMonMgrReport):
-            await self._handle_mgr_report(msg)
-        elif isinstance(msg, MMonSubscribe):
-            self._subscribers[msg.src] = msg.conn
-            await msg.conn.send_message(self._maps_since(msg.start_epoch))
-            await msg.conn.send_message(self._mgr_map_msg())
-            secs = self._config_sections_for(msg.src)
-            if secs:
-                await msg.conn.send_message(MConfig(sections=secs))
-        elif isinstance(msg, MOSDScrubReply):
-            fut = self._scrub_waiters.get(msg.tid)
-            if fut and not fut.done():
-                fut.set_result(msg)
-        elif isinstance(msg, MMonCommand):
-            code, rs, data = await self._command(
-                msg.cmd, caps=getattr(msg.conn, "peer_caps", None))
-            await msg.conn.send_message(
-                MMonCommandAck(tid=msg.tid, code=code, rs=rs, data=data)
-            )
+        with loop_role("background"):
+            if isinstance(msg, MMonElection):
+                await self.paxos.handle_election(msg, msg.src[1])
+            elif isinstance(msg, MMonPaxos):
+                await self.paxos.handle_paxos(msg, msg.src[1])
+            elif isinstance(msg, MOSDBoot):
+                await self._handle_boot(msg)
+            elif isinstance(msg, MOSDBeacon):
+                if self.is_leader:
+                    self._last_beacon[msg.osd] = time.monotonic()
+                    if msg.pg_stats:
+                        self._ingest_pg_stats(msg.osd, msg.epoch, msg.pg_stats)
+                    if msg.statfs:
+                        await self._ingest_statfs(msg.osd, msg.statfs)
+                    om = self.osdmap
+                    if (0 <= msg.osd < om.max_osd and om.exists(msg.osd)
+                            and (not om.is_up(msg.osd)
+                                 or msg.epoch < om.epoch)):
+                        # a beacon from an OSD the map says is DOWN, or one
+                        # whose epoch lags the current map: it is alive but
+                        # never saw the newer epochs (publish raced its
+                        # reboot, a false failure report landed while its
+                        # subscription was being re-established, or a netem
+                        # fault on the mon link made a publish fail and
+                        # popped it from _subscribers).  Hand it the
+                        # catch-up payload so the "map says I'm down;
+                        # re-booting" defense can fire / the stale daemon
+                        # converges — without this the daemon beacons into
+                        # the void forever and its PGs wedge in peering or
+                        # report clean at a dead epoch (soak-chaos-found;
+                        # stale-epoch arm chaos-fuzz-found, control-net).
+                        if msg.src == ("osd", msg.osd):
+                            # the beacon proves this path is healthy again:
+                            # re-register the subscription a failed publish
+                            # dropped (peon-forwarded beacons carry the
+                            # peon's conn — don't register those)
+                            self._subscribers[msg.src] = msg.conn
+                        try:
+                            await msg.conn.send_message(
+                                self._maps_since(msg.epoch))
+                        except (ConnectionError, OSError):
+                            pass
+                else:
+                    await self._forward_to_leader(msg)
+            elif isinstance(msg, MOSDFailure):
+                await self._handle_failure(msg)
+            elif isinstance(msg, MLog):
+                await self._handle_log(msg)
+            elif isinstance(msg, MMgrBeacon):
+                await self._handle_mgr_beacon(msg)
+            elif isinstance(msg, MMonMgrReport):
+                await self._handle_mgr_report(msg)
+            elif isinstance(msg, MMonSubscribe):
+                self._subscribers[msg.src] = msg.conn
+                await msg.conn.send_message(self._maps_since(msg.start_epoch))
+                await msg.conn.send_message(self._mgr_map_msg())
+                secs = self._config_sections_for(msg.src)
+                if secs:
+                    await msg.conn.send_message(MConfig(sections=secs))
+            elif isinstance(msg, MOSDScrubReply):
+                fut = self._scrub_waiters.get(msg.tid)
+                if fut and not fut.done():
+                    fut.set_result(msg)
+            elif isinstance(msg, MMonCommand):
+                code, rs, data = await self._command(
+                    msg.cmd, caps=getattr(msg.conn, "peer_caps", None))
+                await msg.conn.send_message(
+                    MMonCommandAck(tid=msg.tid, code=code, rs=rs, data=data)
+                )
 
     async def _forward_to_leader(self, msg: Message) -> None:
         """Peons forward state-changing daemon messages to the leader

@@ -14,6 +14,7 @@ import asyncio
 import logging
 import time
 
+from ceph_tpu.common.tracing import tick
 from ceph_tpu.ec import registry as ec_registry
 from ceph_tpu.msg.messages import (
     MOSDBoot,
@@ -333,7 +334,7 @@ class OSDMonitorMixin:
         was_leader = False
         last_tick = time.monotonic()
         while True:
-            await asyncio.sleep(self.beacon_grace / 4)
+            await tick(self.beacon_grace / 4)
             now = time.monotonic()
             starved = now - last_tick > self.beacon_grace
             last_tick = now

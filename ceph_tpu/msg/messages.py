@@ -608,7 +608,11 @@ class MOSDECSubOpRead(Message):
     repair reads ride the wire: the reply carries the concatenation of
     the runs, so a regenerating repair moves only sub_chunk_no/q of
     each helper chunk (reference ECCommon.cc:262-299 passing
-    minimum_to_decode's runs down to shard reads)."""
+    minimum_to_decode's runs down to shard reads).
+
+    ``loop_role`` is the loop role of the work that asks (an op, a
+    recovery, a scrub; ``common/tracing.py``): the shard charges its
+    handling of the read to it."""
 
     TYPE = 110
 
@@ -617,7 +621,7 @@ class MOSDECSubOpRead(Message):
         from_osd: int = 0, oid: str = "", off: int = 0, length: int = 0,
         want_attrs: bool = False, epoch: int = 0,
         extents: list[tuple[int, int]] | None = None,
-        snap: int | None = None,
+        snap: int | None = None, loop_role: str = "op",
     ):
         from ceph_tpu.osd.snaps import NOSNAP
 
@@ -627,6 +631,7 @@ class MOSDECSubOpRead(Message):
         self.extents = extents or []
         # which snap object of oid to read (NOSNAP = head shard)
         self.snap = NOSNAP if snap is None else snap
+        self.loop_role = loop_role
 
     def encode_payload(self, enc):
         enc.u64(self.tid)
@@ -642,6 +647,7 @@ class MOSDECSubOpRead(Message):
             enc.u64(o)
             enc.u64(ln)
         enc.u64(self.snap)
+        enc.str_(self.loop_role)
 
     @classmethod
     def decode_payload(cls, dec):
@@ -655,6 +661,7 @@ class MOSDECSubOpRead(Message):
             (dec.u64(), dec.u64()) for _ in range(dec.u32())
         ]
         msg.snap = dec.u64()
+        msg.loop_role = dec.str_()
         return msg
 
 

@@ -406,8 +406,12 @@ class Messenger:
         # of the cluster-wide span tree; None = no messenger spans
         # (clients of the raw messenger)
         self.tracer = None
-        # wire counters, shared with every connection's FrameStream
-        self.stats = dict.fromkeys(frames.STATS, 0)
+        # wire counters, shared with every connection's FrameStream, and
+        # the loop time charged to this entity (common/tracing.py; the
+        # ceph_tpu.common package imports this one, so not at the top)
+        from ceph_tpu.common.tracing import LOOP_STATS
+
+        self.stats = dict.fromkeys(frames.STATS + LOOP_STATS, 0)
 
     def perf_dump(self) -> dict:
         """The messenger's part of a daemon's ``perf dump``."""
@@ -430,8 +434,15 @@ class Messenger:
     # -- server side ---------------------------------------------------
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self._server = await asyncio.get_running_loop().create_server(
-            lambda: frames.FrameStream(self.stats, self._accept), host, port)
+        from ceph_tpu.common.tracing import loop_role, start_loop_clock
+
+        start_loop_clock(self.tracer)
+        # what the server accepts (its transports' reads, handshakes and
+        # reader tasks) is the messenger's own loop time
+        with loop_role("msgr", self):
+            self._server = await asyncio.get_running_loop().create_server(
+                lambda: frames.FrameStream(self.stats, self._accept),
+                host, port)
         sock = self._server.sockets[0]
         self.addr = sock.getsockname()[:2]
         return self.addr
@@ -521,23 +532,30 @@ class Messenger:
         or refuses immediately, and cancelling asyncio's sock_connect
         mid-flight leaves a stale selector registration that a reused
         fd number then trips over (the CPython _sock_write_done /
-        _ensure_fd_no_transport race — also fuzzer-found)."""
-        _, reader = await asyncio.get_running_loop().create_connection(
-            lambda: frames.FrameStream(self.stats), host, port)
-        writer = reader
-        try:
-            return await asyncio.wait_for(
-                self._handshake_out(reader, writer, host, port),
-                self.handshake_timeout)
-        except asyncio.TimeoutError:
-            writer.close()
-            raise ConnectionError(
-                f"handshake with {host}:{port} timed out") from None
-        except BaseException:
-            # handshake failure: the socket must not leak (the
-            # retrying callers re-dial every pass)
-            writer.close()
-            raise
+        _ensure_fd_no_transport race — also fuzzer-found).
+
+        The dial, the transport's reads and the reader task are the
+        messenger's own loop time, whichever task dials."""
+        from ceph_tpu.common.tracing import loop_role, start_loop_clock
+
+        start_loop_clock(self.tracer)
+        with loop_role("msgr", self):
+            _, reader = await asyncio.get_running_loop().create_connection(
+                lambda: frames.FrameStream(self.stats), host, port)
+            writer = reader
+            try:
+                return await asyncio.wait_for(
+                    self._handshake_out(reader, writer, host, port),
+                    self.handshake_timeout)
+            except asyncio.TimeoutError:
+                writer.close()
+                raise ConnectionError(
+                    f"handshake with {host}:{port} timed out") from None
+            except BaseException:
+                # handshake failure: the socket must not leak (the
+                # retrying callers re-dial every pass)
+                writer.close()
+                raise
 
     async def _handshake_out(self, reader, writer, host, port) -> Connection:
         conn = Connection(self, reader, writer)

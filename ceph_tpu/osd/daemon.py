@@ -109,6 +109,17 @@ from ceph_tpu.msg.messenger import Connection, Message, Messenger
 # operator digs a cluster out (reference: deletes pass _check_full)
 _DELETE_OPS = frozenset(
     {OP_DELETE, OP_OMAP_RMKEYS, OP_OMAP_CLEAR, OP_RMXATTR})
+# the loop role a received message's handling is charged to
+# (tracing.loop_role); any other message is background work, and a
+# sub-read is the work of whoever asked for it (its loop_role, where
+# that names a role)
+_LOOP_ROLE = {
+    **dict.fromkeys((MOSDOp, MOSDECSubOpWrite, MOSDECSubOpRead, MOSDRepOp,
+                     MOSDECSubOpWriteReply, MOSDECSubOpReadReply,
+                     MOSDRepOpReply, MOSDOpReply, MWatchNotifyAck), "op"),
+    **dict.fromkeys((MOSDPGPush, MOSDPGPushReply, MBackfillReserve,
+                     MOSDPGQuery, MOSDPGLog, MOSDPGInfo, MOSDPGLogAck),
+                    "recovery")}
 from ceph_tpu.ops.hashing import ceph_str_hash_rjenkins
 from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.mapenc import apply_map_message
@@ -407,28 +418,33 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
     # -- lifecycle -----------------------------------------------------
 
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> None:
-        self.addr = await self.messenger.bind(host, port)
-        sock_path = self.conf["admin_socket"]
-        if sock_path:
-            from ceph_tpu.common import AdminSocket
+        # the tasks it starts (beacons, heartbeats, scrubs, the tier
+        # agent, the grant sweep, the mgr report) are background work
+        with tracing.loop_role("background", self.messenger):
+            self.addr = await self.messenger.bind(host, port)
+            sock_path = self.conf["admin_socket"]
+            if sock_path:
+                from ceph_tpu.common import AdminSocket
 
-            self._admin = AdminSocket(sock_path.replace("$id", str(self.id)))
-            self._register_admin_commands(self._admin)
-            await self._admin.start()
-        await self._mon_hunt()
-        self.mgr_client.start()
-        self.clog.start()
-        if self.beacon_interval > 0:
-            self._beacon_task = asyncio.ensure_future(self._beacon())
-        if self.conf["osd_heartbeat_interval"] > 0:
-            self._hb_task = asyncio.ensure_future(self._heartbeat())
-        if self.conf["osd_scrub_interval"] > 0:
-            self._scrub_task = asyncio.ensure_future(self._scrub_scheduler())
-        if self.conf["osd_tier_agent_interval"] > 0:
-            self._tier_task = asyncio.ensure_future(self._tier_agent())
-        self._grant_sweep_task = asyncio.ensure_future(self._grant_sweep())
-        # wait for the first map so ops can be served
-        await asyncio.wait_for(self._map_event.wait(), 10)
+                self._admin = AdminSocket(
+                    sock_path.replace("$id", str(self.id)))
+                self._register_admin_commands(self._admin)
+                await self._admin.start()
+            await self._mon_hunt()
+            self.mgr_client.start()
+            self.clog.start()
+            if self.beacon_interval > 0:
+                self._beacon_task = asyncio.ensure_future(self._beacon())
+            if self.conf["osd_heartbeat_interval"] > 0:
+                self._hb_task = asyncio.ensure_future(self._heartbeat())
+            if self.conf["osd_scrub_interval"] > 0:
+                self._scrub_task = asyncio.ensure_future(
+                    self._scrub_scheduler())
+            if self.conf["osd_tier_agent_interval"] > 0:
+                self._tier_task = asyncio.ensure_future(self._tier_agent())
+            self._grant_sweep_task = asyncio.ensure_future(self._grant_sweep())
+            # wait for the first map so ops can be served
+            await asyncio.wait_for(self._map_event.wait(), 10)
 
     async def _mon_hunt(self) -> None:
         """Find a live monitor, (re)boot and (re)subscribe — the
@@ -590,7 +606,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         import json as _json
 
         while not self.stopping:
-            await asyncio.sleep(self.beacon_interval)
+            await tracing.tick(self.beacon_interval)
             try:
                 stats = b""
                 try:
@@ -747,7 +763,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         grace = self.conf["osd_heartbeat_grace"]
         last_iter = time.monotonic()
         while not self.stopping:
-            await asyncio.sleep(interval)
+            await tracing.tick(interval)
             om = self.osdmap
             if om is None:
                 continue
@@ -1494,7 +1510,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         return False
 
     def _spawn_repair_task(self, coro) -> None:
-        t = asyncio.ensure_future(coro)
+        with tracing.loop_role("recovery"):
+            t = asyncio.ensure_future(coro)
         hold = getattr(self, "_repair_tasks", None)
         if hold is None:
             hold = self._repair_tasks = set()
@@ -1627,70 +1644,75 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
     # -- dispatch ------------------------------------------------------
 
     async def _dispatch(self, msg: Message) -> None:
-        try:
-            if isinstance(msg, MOSDMap):
-                await self._handle_map(msg)
-            elif isinstance(msg, MMgrMap):
-                self.mgr_client.handle_mgr_map(msg)
-            elif isinstance(msg, MMgrConfigure):
-                self.mgr_client.handle_configure(msg)
-            elif isinstance(msg, MLogAck):
-                self.clog.handle_ack(msg)
-            elif isinstance(msg, MConfig):
-                self._apply_mon_config(msg)
-            elif isinstance(msg, MOSDPing):
-                await self._handle_ping(msg)
-            elif isinstance(msg, MWatchNotifyAck):
-                self._handle_notify_ack(msg)
-            elif isinstance(msg, MOSDOp):
-                asyncio.ensure_future(self._handle_client_op(msg))
-            elif isinstance(msg, MOSDECSubOpWrite):
-                t0 = time.monotonic()
-                await self._handle_sub_write(msg)
-                # shard apply latency — the `ceph osd perf`
-                # apply_latency source (never a TrackedOp: sub-op
-                # service must stay admission-free)
-                self.op_tracker.record_latency(
-                    "subop_w", time.monotonic() - t0)
-            elif isinstance(msg, MOSDECSubOpRead):
-                await self._handle_sub_read(msg)
-            elif isinstance(msg, MOSDRepOp):
-                t0 = time.monotonic()
-                await self._handle_rep_op(msg)
-                self.op_tracker.record_latency(
-                    "subop_w", time.monotonic() - t0)
-            elif isinstance(msg, MOSDPGPush):
-                await self._handle_push(msg)
-            elif isinstance(msg, MOSDPGQuery):
-                # peering messages may wait for map catch-up
-                # (_wait_for_epoch): run off the connection's dispatch
-                # loop so in-flight client sub-ops on the same pipe
-                # don't queue behind the wait (the reference parks
-                # these on a waiting_for_map queue the same way)
-                self._spawn_peering(self._handle_pg_query(msg))
-            elif isinstance(msg, MOSDPGLog):
-                self._spawn_peering(self._handle_pg_log(msg))
-            elif isinstance(msg, MOSDScrub):
-                asyncio.ensure_future(self._handle_scrub(msg))
-            elif isinstance(msg, MBackfillReserve):
-                await self._handle_backfill_reserve(msg)
-            elif isinstance(
-                msg,
-                (
-                    MOSDECSubOpWriteReply, MOSDECSubOpReadReply,
-                    MOSDRepOpReply, MOSDPGInfo, MOSDPGLogAck,
-                    MOSDOpReply,  # tiering: we client other pools
-                ),
-            ):
-                fut = self._waiters.get(msg.tid)
-                if fut and not fut.done():
-                    fut.set_result(msg)
-            elif isinstance(msg, MOSDPGPushReply):
-                fut = self._push_waiters.get(msg.tid)
-                if fut and not fut.done():
-                    fut.set_result(msg)
-        except Exception:
-            log.exception("osd.%d: dispatch failed for %r", self.id, msg)
+        role = _LOOP_ROLE.get(type(msg), "background")
+        if (type(msg) is MOSDECSubOpRead
+                and msg.loop_role in tracing.LOOP_ROLES):
+            role = msg.loop_role
+        with tracing.loop_role(role):
+            try:
+                if isinstance(msg, MOSDMap):
+                    await self._handle_map(msg)
+                elif isinstance(msg, MMgrMap):
+                    self.mgr_client.handle_mgr_map(msg)
+                elif isinstance(msg, MMgrConfigure):
+                    self.mgr_client.handle_configure(msg)
+                elif isinstance(msg, MLogAck):
+                    self.clog.handle_ack(msg)
+                elif isinstance(msg, MConfig):
+                    self._apply_mon_config(msg)
+                elif isinstance(msg, MOSDPing):
+                    await self._handle_ping(msg)
+                elif isinstance(msg, MWatchNotifyAck):
+                    self._handle_notify_ack(msg)
+                elif isinstance(msg, MOSDOp):
+                    asyncio.ensure_future(self._handle_client_op(msg))
+                elif isinstance(msg, MOSDECSubOpWrite):
+                    t0 = time.monotonic()
+                    await self._handle_sub_write(msg)
+                    # shard apply latency — the `ceph osd perf`
+                    # apply_latency source (never a TrackedOp: sub-op
+                    # service must stay admission-free)
+                    self.op_tracker.record_latency(
+                        "subop_w", time.monotonic() - t0)
+                elif isinstance(msg, MOSDECSubOpRead):
+                    await self._handle_sub_read(msg)
+                elif isinstance(msg, MOSDRepOp):
+                    t0 = time.monotonic()
+                    await self._handle_rep_op(msg)
+                    self.op_tracker.record_latency(
+                        "subop_w", time.monotonic() - t0)
+                elif isinstance(msg, MOSDPGPush):
+                    await self._handle_push(msg)
+                elif isinstance(msg, MOSDPGQuery):
+                    # peering messages may wait for map catch-up
+                    # (_wait_for_epoch): run off the connection's dispatch
+                    # loop so in-flight client sub-ops on the same pipe
+                    # don't queue behind the wait (the reference parks
+                    # these on a waiting_for_map queue the same way)
+                    self._spawn_peering(self._handle_pg_query(msg))
+                elif isinstance(msg, MOSDPGLog):
+                    self._spawn_peering(self._handle_pg_log(msg))
+                elif isinstance(msg, MOSDScrub):
+                    asyncio.ensure_future(self._handle_scrub(msg))
+                elif isinstance(msg, MBackfillReserve):
+                    await self._handle_backfill_reserve(msg)
+                elif isinstance(
+                    msg,
+                    (
+                        MOSDECSubOpWriteReply, MOSDECSubOpReadReply,
+                        MOSDRepOpReply, MOSDPGInfo, MOSDPGLogAck,
+                        MOSDOpReply,  # tiering: we client other pools
+                    ),
+                ):
+                    fut = self._waiters.get(msg.tid)
+                    if fut and not fut.done():
+                        fut.set_result(msg)
+                elif isinstance(msg, MOSDPGPushReply):
+                    fut = self._push_waiters.get(msg.tid)
+                    if fut and not fut.done():
+                        fut.set_result(msg)
+            except Exception:
+                log.exception("osd.%d: dispatch failed for %r", self.id, msg)
 
     async def _handle_map(self, msg: MOSDMap) -> None:
         # copy-on-write swap: code that captured self.osdmap mid-pass
@@ -1733,7 +1755,9 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             except (ConnectionError, OSError):
                 pass  # mon hunt will re-boot us
         if self._recovery_task is None or self._recovery_task.done():
-            self._recovery_task = asyncio.ensure_future(self._recover_all())
+            with tracing.loop_role("recovery"):
+                self._recovery_task = asyncio.ensure_future(
+                    self._recover_all())
 
     def _apply_mon_config(self, msg: MConfig) -> None:
         """Centralized config distribution (MConfig/ConfigMonitor):

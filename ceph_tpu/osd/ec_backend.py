@@ -1128,6 +1128,7 @@ class ECBackendMixin:
                 tid=tid, pg=pg, shard=shard, from_osd=self.id, oid=oid,
                 off=off, length=length, want_attrs=True, epoch=self.epoch,
                 extents=extents or [], snap=snap,
+                loop_role=tracing.loop_role_now() or "op",
             ), tid)
         if rep.result != 0:
             return None, None, -rep.result

@@ -389,7 +389,7 @@ class RecoveryMixin:
             period = max(0.25, min(timeout / 4 if timeout > 0 else 15.0,
                                    15.0))
             try:
-                await asyncio.sleep(period)
+                await tracing.tick(period)
             except asyncio.CancelledError:
                 return
             try:
@@ -1684,7 +1684,8 @@ class RecoveryMixin:
             finally:
                 pend.discard(key)
 
-        t = asyncio.ensure_future(_retry())
+        with tracing.loop_role("recovery"):
+            t = asyncio.ensure_future(_retry())
         hold = getattr(self, "_repair_tasks", None)
         if hold is None:
             hold = self._repair_tasks = set()
@@ -2030,7 +2031,8 @@ class RecoveryMixin:
     def _spawn_peering(self, coro) -> None:
         """Run a peering handler as its own task, strongly referenced
         (the loop holds tasks weakly)."""
-        task = asyncio.ensure_future(coro)
+        with tracing.loop_role("recovery"):
+            task = asyncio.ensure_future(coro)
         tasks = getattr(self, "_peering_tasks", None)
         if tasks is None:
             tasks = self._peering_tasks = set()
@@ -2160,6 +2162,7 @@ class RecoveryMixin:
         rep = await self._sub_op(osd, MOSDECSubOpRead(
             tid=tid, pg=pg, shard=shard, from_osd=self.id, oid=oid,
             off=0, length=1, want_attrs=True, epoch=self.epoch,
+            loop_role=tracing.loop_role_now() or "op",
         ), tid)
         if rep.result != 0:
             return None, None

@@ -163,6 +163,30 @@ class TestPrometheusExposition:
             assert f"# TYPE {metric} {typ}" in text, (name, typ)
             assert f"\n{metric} " in "\n" + text, name
 
+    def test_spans_queue_for_export_only_while_a_mgr_is_active(self):
+        """A tracer a MgrClient holds queues its spans for the mgr only
+        while the MgrMap names an active one; the ring keeps them all."""
+        from ceph_tpu.common import ConfigProxy
+        from ceph_tpu.common.tracing import Tracer
+        from ceph_tpu.mgr.client import MgrClient
+        from ceph_tpu.msg.messages import MMgrMap
+
+        def mgr_map(active) -> MMgrMap:
+            return MMgrMap(blob=json.dumps({"active": active}).encode())
+
+        t = Tracer("osd.78", sample_rate=1.0)
+        mc = MgrClient("osd.78", None, ConfigProxy(), dict, tracers=(t,))
+        for name, active in (("no_map", None),
+                             ("active", {"gid": 7, "addr": ["h", 1]}),
+                             ("none_active", None)):
+            if name != "no_map":
+                mc.handle_mgr_map(mgr_map(active))
+            with t.span(name):
+                pass
+        assert [s["name"] for s in t.drain_export()] == ["active"]
+        assert t.counters["spans_recorded"] == 3
+        assert t.counters["spans_exported"] == 1
+
     def test_osd_report_carries_tracing_counters(self):
         """The OSD's _mgr_collect (the MMgrReport raw material) must
         include the tracer's telemetry and the slow-op counts the
