@@ -133,6 +133,7 @@ from ceph_tpu.osd.pglog import (
     eversion_t,
     pg_log_entry_t,
 )
+from ceph_tpu.osd.pgstats import PgStatTally
 from ceph_tpu.osd.snaps import (
     NOSNAP,
     SNAPS_ATTR,
@@ -229,6 +230,8 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             "ms_inject_socket_failures"
         ]
         self.perf = get_perf_counters(f"osd.{osd_id}")
+        # each led PG's objects and bytes, from what transactions change
+        self._pg_tally = PgStatTally(self.store, self.perf)
         from ceph_tpu.common import DoutLogger, OpTracker
         from ceph_tpu.common.tracing import Tracer
 
@@ -626,14 +629,23 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         report (reference src/mgr/DaemonServer.cc aggregation source).
         States mirror the reference's pg_state_t vocabulary at the
         granularity this OSD can see: active+clean, active+degraded
-        (acting set has holes or down members), active+recovering."""
+        (acting set has holes or down members), active+recovering.
+        ``objects`` and ``bytes`` are the tallies of ``_pg_tally``
+        (osd/pgstats.py), which re-stats only what transactions
+        changed since the last report."""
         import json as _json
 
         om = self.osdmap
         if om is None:
             return b""
         out = {}
+        led: set[coll_t] = set()
         for pid, pool in om.pools.items():
+            k = 1
+            if pool.is_erasure():
+                # shard bytes -> logical bytes (k data shards)
+                k = int(om.erasure_code_profiles.get(
+                    pool.erasure_code_profile, {}).get("k", 1) or 1)
             for ps in range(pool.pg_num):
                 pg = pg_t(pid, ps)
                 up, _up, acting, primary = om.pg_to_up_acting_osds(
@@ -658,31 +670,20 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     (s for s, o in enumerate(acting) if o == self.id),
                     None,
                 )
-                n_obj = 0
-                n_bytes = 0
+                n_obj = n_bytes = 0
                 if my_shard is not None:
-                    shard = my_shard if pool.is_erasure() else NO_SHARD
-                    names = self._local_objects(pool, pg, shard)
-                    n_obj = len(names)
-                    c = self._shard_coll(pool, pg, shard)
-                    for nm in names:
-                        try:
-                            n_bytes += self.store.stat(c, ghobject_t(nm))
-                        except FileNotFoundError:
-                            continue
-                    if pool.is_erasure():
-                        # shard bytes -> logical bytes (k data shards)
-                        k = int(self.osdmap.erasure_code_profiles.get(
-                            pool.erasure_code_profile, {}).get("k", 1)
-                            or 1)
-                        n_bytes *= k
+                    c = self._shard_coll(
+                        pool, pg, my_shard if pool.is_erasure() else NO_SHARD)
+                    led.add(c)
+                    n_obj, n_bytes = self._pg_tally.usage(c)
                 out[f"{pid}.{ps}"] = {
-                    "state": state, "objects": n_obj, "bytes": n_bytes,
+                    "state": state, "objects": n_obj, "bytes": n_bytes * k,
                     # upmap/reweight moved this pg off its CRUSH-ideal
                     # home: objects are misplaced (not missing) — the
                     # mgr progress module's rebalance-event source
                     "misplaced": (not degraded and up != acting),
                 }
+        self._pg_tally.retain(led)
         return _json.dumps(out).encode()
 
     def _mgr_collect(self) -> dict:
@@ -1243,10 +1244,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         the parent's, and a sum of the store stage's spans stays a sum
         of commits."""
         if not getattr(self.store, "blocking_commit", False):
-            self.store.queue_transaction(t)
+            self._apply_txn(t)
             return
         if parent_span is None or parent_span is tracing.INERT:
-            await asyncio.to_thread(self.store.queue_transaction, t)
+            await asyncio.to_thread(self._apply_txn, t)
             self._count_commit(t.marks)
             return
         submitted = time.monotonic()
@@ -1254,7 +1255,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         def run() -> None:
             started = time.monotonic()
             try:
-                self.store.queue_transaction(t)
+                self._apply_txn(t)
             finally:
                 # filed here, on the worker thread: the loop thread is
                 # the bottleneck and pays for neither span
@@ -1283,6 +1284,16 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
 
         await asyncio.to_thread(run)
         self._count_commit(t.marks)
+
+    def _apply_txn(self, t: Transaction) -> None:
+        """The one call by which this OSD changes its store: apply ``t``,
+        then note what it named for the PG stat tallies, on whichever
+        thread applied it (noted even if the store raised: a name noted
+        in vain costs one stat)."""
+        try:
+            self.store.queue_transaction(t)
+        finally:
+            self._pg_tally.note(t)
 
     def _count_commit(self, marks: dict) -> None:
         """What a store that counts its writes (BlockStore) stamped
@@ -1907,7 +1918,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         t.truncate(self._META_COLL, ghobject_t(self._META_OID), len(blob))
         t.write(self._META_COLL, ghobject_t(self._META_OID), 0, blob)
         try:
-            self.store.queue_transaction(t)
+            self._apply_txn(t)
         except OSError:
             log.exception("osd.%d: persisting past intervals failed", self.id)
 
@@ -2030,7 +2041,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
             for o in meta_objs:
                 t.remove(c, o)
             t.remove_collection(c)
-            self.store.queue_transaction(t)
+            self._apply_txn(t)
             self._pg_logs.pop(c, None)
             self._clean_epoch.pop((pool.id, c.ps), None)
         return moved
@@ -2077,7 +2088,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                         object_to_pg(pool, oid)).ps == _ps,
                 )
             if not t.empty():
-                self.store.queue_transaction(t)
+                self._apply_txn(t)
         return moved
 
     def _gc_removed_pools(self, old_map, new_map) -> None:
@@ -2105,7 +2116,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     t.remove_collection(c)
                     self._pg_logs.pop(c, None)
             if not t.empty():
-                self.store.queue_transaction(t)
+                self._apply_txn(t)
                 log.info("osd.%d: removed collections of deleted pools %s",
                          self.id, sorted(gone))
         except Exception:

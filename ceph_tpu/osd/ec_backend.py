@@ -615,7 +615,7 @@ class ECBackendMixin:
         """Apply a shard write + (when versioned) its pg-log entry in
         ONE transaction — the reference couples data and log the same
         way (ECTransaction appends log entries to the shard txn)."""
-        self.store.queue_transaction(
+        self._apply_txn(
             self._shard_write_txn(pool, pg, shard, oid, payload, attrs,
                                   delete, version, off, truncate, rmattrs,
                                   reqid)

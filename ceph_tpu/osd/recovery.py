@@ -548,7 +548,7 @@ class RecoveryMixin:
                     "epoch": self.epoch,
                 }).encode(),
             })
-        self.store.queue_transaction(t)
+        self._apply_txn(t)
 
     def _local_objects(self, pool, pg, shard) -> list[str]:
         c = self._shard_coll(pool, pg, shard)
@@ -706,7 +706,7 @@ class RecoveryMixin:
                         lg.append(t, e)
             self._pg_log_trim(t, lg)
             if not t.empty():
-                self.store.queue_transaction(t)
+                self._apply_txn(t)
 
         # scope; prior intervals force the backfill enumeration — the
         # data may live entirely on members our log knows nothing
@@ -835,7 +835,7 @@ class RecoveryMixin:
                                 objs.add(e.oid)
                     self._pg_log_trim(t2, lg)
                     if not t2.empty():
-                        self.store.queue_transaction(t2)
+                        self._apply_txn(t2)
             if chain_grew:
                 self._save_past_acting()  # one write after the drain
             auth = max(lus, key=lambda k: lus[k])
@@ -1000,7 +1000,7 @@ class RecoveryMixin:
                 t_fl = Transaction()
                 lg.clear_contig_floor(t_fl)
                 if not t_fl.empty():
-                    self.store.queue_transaction(t_fl)
+                    self._apply_txn(t_fl)
         # only a FULLY verified pass (every object confirmed on every
         # target) may forget the prior intervals — a swallowed push
         # failure must keep the old home reachable for the retry
@@ -1018,7 +1018,7 @@ class RecoveryMixin:
                     if self._merge_pending(sc, slg):
                         t3 = Transaction()
                         t3.omap_rmkeys(sc, slg.meta, ["merge_pending"])
-                        self.store.queue_transaction(t3)
+                        self._apply_txn(t3)
                 for s, o in set(pairs) | set(prior):
                     if o == self.id:
                         continue
@@ -1810,7 +1810,7 @@ class RecoveryMixin:
                         t.write(c, co, 0, payload)
                     if attrs:
                         t.setattrs(c, co, dict(attrs))
-                    self.store.queue_transaction(t)
+                    self._apply_txn(t)
                     continue
                 try:
                     await self._push(
@@ -2096,7 +2096,7 @@ class RecoveryMixin:
             # superposition is resolved, normal stray semantics resume
             tcm = Transaction()
             tcm.omap_rmkeys(c, lg.meta, ["merge_pending"])
-            self.store.queue_transaction(tcm)
+            self._apply_txn(tcm)
         entries = [e.encode() for e in lg.entries_after(msg.since)]
         objects: list[tuple[str, bytes]] = []
         if msg.want_objects and self.store.collection_exists(c):
@@ -2144,7 +2144,7 @@ class RecoveryMixin:
         )
         self._pg_log_trim(t, lg)
         if not t.empty():
-            self.store.queue_transaction(t)
+            self._apply_txn(t)
         await msg.conn.send_message(MOSDPGLogAck(
             tid=msg.tid, pg=msg.pg, shard=msg.shard, from_osd=self.id,
             result=0, epoch=self.epoch,

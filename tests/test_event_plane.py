@@ -7,6 +7,7 @@ and the chaos ``check_events`` invariant on hand-built observations
 from __future__ import annotations
 
 import asyncio
+import time
 
 from ceph_tpu.common import ConfigProxy
 from ceph_tpu.common.crash import (
@@ -319,8 +320,11 @@ class _FakeMgr:
     def set_degraded(self, per_osd: dict[str, int],
                      metric: str = "pgs_degraded") -> None:
         for d, n in per_osd.items():
-            self.sessions.setdefault(d, {"gauges": {}})[
-                "gauges"][metric] = float(n)
+            sess = self.sessions.setdefault(d, {"gauges": {}})
+            sess["gauges"][metric] = float(n)
+            # a fresh report, as the mgr stamps one: the module counts
+            # only sessions that reported lately
+            sess["last_report"] = time.monotonic()
 
 
 class TestProgressModule:

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 
 import numpy as np
 import pytest
@@ -221,6 +222,7 @@ class TestPrometheusExposition:
         mgr.sessions["osd.0"] = {
             "counters": {}, "histograms": {}, "status": {},
             "reports": 1, "gauges": {"pgs_degraded": 4.0},
+            "last_report": time.monotonic(),
         }
         record_crash(conf, "osd.0", reason="test")
 
