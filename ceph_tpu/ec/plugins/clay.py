@@ -45,15 +45,18 @@ k+m-1) keeps the sequential path — its pair fills read another
 plane's U mid-level.
 
 Served device path: every step above is a GF(2^8)-linear map applied to
-each byte column alike, so encode and the single-chunk repair of each
-node are ONE matrix over sub-chunk rows: :meth:`ErasureCodeClay.
-encode_matrix` (m*alpha, k*alpha) on the data chunks' sub-chunks and
-:meth:`ErasureCodeClay.repair_matrix` (alpha, d*alpha/q) on the helpers'
-repair sub-chunks (alpha = sub_chunk_no).  Each is read off this host
-path once, by encoding or repairing an identity, and then travels like
-any scalar code's matrix: osd/ecutil.py files it with the encode service
-and the decode aggregator (parallel/), which coalesce, pad, launch and
-prewarm it.  The host path stays for several losses and for d < k+m-1.
+each byte column alike, so encode, the single-chunk repair of each node
+and the full decode of each erasure set are ONE matrix over sub-chunk
+rows: :meth:`ErasureCodeClay.encode_matrix` (m*alpha, k*alpha) on the
+data chunks' sub-chunks, :meth:`ErasureCodeClay.repair_matrix` (alpha,
+d*alpha/q) on the helpers' repair sub-chunks and
+:meth:`ErasureCodeClay.subchunk_decode_plan`'s (alpha*lost, alpha*k) on
+the fetched chunks' sub-chunks (alpha = sub_chunk_no).  Each is read off
+this host path once, by encoding, repairing or decoding an identity, and
+then travels like any scalar code's matrix: osd/ecutil.py files it with
+the encode service and the decode aggregator (parallel/), which
+coalesce, pad, launch and prewarm it.  The host path stays for
+recovery's several losses and whole-chunk reads, and for d < k+m-1.
 """
 
 from __future__ import annotations
@@ -347,6 +350,33 @@ class ErasureCodeClay(ErasureCode):
             return np.ascontiguousarray(
                 out[lost].reshape(self.sub_chunk_no, n))
         return self._linear_map(("repair", lost), derive)
+
+    def subchunk_decode_plan(
+        self, want: set[int], available: set[int]
+    ) -> tuple[list[int], list[int], np.ndarray | None]:
+        """What the full decode of ``want`` from whole chunks of
+        ``available`` reads and rebuilds, as one matrix: ``(fetched,
+        lost, D)`` with ``fetched`` minimum_to_decode's chunks in chunk
+        order, ``lost`` the chunks of ``want`` not among them, and D
+        (alpha * |lost|, alpha * |fetched|) over GF(2^8): row ``i *
+        alpha + z`` is sub-chunk z of ``lost[i]``, column ``f * alpha +
+        z'`` sub-chunk z' of ``fetched[f]``.  The host layered decode of
+        an identity, once a process per (lost, fetched); None where
+        nothing is lost."""
+        fetched = sorted(self.minimum_to_decode(set(want), set(available)))
+        lost = sorted(set(want) - set(fetched))
+        if not lost:
+            return fetched, lost, None
+
+        def derive() -> np.ndarray:
+            a, n = self.sub_chunk_no, len(fetched) * self.sub_chunk_no
+            chunks = {c: _identity_rows(a, f * a, n)
+                      for f, c in enumerate(fetched)}
+            out = self._decode(set(lost), chunks)
+            return np.ascontiguousarray(np.concatenate([
+                out[c].reshape(a, n) for c in lost]))
+        return fetched, lost, self._linear_map(
+            ("decode", tuple(lost), tuple(fetched)), derive)
 
     # -- encode / decode entry points ----------------------------------------
 

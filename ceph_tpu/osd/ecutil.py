@@ -302,25 +302,66 @@ async def encode_async(
     return out
 
 
+async def _decode_subchunks_async(
+    sinfo, ec_impl, to_decode, service
+) -> dict[int, np.ndarray] | None:
+    """A read's k data chunks, those it lost rebuilt from the fetched
+    chunks' sub-chunk rows as ONE matrix filed with the encode service
+    (``subchunk_decode_plan``); None = the caller takes the host path."""
+    k = ec_impl.get_data_chunk_count()
+    fetched, lost, D = ec_impl.subchunk_decode_plan(
+        set(range(k)), set(to_decode))
+    out = {c: np.asarray(to_decode[c]).reshape(-1)
+           for c in range(k) if c in to_decode}
+    if not lost:
+        return out
+    if not _service_takes(service):
+        return None
+    alpha = ec_impl.get_sub_chunk_count()
+    sc = sinfo.chunk_size // alpha
+    rows = np.concatenate([
+        _to_subchunk_rows(np.asarray(to_decode[c]).reshape(-1), alpha, sc)
+        for c in fetched])
+    rec = await service.apply(D, rows, kind="subchunk_decode")
+    for i, c in enumerate(lost):
+        out[c] = _from_subchunk_rows(rec[i * alpha:(i + 1) * alpha], sc)
+    return out
+
+
 async def decode_concat_async(
     sinfo: StripeInfo,
     ec_impl: ErasureCodeInterface,
     to_decode: Mapping[int, np.ndarray],
     *,
     service=None,
+    how: dict | None = None,
 ) -> np.ndarray:
-    """:func:`decode_concat` with farm-batched reconstruction."""
-    rec = await _decode_chunks_async(sinfo, ec_impl, to_decode,
-                                     range(ec_impl.get_data_chunk_count()),
-                                     service=service)
+    """:func:`decode_concat` with the lost data chunks rebuilt on the
+    device: a scalar code's by its decode matrix, a vector code's as one
+    matrix over sub-chunk rows.  ``how``, where given, is filled with
+    what served it: ``path`` (``"device"``, ``"host"`` or ``"none"``:
+    every data chunk was fetched), ``lost`` (the data chunks rebuilt)
+    and ``rebuilt_bytes``."""
+    k = ec_impl.get_data_chunk_count()
+    total = len(next(iter(to_decode.values()))) if to_decode else 0
+    lost = [c for c in range(k)
+            if ec_impl.chunk_index(c) not in to_decode] if total else []
+    if not total:
+        rec = None
+    elif _is_linear_vector_code(ec_impl):
+        rec = await _decode_subchunks_async(
+            sinfo, ec_impl, to_decode, service)
+    else:
+        rec = await _decode_chunks_async(
+            sinfo, ec_impl, to_decode, range(k), service=service)
+    if how is not None:
+        how.update(path="none" if not lost else
+                   "host" if rec is None else "device",
+                   lost=lost, rebuilt_bytes=len(lost) * total)
     if rec is None:
         return decode_concat(sinfo, ec_impl, to_decode)
     cs, sw = sinfo.chunk_size, sinfo.stripe_width
-    k = ec_impl.get_data_chunk_count()
-    total = len(next(iter(to_decode.values())))
     ns = total // cs
-    if total == 0:
-        return np.zeros(0, dtype=np.uint8)
     stacked = np.stack([rec[c].reshape(ns, cs) for c in range(k)], axis=1)
     return np.ascontiguousarray(stacked.reshape(ns * sw))
 

@@ -67,12 +67,17 @@ class EncodeService(LaunchBatcher):
     def active(self) -> bool:
         return self.mesh is not None or self.device is not None
 
-    async def apply(self, M: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    async def apply(self, M: np.ndarray, rows: np.ndarray,
+                    **tags) -> np.ndarray:
         """``M @ rows`` over GF(2^8), batched with concurrent callers:
         M an (out, k) byte matrix (coding or cached decode matrix), rows
-        (k, S) uint8.  Returns (out, S) uint8."""
+        (k, S) uint8.  Returns (out, S) uint8.  ``tags`` (``kind``: the
+        same for every caller of one M) go on the launch's span, which
+        then says what it took in and gave back (``helper_bytes``,
+        ``rebuilt_bytes``)."""
         assert self.active()
-        return await self.submit(batcher.matrix_key(M), MatMul(M, rows))
+        return await self.submit(batcher.matrix_key(M),
+                                 MatMul(M, rows, tags or None))
 
     # -- the plan ------------------------------------------------------
 
@@ -93,11 +98,17 @@ class EncodeService(LaunchBatcher):
         big = np.zeros((k, S), np.uint8)
         for req, lo, hi in zip(group, offs, offs[1:]):
             big[:, lo:hi] = req.item.rows
+        tags = dict(group[0].item.tags or ())
         with self._launching(
             (self._kind, bits.shape, k, S), group,
-            kind=f"encode_{self._kind}", guard=self._guard, w=S,
+            kind=tags.pop("kind", f"encode_{self._kind}"),
+            guard=self._guard, w=S,
             b_real=len(group), real_bytes=total * k, padded_bytes=k * S,
         ) as span:
+            if group[0].item.tags:
+                # the bytes the launch is for, pads left out
+                span.tag(helper_bytes=total * k,
+                         rebuilt_bytes=M.shape[0] * total, **tags)
             res = self._launch(bits, big)
             out = jax.device_get(res)
             if self.mesh is not None:
