@@ -232,6 +232,9 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         self.perf = get_perf_counters(f"osd.{osd_id}")
         # each led PG's objects and bytes, from what transactions change
         self._pg_tally = PgStatTally(self.store, self.perf)
+        # every map this daemon installs counts its mapping memo here
+        self.perf.inc("pg_mapping_lookups", 0)
+        self.perf.inc("pg_mapping_runs", 0)
         from ceph_tpu.common import DoutLogger, OpTracker
         from ceph_tpu.common.tracing import Tracer
 
@@ -1751,6 +1754,7 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
         old_map = self.osdmap
         new_map, gap = apply_map_message(self.osdmap, msg.maps, msg.incs)
         if new_map is not None:
+            new_map.mapping_perf = self.perf
             self.osdmap = new_map
             self._wake_reserve_waits()
             self._maybe_snap_trim(old_map, new_map)

@@ -16,6 +16,7 @@ ceph_tpu.osd.remap.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import TYPE_CHECKING
 
 from ceph_tpu.crush.mapper import crush_do_rule
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE, ChooseArg, CrushMap
@@ -24,8 +25,12 @@ from ceph_tpu.osd.types import (
     CEPH_OSD_DEFAULT_PRIMARY_AFFINITY,
     CEPH_OSD_MAX_PRIMARY_AFFINITY,
     PgPool,
+    ceph_stable_mod,
     pg_t,
 )
+
+if TYPE_CHECKING:
+    from ceph_tpu.common.metrics import PerfCounters
 
 CEPH_OSD_EXISTS = 1
 CEPH_OSD_UP = 2
@@ -137,6 +142,14 @@ def _wrap_list(owner: "OSDMap", cur: list) -> "_InvalidatingList":
     return out
 
 
+def _own_mapping_perf():
+    # imported here: ceph_tpu.common imports the messages, which import
+    # this module's package
+    from ceph_tpu.common.metrics import PerfCounters
+
+    return PerfCounters("osdmap")
+
+
 @dataclass
 class OSDMap:
     """Mutable cluster map (an epoch's worth of state).
@@ -166,9 +179,15 @@ class OSDMap:
     # pool id -> name (reference OSDMap pool_name map)
     pool_names: dict[int, str] = field(default_factory=dict)
     # per-epoch memo of pg_to_up_acting_osds (see its docstring);
-    # (epoch, {(pg, folded): (up, upp, acting, actp)}) — never encoded
+    # (epoch, {(pool, pg fold, pgp fold): (up, upp, acting, actp)}) —
+    # never encoded
     _mapping_cache: tuple | None = field(
         default=None, repr=False, compare=False)
+    # ``pg_mapping_lookups`` (memo lookups) and ``pg_mapping_runs``
+    # (pipeline runs: misses); an OSD hands each map it installs its own
+    # collection, so its counts span every map it has held — never encoded
+    mapping_perf: PerfCounters = field(
+        default_factory=_own_mapping_perf, repr=False, compare=False)
 
     def __post_init__(self) -> None:
         # exception tables invalidate the mapping memo on direct writes
@@ -450,20 +469,29 @@ class OSDMap:
         ParallelPGMapper role, src/osd/OSDMapMapping.h:18): every
         daemon subsystem — peering, recovery, scrub, op admission —
         asks for the same mappings many times per epoch, and the
-        scalar pipeline is pure given one epoch's state.  Mutators
-        bump ``epoch`` (mon commit path) which naturally invalidates;
-        in-place mutators below also drop the cache explicitly."""
+        scalar pipeline is pure given one epoch's state.  The key is
+        what the pipeline reads of ``pg``: the pool, the pg_num fold
+        (temp, upmap and primary tables) and the pgp_num fold (the
+        placement seed), so every object name of a PG shares one entry
+        and a folded pg shares its raw pgs'.  Mutators bump ``epoch``
+        (mon commit path) which naturally invalidates; in-place
+        mutators below also drop the cache explicitly."""
+        pool = self.get_pg_pool(pg.pool)
+        if pool is None or (folded and pg.ps >= pool.pg_num):
+            return [], -1, [], -1
         cache = self._mapping_cache
         if cache is None or cache[0] != self.epoch:
             cache = (self.epoch, {})
             self._mapping_cache = cache
-        hit = cache[1].get((pg, folded))
+        key = (pg.pool,
+               ceph_stable_mod(pg.ps, pool.pg_num, pool.pg_num_mask),
+               ceph_stable_mod(pg.ps, pool.pgp_num, pool.pgp_num_mask))
+        self.mapping_perf.inc("pg_mapping_lookups")
+        hit = cache[1].get(key)
         if hit is not None:
             up, up_primary, acting, acting_primary = hit
             return list(up), up_primary, list(acting), acting_primary
-        pool = self.get_pg_pool(pg.pool)
-        if pool is None or (folded and pg.ps >= pool.pg_num):
-            return [], -1, [], -1
+        self.mapping_perf.inc("pg_mapping_runs")
         acting, acting_primary = self._get_temp_osds(pool, pg)
         raw, pps = self._pg_to_raw_osds(pool, pg)
         self._apply_upmap(pool, pg, raw)
@@ -474,7 +502,7 @@ class OSDMap:
             acting = list(up)
             if acting_primary == -1:
                 acting_primary = up_primary
-        cache[1][(pg, folded)] = (
+        cache[1][key] = (
             tuple(up), up_primary, tuple(acting), acting_primary)
         return up, up_primary, acting, acting_primary
 
