@@ -133,6 +133,23 @@ class ErasureCodeInterface(abc.ABC):
         rule id (ErasureCodeInterface.h:212)."""
 
 
+#: (code signature, what) -> a matrix read off a plugin's host code.
+#: Process-wide: co-hosted daemons each hold their own plugin instance of
+#: one profile.
+_LINEAR_MAPS: dict[tuple, np.ndarray] = {}
+
+
+def linear_map(key: tuple, derive) -> np.ndarray:
+    """``derive()``'s matrix for ``key``, derived once a process and
+    shared read-only by every caller: a linear code's encode or decode
+    as one GF(2^8) matrix (the device path of CLAY and LRC)."""
+    hit = _LINEAR_MAPS.get(key)
+    if hit is None:
+        hit = _LINEAR_MAPS[key] = derive()
+        hit.flags.writeable = False     # one array for every caller
+    return hit
+
+
 def _as_u8(data: bytes | bytearray | memoryview | np.ndarray) -> np.ndarray:
     if isinstance(data, np.ndarray):
         return np.ascontiguousarray(data, dtype=np.uint8).reshape(-1)

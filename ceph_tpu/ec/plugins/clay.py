@@ -50,7 +50,7 @@ and the full decode of each erasure set are ONE matrix over sub-chunk
 rows: :meth:`ErasureCodeClay.encode_matrix` (m*alpha, k*alpha) on the
 data chunks' sub-chunks, :meth:`ErasureCodeClay.repair_matrix` (alpha,
 d*alpha/q) on the helpers' repair sub-chunks and
-:meth:`ErasureCodeClay.subchunk_decode_plan`'s (alpha*lost, alpha*k) on
+:meth:`ErasureCodeClay.linear_plan`'s (alpha*lost, alpha*k) on
 the fetched chunks' sub-chunks (alpha = sub_chunk_no).  Each is read off
 this host path once, by encoding, repairing or decoding an identity, and
 then travels like any scalar code's matrix: osd/ecutil.py files it with
@@ -66,18 +66,13 @@ from typing import Mapping
 
 import numpy as np
 
-from ceph_tpu.ec.interface import ECError, ErasureCode
+from ceph_tpu.ec.interface import ECError, ErasureCode, linear_map
 
 __erasure_code_version__ = "0.1.0"
 
 
 def _pow_int(a: int, x: int) -> int:
     return a**x
-
-
-#: (code signature, what) -> derived matrix.  Process-wide: co-hosted
-#: daemons each hold their own plugin instance of one profile.
-_LINEAR_MAPS: dict[tuple, object] = {}
 
 
 def _identity_rows(n_rows: int, first: int, width: int) -> np.ndarray:
@@ -128,6 +123,8 @@ class ErasureCodeClay(ErasureCode):
     DEFAULT_M = "2"
     #: what a launch of a repair matrix calls itself (its span's kind)
     REPAIR_KIND = "clay_repair"
+    #: and a launch of a read's decode matrix
+    DECODE_KIND = "subchunk_decode"
 
     def __init__(self) -> None:
         super().__init__()
@@ -301,13 +298,9 @@ class ErasureCodeClay(ErasureCode):
     # -- the code as matrices over sub-chunk rows (the device path) ----------
 
     def _linear_map(self, what, derive):
-        key = (self.k, self.m, self.d, self._profile.get("scalar_mds"),
-               self._profile.get("technique"), what)
-        hit = _LINEAR_MAPS.get(key)
-        if hit is None:
-            hit = _LINEAR_MAPS[key] = derive()
-            hit.flags.writeable = False     # one array for every caller
-        return hit
+        return linear_map(
+            ("clay", self.k, self.m, self.d, self._profile.get("scalar_mds"),
+             self._profile.get("technique"), what), derive)
 
     def encode_matrix(self) -> np.ndarray:
         """(m * alpha, k * alpha) over GF(2^8): row ``j * alpha + z`` is
@@ -351,14 +344,14 @@ class ErasureCodeClay(ErasureCode):
                 out[lost].reshape(self.sub_chunk_no, n))
         return self._linear_map(("repair", lost), derive)
 
-    def subchunk_decode_plan(
+    def linear_plan(
         self, want: set[int], available: set[int]
-    ) -> tuple[list[int], list[int], np.ndarray | None]:
+    ) -> tuple[list[int], np.ndarray | None]:
         """What the full decode of ``want`` from whole chunks of
         ``available`` reads and rebuilds, as one matrix: ``(fetched,
-        lost, D)`` with ``fetched`` minimum_to_decode's chunks in chunk
-        order, ``lost`` the chunks of ``want`` not among them, and D
-        (alpha * |lost|, alpha * |fetched|) over GF(2^8): row ``i *
+        D)`` with ``fetched`` minimum_to_decode's chunks in chunk order
+        and D (alpha * |lost|, alpha * |fetched|) over GF(2^8), ``lost``
+        being the chunks of ``want`` not fetched, in order: row ``i *
         alpha + z`` is sub-chunk z of ``lost[i]``, column ``f * alpha +
         z'`` sub-chunk z' of ``fetched[f]``.  The host layered decode of
         an identity, once a process per (lost, fetched); None where
@@ -366,7 +359,7 @@ class ErasureCodeClay(ErasureCode):
         fetched = sorted(self.minimum_to_decode(set(want), set(available)))
         lost = sorted(set(want) - set(fetched))
         if not lost:
-            return fetched, lost, None
+            return fetched, None
 
         def derive() -> np.ndarray:
             a, n = self.sub_chunk_no, len(fetched) * self.sub_chunk_no
@@ -375,7 +368,7 @@ class ErasureCodeClay(ErasureCode):
             out = self._decode(set(lost), chunks)
             return np.ascontiguousarray(np.concatenate([
                 out[c].reshape(a, n) for c in lost]))
-        return fetched, lost, self._linear_map(
+        return fetched, self._linear_map(
             ("decode", tuple(lost), tuple(fetched)), derive)
 
     # -- encode / decode entry points ----------------------------------------

@@ -21,6 +21,11 @@ count, feeding recovered chunks to the layers above
 (ErasureCodeLrc.cc:747-838); minimum_to_decode prefers the smallest
 covering layer so a single lost chunk reads only its local group
 (ErasureCodeLrc.cc:565-676 cases 1-3).
+
+Every layer is linear over GF(2^8), so the stack is too: the device path
+takes the code as matrices read off the layered host code by running
+unit vectors through it once a process (``encode_matrix``,
+``linear_plan``), as CLAY's is; ``osd/ecutil.py`` serves both.
 """
 
 from __future__ import annotations
@@ -30,7 +35,7 @@ import json
 
 import numpy as np
 
-from ceph_tpu.ec.interface import ECError, ErasureCode
+from ceph_tpu.ec.interface import ECError, ErasureCode, linear_map
 
 __erasure_code_version__ = "0.1.0"
 
@@ -61,6 +66,12 @@ class Layer:
 
 
 class ErasureCodeLrc(ErasureCode):
+    #: what a launch of a repair matrix with fewer than k helpers (a
+    #: local group's) calls itself (its span's kind)
+    REPAIR_KIND = "local_repair"
+    #: and a launch of a read's decode matrix
+    DECODE_KIND = "layered_decode"
+
     def __init__(self, directory: str | None = None) -> None:
         super().__init__()
         from ceph_tpu.ec.registry import DEFAULT_PLUGIN_DIRECTORY
@@ -430,6 +441,57 @@ class ErasureCodeLrc(ErasureCode):
                 f"{sorted(available_chunks)} ends up unable to read "
                 f"{sorted(want_to_read_erasures)}",
             )
+
+
+    # -- the code as matrices (the device path) -------------------------------
+
+    def _linear_map(self, what, derive) -> np.ndarray:
+        return linear_map(("lrc", tuple(
+            (layer.chunks_map, tuple(sorted(layer.profile.items())))
+            for layer in self.layers), what), derive)
+
+    @staticmethod
+    def _unit_chunks(positions: list[int]) -> dict[int, np.ndarray]:
+        """Chunk ``positions[j]`` is the unit vector j, one byte a chunk
+        of ``positions``: what a linear map does to it is its column j."""
+        eye = np.eye(len(positions), dtype=np.uint8)
+        return {p: eye[j] for j, p in enumerate(positions)}
+
+    def encode_matrix(self) -> np.ndarray:
+        """(n - k, k) over GF(2^8): row j is coding chunk k + j (at
+        position ``chunk_index(k + j)``), column i data chunk i.  Every
+        layer's encode, composed: the layered encode of unit vectors."""
+        def derive() -> np.ndarray:
+            k, n = self.get_data_chunk_count(), self.get_chunk_count()
+            enc = self._unit_chunks([self.chunk_index(i) for i in range(k)])
+            for j in range(k, n):
+                enc[self.chunk_index(j)] = np.zeros(k, dtype=np.uint8)
+            self.encode_chunks(set(range(n)), enc)
+            return np.stack([enc[self.chunk_index(j)] for j in range(k, n)])
+        return self._linear_map("encode", derive)
+
+    def linear_plan(
+        self, want: set[int], available: set[int]
+    ) -> tuple[list[int], np.ndarray | None]:
+        """What rebuilding ``want`` from ``available`` reads and computes,
+        as one matrix: ``(helpers, M)`` with ``helpers``
+        minimum_to_decode's chunks in position order and M (|lost|,
+        |helpers|) over GF(2^8), row i the i-th chunk of ``want`` that is
+        not a helper (``lost``, position order), column j helper j.  One
+        lost chunk of a local group reads its 3 others: a (1, 3) matrix.
+        The layered decode of unit vectors, once a process per (lost,
+        helpers); None where nothing is lost.  Raises ECError(EIO) where
+        the layers cannot rebuild ``want``."""
+        helpers = sorted(self._minimum_to_decode(set(want), set(available)))
+        lost = sorted(set(want) - set(helpers))
+        if not lost:
+            return helpers, None
+
+        def derive() -> np.ndarray:
+            decoded = self._decode(set(lost), self._unit_chunks(helpers))
+            return np.stack([decoded[c] for c in lost])
+        return helpers, self._linear_map(
+            ("decode", tuple(lost), tuple(helpers)), derive)
 
 
 def __erasure_code_init__(name: str, registry) -> None:

@@ -1019,13 +1019,15 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                     if svc is not None and farm_warm \
                             and encode_m is not None:
                         svc.prewarm(encode_m, widths)
-                    # a vector code's degraded read: one (alpha, alpha
-                    # * k) matrix whichever chunk it lost, so one shape
-                    plan = getattr(ec, "subchunk_decode_plan", None)
+                    # a linear code's degraded read: one matrix shape
+                    # whichever data chunk it lost (CLAY's (alpha, alpha
+                    # * k), LRC's (1, 5))
+                    plan = getattr(ec, "linear_plan", None)
                     if svc is not None and farm_warm and plan is not None:
-                        k = ec.get_data_chunk_count()
-                        svc.prewarm(plan(set(range(k)), set(range(
-                            1, ec.get_chunk_count())))[2], widths)
+                        data = {ec.chunk_index(i)
+                                for i in range(ec.get_data_chunk_count())}
+                        svc.prewarm(plan(data, set(range(
+                            ec.get_chunk_count())) - {min(data)})[1], widths)
                 except Exception:
                     self.perf.inc("ec_warmup_failures")
                     log.exception(
@@ -1106,9 +1108,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
     async def _ecu_decode_concat(self, sinfo, ec, chunks):
         """ecutil.decode_concat_async via the encode service.  The span
         says which path rebuilt what (``path``, ``lost``,
-        ``rebuilt_bytes``); a vector code's rebuild is counted on the
-        device (``ec_read_subchunk_decodes``) or on the host loop
-        (``ec_read_host_decodes``: the fallback, never silent)."""
+        ``rebuilt_bytes``); a linear code's rebuild on the host loop is
+        counted (``ec_read_host_decodes``: the fallback, never silent),
+        a vector code's on the device too
+        (``ec_read_subchunk_decodes``)."""
         how: dict = {}
         with self._maybe_span(
             "ec_decode", parent=tracing.CURRENT_SPAN.get(), stage="device",
@@ -1118,10 +1121,10 @@ class OSDDaemon(ECBackendMixin, RecoveryMixin, ScrubMixin, TieringMixin):
                 sinfo, ec, chunks, service=self.encode_service, how=how)
             if sp is not None:
                 sp.tag(**how)
-        if ec.get_sub_chunk_count() > 1 and how["path"] != "none":
-            self.perf.inc("ec_read_subchunk_decodes"
-                          if how["path"] == "device"
-                          else "ec_read_host_decodes")
+        if how["path"] == "host" and hasattr(ec, "linear_plan"):
+            self.perf.inc("ec_read_host_decodes")
+        elif how["path"] == "device" and ec.get_sub_chunk_count() > 1:
+            self.perf.inc("ec_read_subchunk_decodes")
         return logical
 
     def _pg_log(self, c: coll_t) -> PGLog:

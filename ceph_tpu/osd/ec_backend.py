@@ -910,12 +910,13 @@ class ECBackendMixin:
             except Exception:
                 log.exception(
                     "osd.%d: fast_read fetch failed; normal path", self.id)
-        k = ec.get_data_chunk_count()
+        # the data chunks' shards: the code's chunk mapping places them
+        # (upstream's get_chunk_mapping; LRC's k=4 l=3 at 0, 1, 4, 5)
+        want = {ec.chunk_index(i) for i in range(ec.get_data_chunk_count())}
         avail = self._ec_avail(acting)
         excluded: dict[int, int] = {}  # shard -> errno seen
         for _attempt in range(len(acting) + 1):
             usable = {s: o for s, o in avail.items() if s not in excluded}
-            want = set(range(k))
             try:
                 minimum = ec.minimum_to_decode(want, set(usable))
             except Exception:

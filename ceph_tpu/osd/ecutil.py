@@ -195,15 +195,16 @@ def _farm_ready(service, ec_impl) -> bool:
     )
 
 
-# A vector code (sub-chunks) whose plugin gives its encode and its
-# single-chunk repair as matrices over sub-chunk rows (CLAY:
-# ``encode_matrix`` / ``repair_matrix``) takes the same engines with a
-# reshaped operand: a chunk payload of ns stripes is (ns, alpha, sc)
-# bytes, and sub-chunk z of every stripe lies side by side in row z.
+# A linear code whose plugin gives its matrices (``encode_matrix`` and
+# ``linear_plan``: CLAY over sub-chunk rows, LRC's stacked layers over
+# whole chunks) takes the same engines with a reshaped operand: a chunk
+# payload of ns stripes is (ns, alpha, sc) bytes, and sub-chunk z of
+# every stripe lies side by side in row z (alpha = 1: the payload is the
+# one row).  CLAY's single-chunk repair from packed sub-chunk reads is
+# its ``repair_matrix``.
 
-def _is_linear_vector_code(ec_impl) -> bool:
-    return (ec_impl.get_sub_chunk_count() > 1
-            and hasattr(ec_impl, "encode_matrix"))
+def _is_linear_code(ec_impl) -> bool:
+    return hasattr(ec_impl, "linear_plan")
 
 
 def _to_subchunk_rows(payload: np.ndarray, n_sub: int, sc: int) -> np.ndarray:
@@ -219,7 +220,14 @@ def _from_subchunk_rows(rows: np.ndarray, sc: int) -> np.ndarray:
         rows.reshape(n_sub, -1, sc).transpose(1, 0, 2)).reshape(-1)
 
 
-async def _encode_subchunks_async(sinfo, ec_impl, arr, want, service):
+def _rows_of(to_decode, helpers, n_sub: int, sc: int) -> np.ndarray:
+    """The helpers' payloads as one operand, helper after helper."""
+    return np.concatenate([
+        _to_subchunk_rows(np.asarray(to_decode[h]).reshape(-1), n_sub, sc)
+        for h in helpers])
+
+
+async def _encode_linear_async(sinfo, ec_impl, arr, want, service):
     k = ec_impl.get_data_chunk_count()
     m = ec_impl.get_chunk_count() - k
     cs = sinfo.chunk_size
@@ -240,27 +248,50 @@ async def _encode_subchunks_async(sinfo, ec_impl, arr, want, service):
     return out
 
 
-async def _repair_subchunks_async(
-    sinfo, ec_impl, to_decode, need, aggregator
+async def _repair_linear_async(
+    sinfo, ec_impl, to_decode, need, aggregator, *, packed: bool,
+    how: dict | None,
 ) -> dict[int, np.ndarray] | None:
-    """The regenerating repair of one chunk from its helpers' packed
-    ranged reads, as one matrix filed with the aggregator; None = the
-    caller takes the host path."""
-    if aggregator is None or len(need) != 1 \
-            or not _is_linear_vector_code(ec_impl):
+    """The chunks ``need`` rebuilt from their helpers' reads as one
+    matrix filed with the aggregator: a vector code's regenerating
+    repair of one chunk from packed sub-chunk reads (``repair_matrix``),
+    or a scalar linear code's from the whole chunks ``linear_plan``
+    names (an LRC chunk from its local group).  ``how`` gets the
+    launch's ``kind`` and the ``helpers`` it read.  None = the caller
+    takes another path."""
+    if aggregator is None or not _is_linear_code(ec_impl):
         return None
-    lost = next(iter(need))
-    R = ec_impl.repair_matrix(lost)
-    if R is None or set(to_decode) != set(ec_impl.repair_helpers(lost)):
-        return None
-    sc = sinfo.chunk_size // ec_impl.get_sub_chunk_count()
-    beta = R.shape[1] // len(to_decode)
-    rows = np.concatenate([
-        _to_subchunk_rows(np.asarray(to_decode[h]).reshape(-1), beta, sc)
-        for h in sorted(to_decode)])
+    alpha = ec_impl.get_sub_chunk_count()
+    if packed:
+        if len(need) != 1:
+            return None
+        rebuilt = sorted(need)
+        M = ec_impl.repair_matrix(rebuilt[0])
+        if M is None or set(to_decode) != set(
+                ec_impl.repair_helpers(rebuilt[0])):
+            return None
+        helpers, kind = sorted(to_decode), ec_impl.REPAIR_KIND
+    elif alpha == 1:
+        try:
+            helpers, M = ec_impl.linear_plan(set(need), set(to_decode))
+        except ECError:
+            return None         # the layers cannot: the host says so
+        if M is None:
+            return None
+        rebuilt = sorted(set(need) - set(helpers))
+        kind = ec_impl.REPAIR_KIND \
+            if len(helpers) < ec_impl.get_data_chunk_count() else \
+            "decode_batch"
+    else:
+        return None     # a vector code's whole-chunk decode: the host's
+    sc = sinfo.chunk_size // alpha
     out = await aggregator.apply(
-        R, rows, kind=ec_impl.REPAIR_KIND, lost_node=lost)
-    return {lost: _from_subchunk_rows(out, sc)}
+        M, _rows_of(to_decode, helpers, M.shape[1] // len(helpers), sc),
+        kind=kind, lost_node=rebuilt[0])
+    if how is not None:
+        how.update(kind=kind, helpers=len(helpers))
+    return {c: _from_subchunk_rows(out[i * alpha:(i + 1) * alpha], sc)
+            for i, c in enumerate(rebuilt)}
 
 
 async def encode_async(
@@ -277,17 +308,16 @@ async def encode_async(
         if isinstance(data, np.ndarray)
         else np.frombuffer(data, dtype=np.uint8)    # bytes or a view
     )
-    vector = _service_takes(service) and _is_linear_vector_code(ec_impl)
-    if not vector and not _farm_ready(service, ec_impl):
+    linear = _service_takes(service) and _is_linear_code(ec_impl)
+    if not linear and not _farm_ready(service, ec_impl):
         return encode(sinfo, ec_impl, arr, want)
     sw, cs = sinfo.stripe_width, sinfo.chunk_size
     if arr.nbytes % sw:
         raise ECError(errno.EINVAL, f"logical size {arr.nbytes} not stripe aligned")
     if arr.nbytes == 0:
         return {}
-    if vector:
-        return await _encode_subchunks_async(
-            sinfo, ec_impl, arr, want, service)
+    if linear:
+        return await _encode_linear_async(sinfo, ec_impl, arr, want, service)
     k, m = ec_impl.get_data_chunk_count(), ec_impl.get_chunk_count() - ec_impl.get_data_chunk_count()
     ns = arr.nbytes // sw
     data_shards = np.ascontiguousarray(
@@ -302,28 +332,27 @@ async def encode_async(
     return out
 
 
-async def _decode_subchunks_async(
-    sinfo, ec_impl, to_decode, service
+async def _decode_linear_async(
+    sinfo, ec_impl, to_decode, data, service
 ) -> dict[int, np.ndarray] | None:
-    """A read's k data chunks, those it lost rebuilt from the fetched
-    chunks' sub-chunk rows as ONE matrix filed with the encode service
-    (``subchunk_decode_plan``); None = the caller takes the host path."""
-    k = ec_impl.get_data_chunk_count()
-    fetched, lost, D = ec_impl.subchunk_decode_plan(
-        set(range(k)), set(to_decode))
+    """A read's data chunks (``data``: their positions), those it lost
+    rebuilt from the fetched chunks as ONE matrix filed with the encode
+    service (``linear_plan``); None = the caller takes the host path."""
+    try:
+        fetched, D = ec_impl.linear_plan(set(data), set(to_decode))
+    except ECError:
+        return None
     out = {c: np.asarray(to_decode[c]).reshape(-1)
-           for c in range(k) if c in to_decode}
-    if not lost:
+           for c in data if c in to_decode}
+    if D is None:
         return out
     if not _service_takes(service):
         return None
     alpha = ec_impl.get_sub_chunk_count()
     sc = sinfo.chunk_size // alpha
-    rows = np.concatenate([
-        _to_subchunk_rows(np.asarray(to_decode[c]).reshape(-1), alpha, sc)
-        for c in fetched])
-    rec = await service.apply(D, rows, kind="subchunk_decode")
-    for i, c in enumerate(lost):
+    rec = await service.apply(D, _rows_of(to_decode, fetched, alpha, sc),
+                              kind=ec_impl.DECODE_KIND)
+    for i, c in enumerate(sorted(set(data) - set(fetched))):
         out[c] = _from_subchunk_rows(rec[i * alpha:(i + 1) * alpha], sc)
     return out
 
@@ -337,23 +366,26 @@ async def decode_concat_async(
     how: dict | None = None,
 ) -> np.ndarray:
     """:func:`decode_concat` with the lost data chunks rebuilt on the
-    device: a scalar code's by its decode matrix, a vector code's as one
-    matrix over sub-chunk rows.  ``how``, where given, is filled with
-    what served it: ``path`` (``"device"``, ``"host"`` or ``"none"``:
-    every data chunk was fetched), ``lost`` (the data chunks rebuilt)
-    and ``rebuilt_bytes``."""
+    device: a scalar code's by its decode matrix, a linear code's whose
+    plugin gives its plan (CLAY over sub-chunk rows, LRC) as one matrix.
+    ``how``, where given, is filled with what served it: ``path``
+    (``"device"``, ``"host"`` or ``"none"``: every data chunk was
+    fetched), ``lost`` (the positions of the data chunks rebuilt) and
+    ``rebuilt_bytes``."""
     k = ec_impl.get_data_chunk_count()
+    data = [ec_impl.chunk_index(i) for i in range(k)]
     total = len(next(iter(to_decode.values()))) if to_decode else 0
-    lost = [c for c in range(k)
-            if ec_impl.chunk_index(c) not in to_decode] if total else []
+    lost = [c for c in data if c not in to_decode] if total else []
     if not total:
         rec = None
-    elif _is_linear_vector_code(ec_impl):
-        rec = await _decode_subchunks_async(
-            sinfo, ec_impl, to_decode, service)
+    elif _is_linear_code(ec_impl):
+        rec = await _decode_linear_async(
+            sinfo, ec_impl, to_decode, data, service)
     else:
         rec = await _decode_chunks_async(
             sinfo, ec_impl, to_decode, range(k), service=service)
+        if rec is not None:
+            rec = {ec_impl.chunk_index(c): v for c, v in rec.items()}
     if how is not None:
         how.update(path="none" if not lost else
                    "host" if rec is None else "device",
@@ -362,7 +394,7 @@ async def decode_concat_async(
         return decode_concat(sinfo, ec_impl, to_decode)
     cs, sw = sinfo.chunk_size, sinfo.stripe_width
     ns = total // cs
-    stacked = np.stack([rec[c].reshape(ns, cs) for c in range(k)], axis=1)
+    stacked = np.stack([rec[c].reshape(ns, cs) for c in data], axis=1)
     return np.ascontiguousarray(stacked.reshape(ns * sw))
 
 
@@ -375,21 +407,24 @@ async def decode_shards_async(
     packed_repair: bool = False,
     service=None,
     aggregator=None,
+    how: dict | None = None,
 ) -> dict[int, np.ndarray]:
     """:func:`decode_shards` with batched reconstruction (recovery
-    path).  A vector code's packed single-chunk repair is one matrix
-    like a scalar code's decode; its other decodes (several losses,
-    full-chunk reads, d < k+m-1) stay on the host loop.
+    path).  A linear code's repair (a vector code's packed single-chunk
+    repair, a scalar linear code's from its plan's helpers) is one
+    matrix like a scalar code's decode, and says so in ``how``
+    (:func:`_repair_linear_async`); a vector code's other decodes
+    (several losses, full-chunk reads, d < k+m-1) stay on the host loop.
 
     ``aggregator`` (a parallel.decode_batcher.DecodeAggregator) takes
     precedence over the encode farm: per-object recovery decodes that
     share an erasure signature coalesce into fixed-shape batched
     launches — the repair-pipelining discipline — instead of one farm
     matmul per object."""
-    if packed_repair and all(
-            np.asarray(v).size for v in to_decode.values()):
-        rec = await _repair_subchunks_async(
-            sinfo, ec_impl, to_decode, need, aggregator)
+    if all(np.asarray(v).size for v in to_decode.values()):
+        rec = await _repair_linear_async(
+            sinfo, ec_impl, to_decode, need, aggregator,
+            packed=packed_repair, how=how)
         if rec is not None:
             return rec
     if packed_repair or (
@@ -426,7 +461,13 @@ async def _decode_chunks_batched(
         rows = ec_impl.decode_rows(to_decode, survivors)
         if rows.shape[1] == 0:
             return None
-        rec_rows = await aggregator.apply(D, rows)
+        # the rows of the chunks wanted alone: a repair that read k
+        # survivors leaves the others erased and rebuilds none of them
+        r = ec_impl.rows_per_chunk
+        rec_rows = await aggregator.apply(D[[
+            erasures.index(c) * r + j for c in need_rec for j in range(r)]],
+            rows)
+        erasures = tuple(need_rec)
     return ec_impl.decode_assemble(
         to_decode, want_chunks, erasures, need_rec, rec_rows)
 

@@ -13,6 +13,7 @@ import numpy as np
 
 from ceph_tpu.common import tracing
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
+from ceph_tpu.ec.interface import ECError
 from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.pglog import (
     DELETE,
@@ -1451,7 +1452,8 @@ class RecoveryMixin:
         # ranged shard reads so only sub_chunk_no/q of each helper
         # crosses the wire (reference ECCommon.cc:262-299 +
         # ErasureCodeClay::repair_one_lost_chunk) — CLAY's whole point
-        repair_extents: dict[int, list[tuple[int, int]]] = {}
+        # (a helper's extents; None: the whole shard)
+        repair_extents: dict[int, list[tuple[int, int]] | None] = {}
         if (
             len(lost) == 1 and ec.get_sub_chunk_count() > 1
             and not rb_srcs
@@ -1478,6 +1480,17 @@ class RecoveryMixin:
                 log.exception(
                     "osd.%d: %s/%s: no sub-chunk repair plan; reading "
                     "whole chunks", self.id, pg, oid)
+        # a whole-chunk repair reads what the code needs, for every code
+        # (ECCommon::get_min_avail_to_read_shards): minimum_to_decode's
+        # shards, an LRC chunk's local group of 3, a scalar code's k
+        if lost and not repair_extents and not rb_srcs:
+            try:
+                repair_extents = dict.fromkeys(
+                    ec.minimum_to_decode(lost, set(sources)))
+            except ECError as e:    # the full reads below say so
+                log.warning(
+                    "osd.%d: %s/%s: no minimum to decode (%s); reading "
+                    "every source", self.id, pg, oid, e)
         # helper-shard reads and shard pushes both fan out concurrently
         # (the reference's ECSubRead/MOSDPGPush are fire-and-gather)
         chunks: dict[int, np.ndarray] = {}    # what the decode is given
@@ -1485,24 +1498,28 @@ class RecoveryMixin:
         used_packed = False
         read_sp = self.tracer.start_span("recovery_read", parent=obj_sp)
         if (repair_extents or not lost) and not rb_srcs:
-            # ranged reads of the helpers, whole reads of what only moved
-            reads = [(s, sources[s], ext)
+            # the helpers (ranged for a sub-chunk repair, else whole),
+            # whole reads of what only moved (once, where it helps too)
+            whole = {s for s, ext in repair_extents.items() if ext is None}
+            reads = [(s, sources[s], ext, chunks)
                      for s, ext in sorted(repair_extents.items())]
-            reads += [(s, sources[s], None) for s in sorted(need - lost)]
+            reads += [(s, sources[s], None, passed)
+                      for s in sorted(need - lost - whole)]
             payloads = await asyncio.gather(*(
                 self._read_shard_quiet(pool, pg, s, o, oid, extents=ext)
-                for s, o, ext in reads
+                for s, o, ext, _into in reads
             ))
-            for (s, _o, ext), (payload, _a, _e) in zip(reads, payloads):
+            for (s, _o, _ext, into), (payload, _a, _e) in zip(
+                    reads, payloads):
                 if payload is not None:
-                    (passed if ext is None else chunks)[s] = \
-                        np.frombuffer(payload, np.uint8)
+                    into[s] = np.frombuffer(payload, np.uint8)
             if len(chunks) + len(passed) < len(reads):
                 chunks, passed = {}, {}  # a source vanished: full reads
             else:
-                used_packed = bool(chunks)
+                passed.update((s, chunks[s]) for s in (need - lost) & whole)
+                used_packed = any(repair_extents.values())
                 read_sp.tag(extents=sum(
-                    len(ext) if ext else 1 for _s, _o, ext in reads))
+                    len(ext) if ext else 1 for _s, _o, ext, _i in reads))
         if not chunks and not passed:
             src_items = list(sources.items())
             payloads = await asyncio.gather(*(
@@ -1525,7 +1542,8 @@ class RecoveryMixin:
             lost = need - set(chunks)
             passed = {s: chunks[s] for s in need - lost}
         helper_bytes = sum(v.nbytes for v in chunks.values())
-        read_sp.tag(subchunk=used_packed, helper_bytes=helper_bytes)
+        read_sp.tag(subchunk=used_packed, helper_bytes=helper_bytes,
+                    helpers=len(chunks))
         self.tracer.finish_span(read_sp)
         self.perf.inc("recovery_read_bytes", helper_bytes)
         if ec.get_sub_chunk_count() > 1 and lost:
@@ -1540,21 +1558,27 @@ class RecoveryMixin:
         # span is in scope so the aggregator files this object's wait
         # for its launch under it (decode_batch_wait)
         _t0 = time.perf_counter()
+        how: dict = {}
         with self.tracer.span(
             "recovery_decode", parent=obj_sp, stage="device",
         ) as dec_sp, tracing.scope(dec_sp):
             rebuilt = await ecutil.decode_shards_async(
                 sinfo, ec, chunks, lost, packed_repair=used_packed,
                 service=self.encode_service,
-                aggregator=self.decode_aggregator,
+                aggregator=self.decode_aggregator, how=how,
             ) if lost else {}
             rebuilt_bytes = sum(v.nbytes for v in rebuilt.values())
-            if used_packed:
-                dec_sp.tag(kind=getattr(ec, "REPAIR_KIND", "subchunk_repair"),
-                           lost_node=min(lost), objects=1,
+            kind = how.get("kind") or (used_packed and getattr(
+                ec, "REPAIR_KIND", "subchunk_repair"))
+            if kind:
+                dec_sp.tag(kind=kind, lost_node=min(lost), objects=1,
                            helper_bytes=helper_bytes,
                            rebuilt_bytes=rebuilt_bytes)
             rebuilt.update(passed)
+        self.perf.inc("recovery_rebuilt_shards", len(lost))
+        if how.get("helpers", k) < k:
+            # one matrix over fewer than k helpers: a local group's
+            self.perf.inc("recovery_local_repairs", len(lost))
         self.perf.inc("recovery_decode_seconds",
                       time.perf_counter() - _t0)
         # recovery_decode_bytes is every byte handed to a target, the

@@ -261,13 +261,15 @@ class ScrubMixin:
             else:
                 ec = self._ec_for(pool)
                 sinfo = self._sinfo(ec)
-                k = ec.get_data_chunk_count()
+                data = [ec.chunk_index(i)
+                        for i in range(ec.get_data_chunk_count())]
                 import numpy as _np
 
-                if all(s in payloads for s in range(k)) and len(payloads[0]):
+                if all(s in payloads for s in data) \
+                        and len(payloads[data[0]]):
                     chunks = {
                         s: _np.frombuffer(payloads[s], _np.uint8)
-                        for s in range(k)
+                        for s in data
                     }
                     logical = ecutil.decode_concat(sinfo, ec, chunks)
                     expect = ecutil.encode(sinfo, ec, logical)

@@ -126,8 +126,9 @@ class DecodeAggregator(LaunchBatcher):
         aggregator can launch for ``ec_impl``'s code: the whole CLOSED
         ladder (``widths`` only hints at extra buckets) for each of
         ``erasure_counts`` (the decode matrix SHAPE depends only on the
-        count; one above the code's parity is skipped), or for a vector
-        code the one shape of its repair matrices.  Blocking: warmup
+        count; one above the code's parity is skipped), for a vector
+        code the one shape of its repair matrices, for a scalar linear
+        code (LRC) those of its single-chunk repairs.  Blocking: warmup
         only.  Returns the number of programs compiled."""
         import jax.numpy as jnp
 
@@ -142,6 +143,11 @@ class DecodeAggregator(LaunchBatcher):
             # (its other decodes never come here)
             R = ec_impl.repair_matrix(0)
             mats = [] if R is None else [R.shape]
+        elif hasattr(ec_impl, "linear_plan"):
+            # a scalar linear code: one chunk lost, whichever
+            n = ec_impl.get_chunk_count()
+            mats = sorted({ec_impl.linear_plan(
+                {c}, set(range(n)) - {c})[1].shape for c in range(n)})
         else:
             mats = [(e * r, k * r) for e in erasure_counts
                     if e <= ec_impl.get_chunk_count() - k]

@@ -60,9 +60,6 @@ def test_cell_resolves_to_configuration_traffic_reference_and_readers(
     assert config["name"] == cfg["name"]
     assert config["chips"] == entry["chips"]
     assert set(cfg["reduced"]) <= set(config["reduced"])
-    assert config["guarantees"]["copies_compared"] == (
-        config["pool"]["k"] + config["pool"]["m"]
-        if config["pool"]["type"] == "erasure" else config["pool"]["size"])
     with open(os.path.join(BENCH, "traffic",
                            entry["traffic"] + ".json")) as f:
         traffic = json.load(f)
@@ -84,6 +81,13 @@ def test_cell_resolves_to_configuration_traffic_reference_and_readers(
         assert "ceph" + "_tpu" not in f.read()  # nothing of the program
     ref = _load(ref_path, "reference_under_test_" + cell)
     assert callable(ref.expected_copies)
+    # as many copies compared as the reference says the pool stores
+    # (an LRC pool's k + m + (k+m)/l, not k + m): one stripe of zeros
+    pool = config["pool"]
+    blob = bytes(pool["k"] * pool["stripe_unit"]
+                 if pool["type"] == "erasure" else 1)
+    assert config["guarantees"]["copies_compared"] == len(
+        ref.expected_copies(pool, blob))
     e2e = _metrics_of(cell, "end_to_end")
     assert "setup_s" in {m["name"] for m in e2e} and len(e2e) >= 2
     per_layer = _metrics_of(cell, "per_layer")

@@ -1,6 +1,6 @@
 """A CLAY degraded read on the served device path: the data chunks a read
 lost rebuilt from the k fetched chunks as ONE matrix over sub-chunk rows
-(``ErasureCodeClay.subchunk_decode_plan``), filed with the encode
+(``ErasureCodeClay.linear_plan``), filed with the encode
 service by ``ecutil.decode_concat_async``; held to the plain reference
 of the benchmark (``benchmarks/references/clay8411.py``, loaded by path:
 it imports nothing of the program) and to the host's layered decode,
@@ -87,22 +87,21 @@ def test_a_read_decode_matrix_is_derived_once_a_process(geometry):
     data = set(range(k))
     for n in range(1, m + 1):
         lost = set(range(n))
-        fetched, got_lost, D = a.subchunk_decode_plan(
-            data, set(range(k + m)) - lost)
-        assert got_lost == sorted(lost) and len(fetched) == k
+        fetched, D = a.linear_plan(data, set(range(k + m)) - lost)
+        assert sorted(data - set(fetched)) == sorted(lost)
+        assert len(fetched) == k
         assert fetched == sorted(set(range(k + m)) - lost)[:k]
         assert D.shape == (alpha * n, alpha * k)
-        assert b.subchunk_decode_plan(
-            data, set(range(k + m)) - lost)[2] is D
+        assert b.linear_plan(data, set(range(k + m)) - lost)[1] is D
         assert not D.flags.writeable
     # only parity lost: the data is fetched and nothing is decoded
-    assert a.subchunk_decode_plan(data, set(range(k + m)) - {k}) == (
-        list(range(k)), [], None)
+    assert a.linear_plan(data, set(range(k + m)) - {k}) == (
+        list(range(k)), None)
     other = registry.factory("clay", {
         "plugin": "clay", "k": str(k), "m": str(m), "d": str(_d),
         "scalar_mds": "isa"})
-    assert other.subchunk_decode_plan(data, set(range(1, k + m)))[2] \
-        is not a.subchunk_decode_plan(data, set(range(1, k + m)))[2]
+    assert other.linear_plan(data, set(range(1, k + m)))[1] \
+        is not a.linear_plan(data, set(range(1, k + m)))[1]
 
 
 def test_the_launch_is_tagged_and_one_prewarmed_shape_serves_every_loss():
@@ -112,9 +111,9 @@ def test_the_launch_is_tagged_and_one_prewarmed_shape_serves_every_loss():
     ec, sinfo = _code("k4m2d5")
     k, m, d, unit = GEOMETRIES["k4m2d5"]
     svc = _service()
-    plan = ec.subchunk_decode_plan(set(range(k)), set(range(1, k + m)))
+    plan = ec.linear_plan(set(range(k)), set(range(1, k + m)))
     widths = [sinfo.chunk_size // ec.get_sub_chunk_count()]
-    assert svc.prewarm(plan[2], widths) > 0
+    assert svc.prewarm(plan[1], widths) > 0
     blob = _blob("k4m2d5", 7)
     shards = REF.clay_shards(blob, k, m, d, unit)
     before = len(device_tracer().find(kind="subchunk_decode"))
